@@ -30,7 +30,7 @@ from repro.core import jax_sketch as js
 from repro.core import sketch_bank as sb
 from repro.kernels import ops
 from repro.kernels.ref import BucketSpec
-from repro.launch.roofline import attained_bandwidth, ingest_bytes_model
+from repro.launch.roofline import attained_bandwidth, ingest_bytes_model, peaks
 
 # device programs one full bank ingest launches per pipeline: the fused
 # path is ONE dispatch (bucketize + bin + aux stats); sort pays key pass +
@@ -175,16 +175,16 @@ def bench_fused_ingest(
     deltas in ONE dispatch, so ``dispatches_per_ingest`` drops 3 -> 1 and
     the lane traffic drops ~5x (see ``launch.roofline.ingest_bytes_model``).
 
-    Each row carries the roofline position: ``model_mb`` is the modeled
-    bytes moved, ``attained_gbps`` what the measured wall-clock implies
-    those bytes moved at, ``hbm_frac`` that rate against the TPU HBM
-    roofline (on this CPU ref tier: distance-to-roofline trajectory, not
-    an attained fraction).  ``speedup`` is vs the sort pipeline of the same
+    Each row carries ``model_mb``, the modeled bytes moved.  On a TPU it
+    adds ``attained_gbps`` (what the measured wall-clock implies those
+    bytes moved at) and ``hbm_frac`` (that rate against the chip's HBM
+    peak from ``roofline.PEAKS``); a CPU run writes neither.  ``speedup`` is vs the sort pipeline of the same
     (config, dup) — the committed acceptance bar is fused >= 1.3x on the
     high-duplication N=1M / K=128 row.  ``picked_method`` is what
     ``method=None`` auto-resolves to for the config.
     """
     rows = []
+    hw = peaks() if jax.default_backend() == "tpu" else None
     for n, k, m in configs:
         spec = BucketSpec(num_buckets=m, offset=-m // 2)
         rng = np.random.default_rng(0)
@@ -206,26 +206,26 @@ def bench_fused_ingest(
                 secs_by[method] = _time(fn, base, vals, ids, iters=iters)
             for method, secs in secs_by.items():
                 model = ingest_bytes_model(method, n, k, m)
-                bw = attained_bandwidth(model["hbm_bytes"], secs)
-                rows.append(
-                    {
-                        "bench": "fused_ingest",
-                        "n": n,
-                        "K": k,
-                        "m": m,
-                        "dup": dup,
-                        "method": method,
-                        "picked_method": picked,
-                        "dispatches_per_ingest": DISPATCHES_PER_INGEST[method],
-                        "ms": round(secs * 1e3, 3),
-                        "mvals_per_s": round(n / secs / 1e6, 1),
-                        "model_mb": round(model["hbm_bytes"] / 1e6, 1),
-                        "attained_gbps": round(bw["attained_gbps"], 2),
-                        "hbm_frac": round(bw["hbm_frac"], 4),
-                        "speedup": round(secs_by["sort"] / secs, 2),
-                        "impl": "xla_ref",
-                    }
-                )
+                row = {
+                    "bench": "fused_ingest",
+                    "n": n,
+                    "K": k,
+                    "m": m,
+                    "dup": dup,
+                    "method": method,
+                    "picked_method": picked,
+                    "dispatches_per_ingest": DISPATCHES_PER_INGEST[method],
+                    "ms": round(secs * 1e3, 3),
+                    "mvals_per_s": round(n / secs / 1e6, 1),
+                    "model_mb": round(model["hbm_bytes"] / 1e6, 1),
+                    "speedup": round(secs_by["sort"] / secs, 2),
+                    "impl": "xla_ref" if hw is None else "pallas",
+                }
+                if hw is not None:
+                    bw = attained_bandwidth(model["hbm_bytes"], secs, hw=hw)
+                    row["attained_gbps"] = round(bw["attained_gbps"], 2)
+                    row["hbm_frac"] = round(bw["hbm_frac"], 4)
+                rows.append(row)
     return rows
 
 
@@ -407,6 +407,19 @@ def _sharded_worker(cfg: dict) -> list[dict]:
     return rows
 
 
+def _refuse_cpu_children_on_tpu(sweep: str) -> None:
+    """The multi-device sweeps re-exec children pinned to fake CPU devices
+    (the parent already holds the backend).  On a TPU host their rows would
+    time the CPU under a chip run's name, so refuse instead."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{sweep}: this sweep times CPU child processes on simulated "
+            "devices; on a TPU host it would report CPU rows as a chip run. "
+            "Run the sharded path on the chip through chip_smoke.py "
+            "--four-chips instead"
+        )
+
+
 def bench_sharded_ingest(
     k: int = 4096, n: int = 4096, records: int = 20, iters: int = 3,
     shards=(1, 2, 8), n_devices: int = 8,
@@ -420,6 +433,7 @@ def bench_sharded_ingest(
     shard_map path (the capacity win needs real devices), with the
     shards=1 engine row as the in-process baseline.
     """
+    _refuse_cpu_children_on_tpu("sharded_ingest")
     cfg = {"k": k, "n": n, "records": records, "iters": iters,
            "shards": list(shards)}
     env = dict(os.environ)
@@ -579,6 +593,7 @@ def bench_fleet_ingest(
     batch is never replicated — while ``rollup_ms`` carries the one psum).
     Points whose fleet cannot bootstrap are skipped with a note.
     """
+    _refuse_cpu_children_on_tpu("sharded_ingest_fleet")
     rows: list[dict] = []
     for p_count in processes:
         rows.extend(_fleet_point(k, n, records, iters, p_count))

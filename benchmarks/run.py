@@ -21,6 +21,8 @@ import json
 import os
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (
     bank_bench,
     ingest_bench,
@@ -78,6 +80,8 @@ def main() -> None:
     p.add_argument("--json", default=None, metavar="PATH",
                    help="also write all rows to PATH as JSON (CI artifact)")
     args = p.parse_args()
+    # persistent compile cache: $JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
 
     if args.smoke:
         benches = {

@@ -1,9 +1,9 @@
-"""Version-compat shims so the repo runs on jax 0.4.x through current.
+"""One ``shard_map`` surface for the repo.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and renamed ``check_rep`` -> ``check_vma`` / ``auto`` -> ``axis_names`` along
-the way.  Every in-repo caller goes through this wrapper (new-style keyword
-surface) so the rest of the codebase is written against one API.
+Every in-repo caller goes through this wrapper so the replication check
+defaults to off: the bodies here call Pallas kernels and host-routed
+collectives whose outputs carry no varying-manual-axes annotation, which
+``jax.shard_map``'s default ``check_vma=True`` would reject.
 """
 
 from __future__ import annotations
@@ -14,33 +14,16 @@ __all__ = ["shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False, axis_names=None):
-    """New-style ``jax.shard_map`` surface, lowered to whichever API exists.
+    """``jax.shard_map`` with ``check_vma=False`` by default.
 
-    ``axis_names`` (when given) is the set of *manual* mesh axes; on old jax
-    it is translated to ``auto`` = the complement.
+    ``axis_names`` (when given) is the set of *manual* mesh axes.
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = (
-        frozenset()
-        if axis_names is None
-        else frozenset(mesh.axis_names) - frozenset(axis_names)
-    )
-    return _shard_map(
+    kwargs = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
-        auto=auto,
+        check_vma=check_vma,
+        **kwargs,
     )

@@ -157,7 +157,7 @@ def effective_alpha(spec: BucketSpec, level: int) -> float:
     return (g - 1.0) / (g + 1.0)
 
 
-def _bank_histograms(values, weights, levels, spec, use_kernel, method):
+def _bank_histograms(values, weights, levels, spec, method):
     """Both sign stores via the ops front door (matmul vs sort–scatter)."""
     from repro.kernels import ops
 
@@ -169,7 +169,6 @@ def _bank_histograms(values, weights, levels, spec, use_kernel, method):
         num_segments=1,
         spec=spec,
         method=method,
-        force=None if use_kernel else "ref",
     )
     return pos[0], neg[0]
 
@@ -197,14 +196,13 @@ def _needed_levels(k0: jnp.ndarray, spec: BucketSpec) -> jnp.ndarray:
     return jnp.where(fits.any(axis=1), first, 0)
 
 
-@partial(jax.jit, static_argnames=("spec", "use_kernel", "auto_collapse", "method"))
+@partial(jax.jit, static_argnames=("spec", "auto_collapse", "method"))
 def add(
     sketch: DeviceSketch,
     values: jnp.ndarray,
     weights: jnp.ndarray | None = None,
     *,
     spec: BucketSpec,
-    use_kernel: bool = False,
     auto_collapse: bool = False,
     method: str | None = None,
 ) -> DeviceSketch:
@@ -239,7 +237,7 @@ def add(
     lev = sketch.level
     shifts = jnp.broadcast_to(lev, x.shape)
 
-    pos_hist, neg_hist = _bank_histograms(x, raw_w, shifts, spec, use_kernel, method)
+    pos_hist, neg_hist = _bank_histograms(x, raw_w, shifts, spec, method)
 
     # clamp accounting: shifted keys that escape [offset, offset + m - 1]
     top_key = spec.offset + spec.num_buckets - 1
@@ -270,11 +268,11 @@ def add(
 # --------------------------------------------------------------------- #
 # uniform collapse (UDDSketch): resolution as a dynamic property
 # --------------------------------------------------------------------- #
-def _fold(counts, spec: BucketSpec, use_kernel: bool):
+def _fold(counts, spec: BucketSpec):
     # integer-count banks always fold on the exact XLA path: the Pallas fold
     # accumulates in float32, which would silently round counts above 2^24 —
     # the very regime integer counts_dtype exists for.
-    if use_kernel and counts.dtype == jnp.float32:
+    if counts.dtype == jnp.float32:
         from repro.kernels import ops
 
         return ops.fold_pairs(counts, spec=spec)
@@ -282,7 +280,7 @@ def _fold(counts, spec: BucketSpec, use_kernel: bool):
 
 
 def collapse(
-    sketch: DeviceSketch, *, spec: BucketSpec, use_kernel: bool = False
+    sketch: DeviceSketch, *, spec: BucketSpec
 ) -> DeviceSketch:
     """One uniform-collapse step: fold pos/neg bucket pairs, level += 1.
 
@@ -292,14 +290,14 @@ def collapse(
     ``MAX_COLLAPSE_LEVEL`` (``collapse_to`` / ``auto_collapse`` do).
     """
     return sketch._replace(
-        pos=_fold(sketch.pos, spec, use_kernel),
-        neg=_fold(sketch.neg, spec, use_kernel),
+        pos=_fold(sketch.pos, spec),
+        neg=_fold(sketch.neg, spec),
         level=sketch.level + 1,
     )
 
 
 def collapse_to(
-    sketch: DeviceSketch, target, *, spec: BucketSpec, use_kernel: bool = False
+    sketch: DeviceSketch, target, *, spec: BucketSpec
 ) -> DeviceSketch:
     """Fold until ``level >= target`` (clamped to ``MAX_COLLAPSE_LEVEL``).
 
@@ -309,7 +307,7 @@ def collapse_to(
     target = jnp.clip(jnp.asarray(target, jnp.int32), 0, MAX_COLLAPSE_LEVEL)
     return jax.lax.while_loop(
         lambda s: s.level < target,
-        lambda s: collapse(s, spec=spec, use_kernel=use_kernel),
+        lambda s: collapse(s, spec=spec),
         sketch,
     )
 
@@ -319,7 +317,6 @@ def auto_collapse(
     *,
     spec: BucketSpec,
     threshold: float = 0.0,
-    use_kernel: bool = False,
 ) -> DeviceSketch:
     """Reactive collapse: fold once when clamped mass exceeds ``threshold``.
 
@@ -333,7 +330,7 @@ def auto_collapse(
     fire = (sketch.overflow + sketch.underflow > threshold) & (
         sketch.level < MAX_COLLAPSE_LEVEL
     )
-    folded = collapse(sketch, spec=spec, use_kernel=use_kernel)
+    folded = collapse(sketch, spec=spec)
     folded = folded._replace(
         overflow=jnp.zeros_like(sketch.overflow),
         underflow=jnp.zeros_like(sketch.underflow),

@@ -140,22 +140,26 @@ def picked_insert_method(
     num_buckets: int,
     *,
     unit_weights: bool = True,
-    use_kernel: bool = False,
+    on_tpu: bool | None = None,
 ) -> str:
     """The pipeline ``add_impl(..., method=None)`` resolves to.
 
     ``kernels.ops.insert_method`` plus this module's one adjustment: on the
-    ref tier a small bank (the dense (K, N) stats regime) keeps the
-    two-pass sort path, since the dense masked reductions beat the fused
-    segment stats there.  Benches record this so every timing row names the
-    pipeline the auto heuristic actually ran.
+    ref tier (``on_tpu`` false; None asks the default backend) a small bank
+    (the dense (K, N) stats regime) keeps the two-pass sort path, since the
+    dense masked reductions beat the fused segment stats there.  Benches
+    record this so every timing row names the pipeline the auto heuristic
+    actually ran.
     """
     from repro.kernels import ops
 
+    if on_tpu is None:
+        on_tpu = ops._on_tpu()
     method = ops.insert_method(
-        n, k, num_buckets, unit_weights=unit_weights, full_ingest=True
+        n, k, num_buckets, unit_weights=unit_weights, on_tpu=on_tpu,
+        full_ingest=True,
     )
-    if method == "fused" and _dense_stats_applies(n, k) and not use_kernel:
+    if method == "fused" and _dense_stats_applies(n, k) and not on_tpu:
         method = "sort"
     return method
 
@@ -167,7 +171,6 @@ def add_impl(
     weights: jnp.ndarray | None = None,
     *,
     spec: BucketSpec,
-    use_kernel: bool = False,
     auto_collapse: bool = False,
     method: str | None = None,
 ) -> SketchBank:
@@ -237,7 +240,7 @@ def add_impl(
     if method is None:
         method = picked_insert_method(
             x.size, k, spec.num_buckets,
-            unit_weights=raw_w is None, use_kernel=use_kernel,
+            unit_weights=raw_w is None,
         )
 
     if method == "fused":
@@ -249,7 +252,6 @@ def add_impl(
             shifts,
             num_segments=k,
             spec=spec,
-            force=None if use_kernel else "ref",
         )
         cd = bank.pos.dtype
         return SketchBank(
@@ -272,7 +274,6 @@ def add_impl(
         num_segments=k,
         spec=spec,
         method=method,
-        force=None if use_kernel else "ref",
     )
 
     # clamp accounting: shifted keys that escape [offset, offset + m - 1]
@@ -320,7 +321,7 @@ def add_impl(
 
 
 add = partial(
-    jax.jit, static_argnames=("spec", "use_kernel", "auto_collapse", "method")
+    jax.jit, static_argnames=("spec", "auto_collapse", "method")
 )(add_impl)
 
 
@@ -335,7 +336,6 @@ def collapse(
     rows: jnp.ndarray | None = None,
     *,
     spec: BucketSpec,
-    use_kernel: bool = False,
 ) -> SketchBank:
     """One uniform-collapse step on the selected rows (all rows if None).
 
@@ -348,8 +348,8 @@ def collapse(
         if rows is None
         else jnp.asarray(rows, bool)
     )
-    pos_f = _fold(bank.pos, spec, use_kernel)
-    neg_f = _fold(bank.neg, spec, use_kernel)
+    pos_f = _fold(bank.pos, spec)
+    neg_f = _fold(bank.neg, spec)
     return bank._replace(
         pos=jnp.where(mask[:, None], pos_f, bank.pos),
         neg=jnp.where(mask[:, None], neg_f, bank.neg),
@@ -358,7 +358,7 @@ def collapse(
 
 
 def collapse_to(
-    bank: SketchBank, target, *, spec: BucketSpec, use_kernel: bool = False
+    bank: SketchBank, target, *, spec: BucketSpec
 ) -> SketchBank:
     """Fold each row until its level reaches ``target`` (scalar or (K,)).
 
@@ -371,7 +371,7 @@ def collapse_to(
     )
     return jax.lax.while_loop(
         lambda b: (b.level < target).any(),
-        lambda b: collapse(b, b.level < target, spec=spec, use_kernel=use_kernel),
+        lambda b: collapse(b, b.level < target, spec=spec),
         bank,
     )
 
@@ -381,7 +381,6 @@ def auto_collapse(
     *,
     spec: BucketSpec,
     threshold: float = 0.0,
-    use_kernel: bool = False,
 ) -> SketchBank:
     """Reactive collapse: fold rows whose clamped mass exceeds ``threshold``.
 
@@ -392,7 +391,7 @@ def auto_collapse(
     fire = (bank.overflow + bank.underflow > threshold) & (
         bank.level < MAX_COLLAPSE_LEVEL
     )
-    folded = collapse(bank, fire, spec=spec, use_kernel=use_kernel)
+    folded = collapse(bank, fire, spec=spec)
     return folded._replace(
         overflow=jnp.where(fire, jnp.zeros_like(bank.overflow), bank.overflow),
         underflow=jnp.where(fire, jnp.zeros_like(bank.underflow), bank.underflow),
@@ -488,7 +487,6 @@ def quantiles_impl(
     qs: jnp.ndarray,
     *,
     spec: BucketSpec,
-    use_kernel: bool = False,
     table: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Per-row quantile estimates, shape ``(K, len(qs))``.
@@ -501,8 +499,8 @@ def quantiles_impl(
     counts *once* and answers every q off that cumsum — no per-(row, q)
     rebuilds, no Python loop anywhere.  Bit-identical to querying each row
     as a standalone DeviceSketch at its own collapse level; all-empty rows
-    answer NaN.  ``use_kernel=True`` routes to the Pallas row-tile kernel
-    (TPU; elsewhere it falls back to the fused XLA twin).
+    answer NaN.  On TPU this is the Pallas row-tile kernel; elsewhere the
+    fused XLA twin (``kernels.ops``' automatic choice).
     """
     qf = jnp.atleast_1d(jnp.asarray(qs, jnp.float32))
     from repro.kernels import ops
@@ -516,19 +514,18 @@ def quantiles_impl(
         bank.level,
         qf,
         spec=spec,
-        force=None if use_kernel else "ref",
         table=table,
     )
 
 
-quantiles = partial(jax.jit, static_argnames=("spec", "use_kernel"))(quantiles_impl)
+quantiles = partial(jax.jit, static_argnames=("spec",))(quantiles_impl)
 
 
-@partial(jax.jit, static_argnames=("spec", "use_kernel"))
+@partial(jax.jit, static_argnames=("spec",))
 def quantile(
-    bank: SketchBank, q, *, spec: BucketSpec, use_kernel: bool = False
+    bank: SketchBank, q, *, spec: BucketSpec
 ) -> jnp.ndarray:
     """One quantile for every row, shape ``(K,)`` (NaN for empty rows)."""
-    return quantiles_impl(bank, jnp.asarray([q]), spec=spec, use_kernel=use_kernel)[
+    return quantiles_impl(bank, jnp.asarray([q]), spec=spec)[
         :, 0
     ]

@@ -77,7 +77,6 @@ def shared_engine(
     num_sketches: int,
     *,
     counts_dtype=jnp.float32,
-    use_kernel: bool = False,
     method: str | None = None,
 ) -> "SketchEngine":
     """Process-wide engine registry, one per bank geometry.
@@ -92,7 +91,6 @@ def shared_engine(
         spec,
         num_sketches,
         counts_dtype=counts_dtype,
-        use_kernel=use_kernel,
         method=method,
     )
 
@@ -110,7 +108,6 @@ def window_merge_bank(
     live: jnp.ndarray,
     *,
     spec: BucketSpec,
-    use_kernel: bool = False,
 ) -> SketchBank:
     """Traced body of a window query: gather + fused range merge -> one bank.
 
@@ -186,7 +183,6 @@ def window_merge_bank(
             jnp.concatenate([delta, delta], axis=1),
             spec=spec,
             valid=mask,
-            force=None if use_kernel else "ref",
         )
         return merged[:k], merged[k:]
 
@@ -219,8 +215,9 @@ class SketchEngine:
     (donated) like any jax state, so one engine can drive many banks of the
     same geometry.  ``new_bank()`` mints a fresh one.
 
-    ``use_kernel`` / ``method`` pin the kernel backend and insert pipeline
-    exactly as ``sketch_bank.add`` does; ``collapse_threshold`` semantics
+    ``method`` pins the insert pipeline exactly as ``sketch_bank.add``
+    does (the backend is ``kernels.ops``' automatic choice: the compiled
+    Pallas kernels on TPU, the XLA reference elsewhere); ``collapse_threshold`` semantics
     live at the call site (``ingest(..., threshold=)``), not here, so one
     executable serves every threshold value.
     """
@@ -231,13 +228,11 @@ class SketchEngine:
         num_sketches: int,
         *,
         counts_dtype=jnp.float32,
-        use_kernel: bool = False,
         method: str | None = None,
     ):
         self.spec = spec
         self.num_sketches = int(num_sketches)
         self.counts_dtype = jax_sketch._counts_dtype(counts_dtype)
-        self.use_kernel = use_kernel
         self.method = method
         self._cache: dict[tuple, Any] = {}
         self._hits = 0
@@ -481,7 +476,6 @@ class SketchEngine:
                 ss,
                 ww,
                 spec=self.spec,
-                use_kernel=self.use_kernel,
                 auto_collapse=auto_collapse,
                 method=self.method,
             )
@@ -489,7 +483,7 @@ class SketchEngine:
                 return b
             clamped = (b.overflow + b.underflow).astype(jnp.float32)
             fire = (clamped > thr) & (b.level < MAX_COLLAPSE_LEVEL)
-            folded = sbank.collapse(b, fire, spec=self.spec, use_kernel=self.use_kernel)
+            folded = sbank.collapse(b, fire, spec=self.spec)
             b = folded._replace(
                 overflow=_zero_where(fire, b.overflow),
                 underflow=_zero_where(fire, b.underflow),
@@ -523,7 +517,7 @@ class SketchEngine:
         )
 
         def collapse_impl(b, t):
-            return sbank.collapse_to(b, t, spec=self.spec, use_kernel=self.use_kernel)
+            return sbank.collapse_to(b, t, spec=self.spec)
 
         return self._compiled(
             ("collapse_to",),
@@ -540,7 +534,7 @@ class SketchEngine:
 
         def auto_impl(b, thr):
             return sbank.auto_collapse(
-                b, spec=self.spec, threshold=thr, use_kernel=self.use_kernel
+                b, spec=self.spec, threshold=thr
             )
 
         thr = jnp.asarray(threshold, jnp.float32)
@@ -672,7 +666,7 @@ class SketchEngine:
 
         def query_impl(sl, b, nd, vm, lv, q, t):
             mb = window_merge_bank(
-                sl, b, nd, vm, lv, spec=self.spec, use_kernel=self.use_kernel
+                sl, b, nd, vm, lv, spec=self.spec
             )
             return ops.bank_quantiles(
                 mb.pos,
@@ -683,7 +677,6 @@ class SketchEngine:
                 mb.level,
                 q,
                 spec=self.spec,
-                force=None if self.use_kernel else "ref",
                 table=t,
             )
 
@@ -719,14 +712,13 @@ class SketchEngine:
 
         def rollup_impl(sl, b, nd, vm, lv, q, t):
             mb = window_merge_bank(
-                sl, b, nd, vm, lv, spec=self.spec, use_kernel=self.use_kernel
+                sl, b, nd, vm, lv, spec=self.spec
             )
             gmax = jnp.max(mb.level)
             mb = sbank.collapse_to(
                 mb,
                 jnp.broadcast_to(gmax, mb.level.shape),
                 spec=self.spec,
-                use_kernel=self.use_kernel,
             )
             return bank_quantiles_ref(
                 mb.pos.sum(0)[None],
@@ -768,7 +760,7 @@ class SketchEngine:
 
         def quantiles_impl(b, q, t):
             return sbank.quantiles_impl(
-                b, q, spec=self.spec, use_kernel=self.use_kernel, table=t
+                b, q, spec=self.spec, table=t
             )
 
         return self._compiled(
@@ -806,7 +798,6 @@ class SketchEngine:
                 b,
                 jnp.broadcast_to(gmax, b.level.shape),
                 spec=self.spec,
-                use_kernel=self.use_kernel,
             )
             f32 = jnp.float32
             return bank_quantiles_ref(

@@ -364,7 +364,6 @@ class ShardedEngine(SketchEngine):
                 b,
                 jnp.broadcast_to(gmax, b.level.shape),
                 spec=spec,
-                use_kernel=self.use_kernel,
             )
             f32 = jnp.float32
             pos = jax.lax.psum(b.pos.astype(f32).sum(0), BANK_ROW_AXIS)
@@ -414,14 +413,13 @@ class ShardedEngine(SketchEngine):
 
         def rollup_impl(sl, b, nd, vm, lv, q, t):
             mb = window_merge_bank(
-                sl, b, nd, vm, lv, spec=spec, use_kernel=self.use_kernel
+                sl, b, nd, vm, lv, spec=spec
             )
             gmax = jax.lax.pmax(jnp.max(mb.level), BANK_ROW_AXIS)
             mb = sbank.collapse_to(
                 mb,
                 jnp.broadcast_to(gmax, mb.level.shape),
                 spec=spec,
-                use_kernel=self.use_kernel,
             )
             pos = jax.lax.psum(mb.pos.sum(0), BANK_ROW_AXIS)
             neg = jax.lax.psum(mb.neg.sum(0), BANK_ROW_AXIS)
@@ -482,7 +480,6 @@ class ShardedBank:
         *,
         num_shards: int | None = None,
         counts_dtype=jnp.float32,
-        use_kernel: bool = False,
         method: str | None = None,
     ):
         self.engine = ShardedEngine(
@@ -490,7 +487,6 @@ class ShardedBank:
             num_sketches,
             num_shards=num_shards,
             counts_dtype=counts_dtype,
-            use_kernel=use_kernel,
             method=method,
         )
         self.state = self.engine.new_bank()

@@ -22,8 +22,11 @@ products are counts * {0, 1}, so every accumulation is an exact f32 sum of
 integer-valued counts — bit-identical to ``ref.bank_range_merge_ref`` and
 to sequential ``sketch_bank.merge`` folds.
 
-Grid = (row_tiles, bucket_tiles, D); block shapes: counts ``(1, TR, m)``,
-deltas ``(1, TR, 1)``, out ``(TR, TB)`` revisited across d.
+Grid = (row_tiles, bucket_tiles, D); block shapes: counts ``(1, TR, m)``
+for the folds plus the same counts as a ``(1, TR, TB)`` block for the
+identity term (a lane-offset slice of a loaded value does not lower on
+Mosaic; a second block spec lets the DMA deliver it), deltas
+``(1, TR, 1)``, out ``(TR, TB)`` revisited across d.
 
 VMEM budget per step (defaults TR=8, TB=512, m=2048, f32):
   counts (TR, m) 64 KiB + F (m, TB) 4 MiB + out tile 16 KiB << 16 MiB.
@@ -50,7 +53,7 @@ __all__ = ["bank_range_merge_pallas"]
 
 
 def _range_merge_kernel(
-    counts_ref, deltas_ref, out_ref, *, offset: int, bucket_tile: int
+    counts_ref, tile_ref, deltas_ref, out_ref, *, offset: int, bucket_tile: int
 ):
     j = pl.program_id(1)  # bucket-tile index (parallel)
     d = pl.program_id(2)  # slice index (sequential; accumulates into out)
@@ -59,9 +62,9 @@ def _range_merge_kernel(
     delta = deltas_ref[0]  # (TR, 1) int32 per-row fold depth of this slice
     m = x.shape[1]
 
-    # delta == 0 contribution: identity fold, a direct column slice
-    tile = jax.lax.dynamic_slice_in_dim(x, j * bucket_tile, bucket_tile, 1)
-    acc = jnp.where(delta == 0, tile, 0.0)
+    # delta == 0 contribution: identity fold — the output tile's own
+    # columns, delivered as a second (TR, TB) block of the same counts
+    acc = jnp.where(delta == 0, tile_ref[0], 0.0)
     # delta >= 1 contributions: one one-hot fold matrix per level, built
     # from iotas (same exact int math as ref.multi_fold_destinations)
     src = jax.lax.broadcasted_iota(jnp.int32, (m, bucket_tile), 0)
@@ -142,10 +145,11 @@ def bank_range_merge_pallas(
         grid=(nr, nb, num_slices),
         in_specs=[
             pl.BlockSpec((1, row_tile, m), lambda i, j, d: (d, i, 0)),
+            pl.BlockSpec((1, row_tile, bucket_tile), lambda i, j, d: (d, i, j)),
             pl.BlockSpec((1, row_tile, 1), lambda i, j, d: (d, i, 0)),
         ],
         out_specs=pl.BlockSpec((row_tile, bucket_tile), lambda i, j, d: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_padded, m), jnp.float32),
         interpret=interpret,
-    )(x, dl)
+    )(x, x, dl)
     return out[:r]

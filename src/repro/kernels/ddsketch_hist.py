@@ -5,10 +5,12 @@ reference implementations do a scalar log + hash-map increment; neither maps
 to a TPU (no fast random scatter; scalar loops waste the VPU).  The
 TPU-native formulation (DESIGN.md §3):
 
-* the *mapping* is evaluated vectorized on the VPU — either a true log
-  ("log" mapping) or the paper's §2.2 "costless log2 from the float's binary
+* the *mapping* is evaluated vectorized — either a true log ("log"
+  mapping) or the paper's §2.2 "costless log2 from the float's binary
   representation" trick, which lowers to integer bitcast/shift/mask ops
-  ("linear"/"cubic" mappings, the DDSketch-fast variants);
+  ("linear"/"cubic" mappings, the DDSketch-fast variants).  It runs as one
+  XLA elementwise pass (``ref.level_keys``) feeding the kernel int32 keys,
+  so the kernel buckets with the reference's own log;
 * the *scatter* becomes a compare-against-iota one-hot reduction: a
   (bucket_tile, value_tile) boolean match matrix is contracted against the
   weights along the value axis.  Everything stays in VMEM/VREGs.
@@ -34,27 +36,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import BucketSpec, approx_log2, shift_key
+from repro.kernels.ref import BucketSpec, level_keys
 
 __all__ = ["histogram_pallas"]
 
 
 def _hist_kernel(
-    vals_ref, w_ref, lev_ref, out_ref, *, spec: BucketSpec, bucket_tile: int
+    vals_ref, w_ref, key_ref, out_ref, *, spec: BucketSpec, bucket_tile: int
 ):
     i = pl.program_id(0)  # bucket-tile index (parallel)
     j = pl.program_id(1)  # value-tile index (sequential reduction)
 
     x = vals_ref[...]  # (1, TV) float32
     w = w_ref[...]  # (1, TV) float32
-    lev = lev_ref[...]  # (1, TV) int32 per-value collapse levels
+    k0 = key_ref[...]  # (1, TV) int32 collapse-level keys (ref.level_keys)
 
     mask = jnp.isfinite(x) & (x > spec.min_indexable)
-    safe = jnp.where(mask, x, 1.0)
-    # ceil(log_gamma(x)) == ceil(approx_log2(x) * multiplier); float32 math
-    # identical to ref.bucket_index so host/device/kernel agree exactly.
-    key = jnp.ceil(approx_log2(safe, spec.mapping) * jnp.float32(spec.multiplier))
-    k0 = shift_key(key.astype(jnp.int32), lev)  # collapse-level key shift
     idx = jnp.clip(k0 - spec.offset, 0, spec.num_buckets - 1)
     w = jnp.where(mask, w, 0.0)
 
@@ -63,7 +60,7 @@ def _hist_kernel(
     rows = jax.lax.broadcasted_iota(jnp.int32, (bucket_tile, tv), 0)
     bucket_ids = rows + i * bucket_tile
     match = idx == bucket_ids  # (1,TV) vs (TB,TV) -> (TB,TV)
-    partial = jnp.sum(jnp.where(match, w, 0.0), axis=1)[None, :]  # (1, TB)
+    partial = jnp.sum(jnp.where(match, w, 0.0), axis=1, keepdims=True)  # (TB, 1)
 
     @pl.when(j == 0)
     def _init():
@@ -118,20 +115,22 @@ def histogram_pallas(
         lev = jnp.pad(lev, (0, pad), constant_values=0)
     nv = x.shape[0] // value_tile
     nb = spec.num_buckets // bucket_tile
-    x = x.reshape(nv, value_tile)
-    w = w.reshape(nv, value_tile)
-    lev = lev.reshape(nv, value_tile)
+    # lanes ride as one (1, N) row so each (1, TV) block is tile-aligned
+    kl = level_keys(x, lev, spec)  # XLA's log, as on the reference tier
+    x, w, kl = (a.reshape(1, -1) for a in (x, w, kl))
 
     out = pl.pallas_call(
         functools.partial(_hist_kernel, spec=spec, bucket_tile=bucket_tile),
         grid=(nb, nv),
         in_specs=[
-            pl.BlockSpec((1, value_tile), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, value_tile), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, value_tile), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, value_tile), lambda i, j: (0, j)),
+            pl.BlockSpec((1, value_tile), lambda i, j: (0, j)),
+            pl.BlockSpec((1, value_tile), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bucket_tile), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, bucket_tile), jnp.float32),
+        # buckets on the sublane axis: each step's (TB, 1) column is the
+        # lane reduction's natural layout, no lane<->sublane relayout
+        out_specs=pl.BlockSpec((bucket_tile, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((spec.num_buckets, 1), jnp.float32),
         interpret=interpret,
-    )(x, w, lev)
+    )(x, w, kl)
     return out.reshape(spec.num_buckets)

@@ -5,12 +5,12 @@ bucketize -> XLA sort/segment-sum -> ``ddsketch_scatter`` kernel) with full
 HBM round trips of the (N,)-sized intermediates between stages, and then
 ``sketch_bank.add_impl`` makes a *second* pass over the lanes for the aux
 stats (zero / overflow / underflow / sum / extrema).  This kernel collapses
-the whole ingest into **one** program: each value tile is bucketized
-in-kernel (the exact ``ref.bucket_index`` float32 math, including the
-per-lane uniform-collapse ``shift_key``), binned into the combined
-``(2K, m)`` pos/neg layout with the input-stationary resident-row trick of
-``ddsketch_scatter``, and folded into the six per-row aux stats — the lanes
-are read from HBM once, ever.
+the whole ingest into **one** program: each value tile, with its int32
+collapse-level keys (``ref.level_keys``: the reference's own XLA log and
+``shift_key``, one elementwise pass ahead of the kernel), is binned into the
+combined ``(2K, m)`` pos/neg layout with the input-stationary resident-row
+trick of ``ddsketch_scatter`` and folded into the six per-row aux stats —
+no sort stage and no second stats pass over the lanes.
 
 Layout: grid = (bucket_tiles, value_tiles), value axis innermost
 (sequential reduction).  Three outputs:
@@ -28,12 +28,13 @@ Layout: grid = (bucket_tiles, value_tiles), value axis innermost
   sublane-axis reduction of the masked ``(TV, K_pad)`` broadcast, again only
   on ``j == 0``.
 
-VMEM budget per step (defaults TV=1024, TB=512, f32, worst-case
-2K = 1024 resident rows, K_pad = 512): streams 16 KiB + A (1024, 1024)
-4 MiB + M (1024, 512) 2 MiB + hist tile (1024, 512) 2 MiB + stats one-hot /
-masked broadcasts 3 x (1024, 512) 6 MiB + stats tiles 32 KiB ~= 14 MiB
-< 16 MiB — which is why the default ``value_tile`` here is 1024, not the
-2048 the stats-free scatter kernel uses.
+VMEM: at the worst case 2K = 1024 resident rows (K_pad = 512) and TB=512,
+Mosaic's scoped allocation for one step is 4.0 MiB at the default TV=512
+and 22.2 MiB at TV=1024 (compiled for a described v5e), against the
+16 MiB default scoped limit — hence ``value_tile`` 512.  The lanes ride as
+one (1, N) row so each (1, TV) block is tile-aligned, and the extrema's
+"contributes" mask is formed after the lane->sublane reshape of the f32
+weights (Mosaic cannot relayout a bool lane vector).
 
 Counter outputs (sums of ``w * {0, 1}``) and extrema match
 ``ref.fused_ingest_ref`` exactly; the float ``summ`` row accumulates in
@@ -51,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.ddsketch_scatter import MAX_RESIDENT_ROWS
-from repro.kernels.ref import BucketSpec, IngestStats, approx_log2, shift_key
+from repro.kernels.ref import BucketSpec, IngestStats, level_keys
 
 __all__ = ["ddsketch_ingest_pallas"]
 
@@ -60,7 +61,7 @@ def _ingest_kernel(
     vals_ref,
     w_ref,
     seg_ref,
-    lev_ref,
+    key_ref,
     hist_ref,
     sums_ref,
     ext_ref,
@@ -75,7 +76,7 @@ def _ingest_kernel(
     x = vals_ref[...]  # (1, TV) float32
     w = w_ref[...]  # (1, TV) float32
     seg = seg_ref[...]  # (1, TV) int32
-    lev = lev_ref[...]  # (1, TV) int32 per-value collapse levels
+    k_lev = key_ref[...]  # (1, TV) int32 collapse-level keys (ref.level_keys)
 
     k = num_segments
     valid = jnp.isfinite(x) & (seg >= 0) & (seg < k)
@@ -85,11 +86,7 @@ def _ingest_kernel(
     is_neg = valid & (x < -spec.min_indexable)
     is_zero = valid & ~is_pos & ~is_neg
 
-    # one in-register key pass: histogram index AND clamp accounting
-    # (float32 math identical to ref.bucket_index, so all tiers agree)
-    mag = jnp.where(is_pos | is_neg, jnp.abs(x), 1.0)
-    key = jnp.ceil(approx_log2(mag, spec.mapping) * jnp.float32(spec.multiplier))
-    k_lev = shift_key(key.astype(jnp.int32), lev)
+    # the keys feed the histogram index AND the clamp accounting
     idx = jnp.clip(k_lev - spec.offset, 0, spec.num_buckets - 1)
     top_key = spec.offset + spec.num_buckets - 1
     over = (is_pos | is_neg) & (k_lev > top_key)
@@ -146,9 +143,13 @@ def _ingest_kernel(
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
         )
-        contrib = (valid & (w > 0.0)).reshape(tv, 1)
-        xin = jnp.where(sel & contrib, x.reshape(tv, 1), jnp.inf)
-        nxin = jnp.where(sel & contrib, -x.reshape(tv, 1), jnp.inf)
+        # w is already zero off the valid lanes, so w > 0 is the whole
+        # "contributes" test; reshape the f32 lanes to a column and compare
+        # there (Mosaic cannot relayout a bool lane vector to sublanes)
+        xc = x.reshape(tv, 1)
+        contrib = sel & (w.reshape(tv, 1) > 0.0)
+        xin = jnp.where(contrib, xc, jnp.inf)
+        nxin = jnp.where(contrib, -xc, jnp.inf)
         vmin_p = jnp.min(xin, axis=0, keepdims=True)  # (1, KP)
         nmax_p = jnp.min(nxin, axis=0, keepdims=True)  # (1, KP): -vmax
         inf_row = jnp.full_like(vmin_p, jnp.inf)
@@ -178,7 +179,7 @@ def ddsketch_ingest_pallas(
     *,
     num_segments: int,
     spec: BucketSpec,
-    value_tile: int = 1024,
+    value_tile: int = 512,
     bucket_tile: int = 512,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, IngestStats]:
@@ -244,10 +245,10 @@ def ddsketch_ingest_pallas(
     k_padded = k + ((-k) % 128)  # stats lane axis
     nv = x.shape[0] // value_tile
     nb = buckets_padded // bucket_tile
-    x = x.reshape(nv, value_tile)
-    s = s.reshape(nv, value_tile)
-    w = w.reshape(nv, value_tile)
-    lev = lev.reshape(nv, value_tile)
+    # lanes ride as one (1, N) row: a (1, TV) block spans the whole
+    # second-minor dimension, the layout Mosaic tiles without padding rows
+    kl = level_keys(x, lev, spec)  # XLA's log, as on the reference tier
+    x, s, w, kl = (a.reshape(1, -1) for a in (x, s, w, kl))
 
     hist, sums, ext = pl.pallas_call(
         functools.partial(
@@ -258,10 +259,10 @@ def ddsketch_ingest_pallas(
         ),
         grid=(nb, nv),
         in_specs=[
-            pl.BlockSpec((1, value_tile), lambda j, v: (v, 0)),
-            pl.BlockSpec((1, value_tile), lambda j, v: (v, 0)),
-            pl.BlockSpec((1, value_tile), lambda j, v: (v, 0)),
-            pl.BlockSpec((1, value_tile), lambda j, v: (v, 0)),
+            pl.BlockSpec((1, value_tile), lambda j, v: (0, v)),
+            pl.BlockSpec((1, value_tile), lambda j, v: (0, v)),
+            pl.BlockSpec((1, value_tile), lambda j, v: (0, v)),
+            pl.BlockSpec((1, value_tile), lambda j, v: (0, v)),
         ],
         out_specs=[
             pl.BlockSpec((rows_padded, bucket_tile), lambda j, v: (0, j)),
@@ -274,7 +275,7 @@ def ddsketch_ingest_pallas(
             jax.ShapeDtypeStruct((8, k_padded), jnp.float32),
         ],
         interpret=interpret,
-    )(x, w, s, lev)
+    )(x, w, s, kl)
     stats = IngestStats(
         zero=sums[0, :k],
         overflow=sums[1, :k],

@@ -21,12 +21,14 @@ build ``A[r, t] = w[t] * (row(t) == r)`` (R, TT) against the bucket one-hot
 
 Because the rows are not tiled, ``rows_padded * bucket_tile`` floats must
 fit in VMEM next to A and M — fine for the telemetry-bank regime (2K <=
-~1024 rows); the ops dispatcher falls back to the matmul-histogram kernel
+1024 rows); the ops dispatcher falls back to the matmul-histogram kernel
 beyond that.
 
-VMEM budget per step (defaults TT=2048, TB=512, R=256, f32):
-  keys+weights 16 KiB + A (R, TT) 2 MiB + M (TT, TB) 4 MiB
-  + out tile (R, TB) 512 KiB << 16 MiB.
+VMEM: at the worst case R = 2K = 1024 resident rows and TB=512, Mosaic's
+scoped allocation for one step is 4.0 MiB at the default TT=512, 22.0 MiB
+at TT=1024 and 38.6 MiB at TT=2048 (compiled for a described v5e) — the
+default scoped limit is 16 MiB, so the triple tile stays at 512.  The
+(1, N) lane layout keeps each (1, TT) block tile-aligned.
 
 With unique keys (what ``compact_triples`` emits) every output bucket
 receives one real add plus zeros, so the kernel matches
@@ -45,8 +47,9 @@ from jax.experimental import pallas as pl
 
 __all__ = ["MAX_RESIDENT_ROWS", "ddsketch_scatter_pallas"]
 
-# Row ceiling keeping the resident (rows, bucket_tile) output tile + the
-# (rows, triple_tile) one-hot comfortably inside VMEM at the default tiles.
+# Row ceiling of the resident (rows, bucket_tile) output tile: at 1024 rows
+# and the default 512-lane triple / value tiles, this kernel and the fused
+# ingest each take ~4 MiB of scoped VMEM on a v5e (16 MiB default limit).
 MAX_RESIDENT_ROWS = 1024
 
 
@@ -113,7 +116,7 @@ def ddsketch_scatter_pallas(
     *,
     num_rows: int,
     num_buckets: int,
-    triple_tile: int = 2048,
+    triple_tile: int = 512,
     bucket_tile: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -149,8 +152,9 @@ def ddsketch_scatter_pallas(
     buckets_padded = num_buckets + ((-num_buckets) % bucket_tile)
     nt = k.shape[0] // triple_tile
     nb = buckets_padded // bucket_tile
-    k = k.reshape(nt, triple_tile)
-    w = w.reshape(nt, triple_tile)
+    # triples ride as one (1, N) row so each (1, TT) block is tile-aligned
+    k = k.reshape(1, -1)
+    w = w.reshape(1, -1)
 
     out = pl.pallas_call(
         functools.partial(
@@ -161,8 +165,8 @@ def ddsketch_scatter_pallas(
         ),
         grid=(nb, nt),
         in_specs=[
-            pl.BlockSpec((1, triple_tile), lambda j, t: (t, 0)),
-            pl.BlockSpec((1, triple_tile), lambda j, t: (t, 0)),
+            pl.BlockSpec((1, triple_tile), lambda j, t: (0, t)),
+            pl.BlockSpec((1, triple_tile), lambda j, t: (0, t)),
         ],
         out_specs=pl.BlockSpec((rows_padded, bucket_tile), lambda j, t: (0, j)),
         out_shape=jax.ShapeDtypeStruct((rows_padded, buckets_padded), jnp.float32),

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ref import BucketSpec, approx_log2, shift_key
+from repro.kernels.ref import BucketSpec, level_keys
 
 __all__ = ["segment_histogram_pallas"]
 
@@ -48,7 +48,7 @@ def _seg_hist_kernel(
     vals_ref,
     w_ref,
     seg_ref,
-    lev_ref,
+    key_ref,
     out_ref,
     *,
     spec: BucketSpec,
@@ -63,7 +63,7 @@ def _seg_hist_kernel(
     x = vals_ref[...]  # (1, TV) float32
     w = w_ref[...]  # (1, TV) float32
     seg = seg_ref[...]  # (1, TV) int32
-    lev = lev_ref[...]  # (1, TV) int32 per-value collapse levels
+    k0 = key_ref[...]  # (1, TV) int32 collapse-level keys (ref.level_keys)
 
     mask = (
         jnp.isfinite(x)
@@ -71,11 +71,6 @@ def _seg_hist_kernel(
         & (seg >= 0)
         & (seg < num_segments)
     )
-    safe = jnp.where(mask, x, 1.0)
-    # ceil(log_gamma(x)) == ceil(approx_log2(x) * multiplier); float32 math
-    # identical to ref.bucket_index so ref/kernel agree exactly.
-    key = jnp.ceil(approx_log2(safe, spec.mapping) * jnp.float32(spec.multiplier))
-    k0 = shift_key(key.astype(jnp.int32), lev)  # collapse-level key shift
     idx = jnp.clip(k0 - spec.offset, 0, spec.num_buckets - 1)
     w = jnp.where(mask, w, 0.0)
 
@@ -173,10 +168,9 @@ def segment_histogram_pallas(
     nv = x.shape[0] // value_tile
     nr = rows_padded // row_tile
     nb = buckets_padded // bucket_tile
-    x = x.reshape(nv, value_tile)
-    s = s.reshape(nv, value_tile)
-    w = w.reshape(nv, value_tile)
-    lev = lev.reshape(nv, value_tile)
+    # lanes ride as one (1, N) row so each (1, TV) block is tile-aligned
+    kl = level_keys(x, lev, spec)  # XLA's log, as on the reference tier
+    x, s, w, kl = (a.reshape(1, -1) for a in (x, s, w, kl))
 
     out = pl.pallas_call(
         functools.partial(
@@ -188,13 +182,13 @@ def segment_histogram_pallas(
         ),
         grid=(nr, nb, nv),
         in_specs=[
-            pl.BlockSpec((1, value_tile), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((1, value_tile), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((1, value_tile), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((1, value_tile), lambda i, j, k: (k, 0)),
+            pl.BlockSpec((1, value_tile), lambda i, j, k: (0, k)),
+            pl.BlockSpec((1, value_tile), lambda i, j, k: (0, k)),
+            pl.BlockSpec((1, value_tile), lambda i, j, k: (0, k)),
+            pl.BlockSpec((1, value_tile), lambda i, j, k: (0, k)),
         ],
         out_specs=pl.BlockSpec((row_tile, bucket_tile), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_padded, buckets_padded), jnp.float32),
         interpret=interpret,
-    )(x, w, s, lev)
+    )(x, w, s, kl)
     return out[:num_segments, : spec.num_buckets]

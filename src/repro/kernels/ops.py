@@ -17,7 +17,10 @@ shapes, dtypes, mappings and tile configurations asserting exact agreement.
   requesting it off-TPU raises immediately instead),
 * ``None``         — auto: compiled kernel on TPU *when the batch fills at
   least one tile* (padding a sub-tile batch to ``value_tile`` costs more
-  than the XLA scatter it replaces), reference elsewhere.
+  than the XLA scatter it replaces), reference elsewhere.  Every TPU detour
+  to the reference (sub-tile batch, bank taller than the resident rows,
+  sub-tile query row axis) is counted in ``dispatch_stats()`` and warned
+  once per site, so a served path that left the chip's kernels shows it.
 
 ``bank_histograms`` is the bank-insert front door: it routes a batch of
 ``(value, segment)`` pairs to the matmul-histogram formulation (work
@@ -73,6 +76,7 @@ __all__ = [
     "bank_range_merge",
     "insert_method",
     "dispatch_stats",
+    "fallback_count",
     "reset_dispatch_stats",
     "BucketSpec",
     "IngestStats",
@@ -82,25 +86,24 @@ _FORCE_VALUES = (None, "pallas", "interpret", "ref")
 _METHOD_VALUES = (None, "matmul", "sort", "fused")
 _METHOD_ENV = "REPRO_INSERT_METHOD"
 
-# fallback observability (satellite of PR 7): auto dispatch decisions that
-# silently changed paths used to be invisible — now each tall-bank
-# ref-fallback warns once per call site and counts here.  Counts are per
+# fallback observability: every auto dispatch that leaves the compiled
+# kernel on TPU warns once per (kind, site) and counts here.  Counts are per
 # *trace* (the decision is made on static shapes at trace time), so an AOT
 # executable that falls back registers once, not once per call.
 _DISPATCH_STATS: dict[str, dict[str, int]] = {
+    # bank row axis taller than MAX_RESIDENT_ROWS on a resident-row kernel
     "tall_bank_fallbacks": {},
     # per-*trace* count of fused range-merge dispatches: the windowed-query
     # acceptance test asserts a W-slice window query registers exactly one
     # (one device program, not W-1 host-looped merges)
     "range_merge_calls": {},
-    # query-path twin of tall_bank_fallbacks (satellite of PR 10): on TPU a
-    # sub-tile row axis silently drops bank_quantiles / bank_range_merge off
-    # the fused kernel onto the XLA reference — correct, but a perf cliff
-    # the serving tier should be able to see on its dashboard
+    # query row axis below row_tile (bank_quantiles / bank_range_merge)
     "query_fallbacks": {},
+    # ingest batch below one value / triple tile
+    "subtile_fallbacks": {},
 }
-_TALL_BANK_WARNED: set[str] = set()
-_QUERY_WARNED: set[str] = set()
+_FALLBACK_KINDS = ("tall_bank_fallbacks", "query_fallbacks", "subtile_fallbacks")
+_WARNED: set[tuple[str, str]] = set()
 
 
 def dispatch_stats() -> dict:
@@ -108,54 +111,41 @@ def dispatch_stats() -> dict:
     return {k: dict(v) for k, v in _DISPATCH_STATS.items()}
 
 
+def fallback_count() -> int:
+    """Total TPU detours to the reference recorded so far (all kinds)."""
+    return sum(sum(_DISPATCH_STATS[k].values()) for k in _FALLBACK_KINDS)
+
+
 def reset_dispatch_stats() -> None:
     """Clear fallback counters AND the warn-once latches (tests/benches)."""
     for v in _DISPATCH_STATS.values():
         v.clear()
-    _TALL_BANK_WARNED.clear()
-    _QUERY_WARNED.clear()
+    _WARNED.clear()
+
+
+def _note_fallback(kind: str, site: str, detail: str) -> None:
+    """Count (and warn once per site) an auto dispatch landing on the ref."""
+    counts = _DISPATCH_STATS[kind]
+    counts[site] = counts.get(site, 0) + 1
+    if (kind, site) not in _WARNED:
+        _WARNED.add((kind, site))
+        warnings.warn(
+            f"{site}: {detail}; auto dispatch is falling back to the XLA "
+            "reference path (correct but off the compiled kernel).  Recorded "
+            f"in ops.dispatch_stats()[{kind!r}]; warning once per site.",
+            RuntimeWarning,
+            stacklevel=3,
+        )
 
 
 def _note_tall_bank_fallback(site: str, num_rows: int) -> None:
-    counts = _DISPATCH_STATS["tall_bank_fallbacks"]
-    counts[site] = counts.get(site, 0) + 1
-    if site not in _TALL_BANK_WARNED:
-        _TALL_BANK_WARNED.add(site)
-        warnings.warn(
-            f"{site}: bank row axis ({num_rows} rows) exceeds "
-            f"MAX_RESIDENT_ROWS={MAX_RESIDENT_ROWS}; auto dispatch is "
-            "falling back to the XLA reference path (correct but off the "
-            "resident-row kernel).  Shard the bank, shrink it, or pin "
-            'method="matmul" to silence this.  Recorded in '
-            "ops.dispatch_stats(); warning once per site.",
-            RuntimeWarning,
-            stacklevel=3,
-        )
-
-
-def _note_query_fallback(site: str, num_rows: int, row_tile: int) -> None:
-    """Record (and warn once) a query-path auto dispatch landing on ref.
-
-    Counted only when the compiled kernel was on the menu (TPU backend,
-    ``force=None``) and the row axis was too small to fill one tile — the
-    ingest path got this treatment in PR 7; the read path gets it here so
-    dashboard pollers noticing slow queries can see *why* in
-    ``dispatch_stats()`` instead of guessing.
-    """
-    counts = _DISPATCH_STATS["query_fallbacks"]
-    counts[site] = counts.get(site, 0) + 1
-    if site not in _QUERY_WARNED:
-        _QUERY_WARNED.add(site)
-        warnings.warn(
-            f"{site}: bank row axis ({num_rows} rows) is below "
-            f"row_tile={row_tile}; auto dispatch is falling back to the XLA "
-            "reference path (correct but off the fused query kernel).  "
-            "Batch more rows per query, shrink row_tile, or pin "
-            'force="ref" to acknowledge this.  Recorded in '
-            "ops.dispatch_stats(); warning once per site.",
-            RuntimeWarning,
-            stacklevel=3,
-        )
+    _note_fallback(
+        "tall_bank_fallbacks",
+        site,
+        f"bank row axis ({num_rows} rows) exceeds MAX_RESIDENT_ROWS="
+        f"{MAX_RESIDENT_ROWS}; shard the bank, shrink it, or pin "
+        'method="matmul"',
+    )
 
 
 def _on_tpu() -> bool:
@@ -173,18 +163,33 @@ def _check_force(force: str | None) -> None:
         )
 
 
-def _impl(force: str | None, n: int, tile: int) -> str:
+def _impl(
+    force: str | None,
+    n: int,
+    tile: int,
+    site: str,
+    kind: str = "subtile_fallbacks",
+) -> str:
     """Resolve ``force=None`` to a concrete implementation, size-aware.
 
     Pinned values pass through.  Auto picks the compiled kernel only on TPU
     *and* only when the streamed axis fills at least one tile (``n >=
     tile``): below that, padding to the tile dominates the launch and the
-    XLA reference is strictly cheaper.  The crossover is pinned by a unit
+    XLA reference is strictly cheaper.  That TPU detour is counted under
+    ``kind`` for ``site`` (warn once).  The crossover is pinned by a unit
     test in ``tests/test_sort_scatter.py``.
     """
     if force is not None:
         return force
-    if not _on_tpu() or n < tile:
+    if not _on_tpu():
+        return "ref"
+    if n < tile:
+        detail = (
+            f"bank row axis ({n} rows) is below row_tile={tile}"
+            if kind == "query_fallbacks"
+            else f"batch ({n} lanes) is below one tile ({tile} lanes)"
+        )
+        _note_fallback(kind, site, detail)
         return "ref"
     return "pallas"
 
@@ -270,7 +275,7 @@ def ddsketch_histogram(
 
     ``levels`` holds per-value int32 collapse levels; omitted = level 0."""
     _check_force(force)
-    impl = _impl(force, values.size, value_tile)
+    impl = _impl(force, values.size, value_tile, "ddsketch_histogram")
     if impl == "ref":
         return histogram_ref(values, weights, levels, spec=spec)
     return histogram_pallas(
@@ -301,7 +306,7 @@ def segment_histogram(
     whole bank of K sketches regardless of K.  ``levels`` holds *per-value*
     int32 collapse levels (gather per-row levels outside); omitted = level 0."""
     _check_force(force)
-    impl = _impl(force, values.size, value_tile)
+    impl = _impl(force, values.size, value_tile, "segment_histogram")
     if impl == "ref":
         return segment_histogram_ref(
             values, segment_ids, weights, levels, num_segments=num_segments, spec=spec
@@ -350,7 +355,7 @@ def ddsketch_scatter(
     *,
     num_rows: int,
     num_buckets: int,
-    triple_tile: int = 2048,
+    triple_tile: int = 512,
     bucket_tile: int = 512,
     force: str | None = None,  # "pallas" | "interpret" | "ref" | None(auto)
 ) -> jnp.ndarray:
@@ -361,7 +366,7 @@ def ddsketch_scatter(
     nothing.  Bit-exact vs ``ref.scatter_histogram_ref`` for unique keys —
     what ``ref.compact_triples`` emits."""
     _check_force(force)
-    impl = _impl(force, keys.size, triple_tile)
+    impl = _impl(force, keys.size, triple_tile, "ddsketch_scatter")
     if impl != "ref" and num_rows > MAX_RESIDENT_ROWS and force is None:
         # auto never hands a too-tall bank to the resident kernel — but it
         # no longer changes paths silently (warn once + counted)
@@ -395,7 +400,7 @@ def bank_histograms(
     value_tile: int = 2048,
     row_tile: int = 8,
     bucket_tile: int = 512,
-    triple_tile: int = 2048,
+    triple_tile: int = 512,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Both sign stores of a bank insert: ``(pos, neg)``, each ``(K, m)``.
 
@@ -468,7 +473,7 @@ def bank_histograms(
             pos = segment_histogram(pos_vals, segment_ids, weights, levels, **kw)
             neg = segment_histogram(neg_vals, segment_ids, weights, levels, **kw)
         return pos, neg
-    impl = _impl(force, n, triple_tile)
+    impl = _impl(force, n, triple_tile, "bank_histograms[sort]")
     if impl != "ref" and 2 * num_segments > MAX_RESIDENT_ROWS and force is None:
         # bank too tall for the resident-row scatter kernel (warn once)
         _note_tall_bank_fallback("bank_histograms[sort]", 2 * num_segments)
@@ -518,7 +523,7 @@ def fused_ingest(
     *,
     num_segments: int,
     spec: BucketSpec,
-    value_tile: int = 1024,
+    value_tile: int = 512,
     bucket_tile: int = 512,
     force: str | None = None,  # "pallas" | "interpret" | "ref" | None(auto)
 ) -> tuple[jnp.ndarray, jnp.ndarray, IngestStats]:
@@ -538,7 +543,7 @@ def fused_ingest(
     raises in the kernel instead.
     """
     _check_force(force)
-    impl = _impl(force, values.size, value_tile)
+    impl = _impl(force, values.size, value_tile, "fused_ingest")
     if impl != "ref" and 2 * num_segments > MAX_RESIDENT_ROWS and force is None:
         _note_tall_bank_fallback("fused_ingest", 2 * num_segments)
         impl = "ref"
@@ -590,10 +595,9 @@ def bank_quantiles(
         from repro.engine.tables import device_value_table  # deferred: no cycle
 
         table = device_value_table(spec)
-    impl = _impl(force, pos.shape[0], row_tile)
+    impl = _impl(force, pos.shape[0], row_tile, "bank_quantiles",
+                 "query_fallbacks")
     if impl == "ref":
-        if force is None and _on_tpu():
-            _note_query_fallback("bank_quantiles", pos.shape[0], row_tile)
         return bank_quantiles_ref(pos, neg, zero, vmin, vmax, level, qs, table)
     return bank_quantiles_pallas(
         pos,
@@ -639,10 +643,9 @@ def bank_range_merge(
     _check_force(force)
     calls = _DISPATCH_STATS["range_merge_calls"]
     calls["bank_range_merge"] = calls.get("bank_range_merge", 0) + 1
-    impl = _impl(force, counts.shape[1], row_tile)
+    impl = _impl(force, counts.shape[1], row_tile, "bank_range_merge",
+                 "query_fallbacks")
     if impl == "ref":
-        if force is None and _on_tpu():
-            _note_query_fallback("bank_range_merge", counts.shape[1], row_tile)
         return bank_range_merge_ref(counts, deltas, spec=spec, valid=valid)
     d = jnp.clip(deltas.astype(jnp.int32), 0, MAX_COLLAPSE_LEVEL)
     if valid is not None:

@@ -26,6 +26,7 @@ __all__ = [
     "fold_pairs_ref",
     "fold_destination_range",
     "approx_log2",
+    "level_keys",
     "shift_key",
     "composite_keys",
     "compact_triples",
@@ -102,17 +103,52 @@ _CUBIC_B = -3.0 / 5.0
 _CUBIC_C = 10.0 / 7.0
 
 
+# Cephes ``logf`` (S. L. Moshier): ln(1 + t) = t - t^2/2 + t^3 * P(t) on
+# t in [sqrt(1/2) - 1, sqrt(2) - 1), and ln 2 split in two so that e * ln 2
+# keeps full precision.  Peak relative error ~1 ulp over the normal range.
+_LOGF_P = (
+    7.0376836292e-2, -1.1514610310e-1, 1.1676998740e-1, -1.2420140846e-1,
+    1.4249322787e-1, -1.6668057665e-1, 2.0000714765e-1, -2.4999993993e-1,
+    3.3333331174e-1,
+)
+_LN2_HI = 0.693359375
+_LN2_LO = -2.12194440e-4
+
+
+def _ln(x: jnp.ndarray) -> jnp.ndarray:
+    """Natural log of positive normal float32 values, to ~1 ulp, from
+    bit operations, adds and multiplies only.
+
+    ``jnp.log`` lowers to each backend's own approximation; on a TPU v5e it
+    is off by up to ~4e3 ulp, enough to key values up to ~3e-5 (relative)
+    from a bucket edge into the neighbouring bucket and break the alpha
+    guarantee.  This form gives the same ~1 ulp on every backend.
+    """
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    e = ((bits >> 23) & 0xFF) - 126  # x = f * 2**e, f in [0.5, 1)
+    f = jax.lax.bitcast_convert_type((bits & 0x7FFFFF) | 0x3F000000, jnp.float32)
+    small = f < jnp.float32(0.70710678118654752440)
+    e = jnp.where(small, e - 1, e).astype(jnp.float32)
+    t = jnp.where(small, f + f - 1.0, f - 1.0)
+    z = t * t
+    p = jnp.float32(_LOGF_P[0])
+    for c in _LOGF_P[1:]:
+        p = p * t + jnp.float32(c)
+    y = p * t * z + jnp.float32(_LN2_LO) * e - 0.5 * z
+    return (t + y) + jnp.float32(_LN2_HI) * e
+
+
 def approx_log2(x: jnp.ndarray, mapping: str) -> jnp.ndarray:
     """Mapping-specific monotone log approximation (float32 semantics).
 
-    "log": exact natural log (converted by the multiplier).
+    "log": natural log to ~1 ulp (``_ln``; converted by the multiplier).
     "linear"/"cubic": exponent bits + mantissa interpolation — the paper's
     §2.2 'costless log2 from the binary representation' trick, expressed as
     a bitcast so it lowers to TPU integer ops.
     """
     x = x.astype(jnp.float32)
     if mapping == "log":
-        return jnp.log(x)  # natural log; multiplier = 1/ln(gamma)
+        return _ln(x)  # natural log; multiplier = 1/ln(gamma)
     bits = jax.lax.bitcast_convert_type(x, jnp.int32)
     e = ((bits >> 23) & 0xFF) - 127
     f = (bits & 0x7FFFFF).astype(jnp.float32) * (2.0 ** -23)
@@ -131,6 +167,23 @@ def shift_key(key: jnp.ndarray, levels: jnp.ndarray) -> jnp.ndarray:
     is two negations — exact int32 math shared by ref and Pallas paths.
     """
     return -((-key) >> levels)
+
+
+def level_keys(x: jnp.ndarray, levels: jnp.ndarray, spec: BucketSpec) -> jnp.ndarray:
+    """int32 collapse-level keys of ``|x|`` (1.0 stands in for values that
+    cannot be indexed: zeros, tiny magnitudes, NaN/inf).
+
+    The Pallas ingest kernels take these keys as an input instead of taking
+    the log in-kernel: the log is then always XLA's, the one the reference
+    tier uses, so kernel and reference bucket every value identically on any
+    backend (a kernel-side log may round differently near a bucket edge).
+    """
+    x = x.astype(jnp.float32)
+    mag = jnp.where(
+        jnp.isfinite(x) & (jnp.abs(x) > spec.min_indexable), jnp.abs(x), 1.0
+    )
+    key = jnp.ceil(approx_log2(mag, spec.mapping) * jnp.float32(spec.multiplier))
+    return shift_key(key.astype(jnp.int32), levels)
 
 
 def bucket_index(
@@ -427,9 +480,9 @@ def fused_ingest_ref(
     is_zero = valid & ~is_pos & ~is_neg
 
     # one elementwise key pass feeds the histogram AND the clamp accounting
-    mag = jnp.where(is_pos | is_neg, jnp.abs(x), 1.0)
-    key = jnp.ceil(approx_log2(mag, spec.mapping) * jnp.float32(spec.multiplier))
-    k_lev = shift_key(key.astype(jnp.int32), lev)
+    # (the keys the fused kernel is fed; lanes off is_pos | is_neg carry no
+    # weight and no clamp flag, whatever their key)
+    k_lev = level_keys(x, lev, spec)
     idx = jnp.clip(k_lev - spec.offset, 0, m - 1)
     top_key = spec.offset + m - 1
     over = (is_pos | is_neg) & (k_lev > top_key)
@@ -480,64 +533,40 @@ def fused_ingest_ref(
 # --------------------------------------------------------------------- #
 # fused bank quantile query (Algorithm 2 over all rows and qs at once)
 # --------------------------------------------------------------------- #
-def _bank_quantiles_math(pos, neg, zero, vmin, vmax, level, qs, table, *, gather=False):
+def _bank_quantiles_math(pos, neg, zero, vmin, vmax, level, qs, table):
     """Shared formulation of the fused query; see ``bank_quantiles_ref``.
 
-    Operates on a ``(K, m)`` row block with per-row scalars shaped ``(K, 1)``
-    so the same code runs as the XLA oracle and inside the Pallas row-tile
-    kernel (where ``K`` is the row tile).  ``qs`` is static-length; the loop
-    unrolls, answering every q off one cumsum per row.
-
-    ``gather`` switches the *selection-only* steps between bit-identical
-    formulations.  The kernel keeps ``gather=False`` (masked loops, full
-    lane scans, masked sums — the forms Mosaic lowers).  The XLA oracle
-    uses ``gather=True``: the rank search is a per-row binary
-    ``searchsorted`` (identical count on a nondecreasing cumsum) and the
-    answer value is gathered straight out of ``table`` at the one
-    ``(row, lane)`` each q actually reads — the dense per-level value
-    plane and the mirrored value line are never materialized.  Both paths
-    select the same elements, so results are bit-equal — the
-    interpret-mode parity suite pins this.
+    Operates on a ``(K, m)`` row block with per-row scalars shaped ``(K, 1)``.
+    ``qs`` is static-length; the loop unrolls, answering every q off one
+    cumsum per row.  The rank search is a per-row binary ``searchsorted``
+    over the mirrored line's cumsum, and the answer value is gathered
+    straight out of ``table`` at the one ``(row, lane)`` each q actually
+    reads — the dense per-level value plane and the mirrored value line are
+    never materialized.  The Pallas kernel (``kernels/bank_quantiles.py``)
+    selects the same elements with lane scans and one-hots, so the two
+    agree bit-for-bit on integer-valued counts — the interpret-mode parity
+    suite pins this.
     """
     num_levels = table.shape[0]
     m = pos.shape[1]
     lclip = jnp.clip(level, 0, num_levels - 1)
-    if not gather:
-        vals = jnp.zeros_like(pos)
-        for lev in range(num_levels):
-            vals = jnp.where(lclip == lev, table[lev][None, :], vals)
-        line_vals = jnp.concatenate(
-            [-vals[:, ::-1], jnp.zeros_like(zero), vals], axis=1
-        )
     line_counts = jnp.concatenate([neg[:, ::-1], zero, pos], axis=1)
     n = jnp.sum(line_counts, axis=1, keepdims=True)
     cum = jnp.cumsum(line_counts, axis=1)
-    if gather:
-        search = jax.vmap(lambda c, r: jnp.searchsorted(c, r, side="right"))
-        tflat = table.reshape(-1)
-        lrow = lclip.reshape(-1, 1) * m  # row offset into the flat table
-    else:
-        lanes = jax.lax.broadcasted_iota(jnp.int32, line_counts.shape, 1)
+    search = jax.vmap(lambda c, r: jnp.searchsorted(c, r, side="right"))
+    tflat = table.reshape(-1)
+    lrow = lclip.reshape(-1, 1) * m  # row offset into the flat table
     cols = []
     for qi in range(qs.shape[-1]):
         qf = qs.reshape(-1)[qi]
         rank = qf * jnp.maximum(n - 1.0, 0.0)
-        if gather:
-            idx = search(cum, rank.reshape(-1)).reshape(-1, 1)
-        else:
-            # searchsorted(cum, rank, side="right") == #{cum <= rank}
-            idx = jnp.sum((cum <= rank).astype(jnp.int32), axis=1, keepdims=True)
+        idx = search(cum, rank.reshape(-1)).reshape(-1, 1)
         idx = jnp.clip(idx, 0, 2 * m)
-        if gather:
-            # line lane j maps to -vals[m-1-j] / 0 / vals[j-m-1]; read the
-            # one table cell behind it instead of building the line
-            vneg = -jnp.take(tflat, lrow + jnp.clip(m - 1 - idx, 0, m - 1))
-            vpos = jnp.take(tflat, lrow + jnp.clip(idx - m - 1, 0, m - 1))
-            est = jnp.where(idx < m, vneg, jnp.where(idx == m, 0.0, vpos))
-        else:
-            est = jnp.sum(
-                jnp.where(lanes == idx, line_vals, 0.0), axis=1, keepdims=True
-            )
+        # line lane j maps to -vals[m-1-j] / 0 / vals[j-m-1]; read the
+        # one table cell behind it instead of building the line
+        vneg = -jnp.take(tflat, lrow + jnp.clip(m - 1 - idx, 0, m - 1))
+        vpos = jnp.take(tflat, lrow + jnp.clip(idx - m - 1, 0, m - 1))
+        est = jnp.where(idx < m, vneg, jnp.where(idx == m, 0.0, vpos))
         est = jnp.clip(est, vmin, vmax)  # exact-extrema clamp
         est = jnp.where(qf <= 0.0, vmin, jnp.where(qf >= 1.0, vmax, est))
         cols.append(jnp.where(n > 0, est, jnp.nan))
@@ -574,7 +603,6 @@ def bank_quantiles_ref(
         level.astype(jnp.int32).reshape(-1, 1),
         qf,
         table.astype(jnp.float32),
-        gather=True,
     )
 
 

@@ -163,11 +163,8 @@ def initialize(
         return False  # single process: plain local jax, nothing to join
 
     # XLA's CPU client only speaks cross-process collectives through gloo;
-    # select it before the backend exists (no-op where unsupported).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:  # pragma: no cover - much older jax
-        pass
+    # select it before the backend exists (ignored by the TPU client)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     if preflight_timeout_s is None:
         preflight_timeout_s = _env_float(_ENV_PREFLIGHT_TIMEOUT)

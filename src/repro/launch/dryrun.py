@@ -174,7 +174,11 @@ def run_cell(
         }
 
     compile_s = time.time() - t_start
-    terms = roofline.roofline_terms(flops, hbm_bytes, wire_bytes)
+    # the dry run plans v5e pods (the production meshes), so it reads the
+    # v5e row whatever backend compiled it
+    terms = roofline.roofline_terms(
+        flops, hbm_bytes, wire_bytes, hw=roofline.peaks("TPU v5 lite")
+    )
 
     n_params = base_cfg.param_count()
     n_active = base_cfg.active_param_count()

@@ -38,11 +38,14 @@ Fault injection (``launch.faults``) hooks two points deterministically:
 paths fire on demand) and ``slow_engine`` rides the engine's tick hooks.
 The drain thread never dies: an engine error during a tick is counted
 (``drain_errors``), the failing tick's batches are dropped as shed mass,
-and the loop keeps serving — partial failure, defined response.
+and the loop keeps serving — partial failure, defined response.  The
+failure is never silent: ``stats()["last_drain_error"]`` keeps the latest
+exception's type and message, and the first one's traceback is logged.
 """
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -53,6 +56,8 @@ import numpy as np
 from repro.core.ddsketch import DDSketch
 
 __all__ = ["GatewayOverloaded", "IngestGateway"]
+
+_log = logging.getLogger(__name__)
 
 # relative-error guarantee for the gateway's self-instrumented
 # ingest-to-queryable latency sketch (paper alpha, host DDSketch)
@@ -158,6 +163,7 @@ class IngestGateway:
             "slice_advances": 0,
             "engine_calls": 0,
             "drain_errors": 0,
+            "last_drain_error": None,  # "Type: message" of the latest error
             "stalls": 0,
             "max_queue_depth": 0,
         }
@@ -314,11 +320,11 @@ class IngestGateway:
                 n = self.window.record_batches(
                     [(b.key, b.values, b.weights) for b in live]
                 )
-            except Exception:
+            except Exception as e:
                 # partial failure stays partial: count it, shed this tick's
                 # batches, keep the drain thread alive for the next one
+                self._note_drain_error("drain tick", e)
                 with self._lock:
-                    self._stats["drain_errors"] += 1
                     self._stats["shed_mass"] += int(sum(b.values.size for b in live))
                 return 0
             done = time.monotonic()
@@ -335,6 +341,15 @@ class IngestGateway:
             # the version cache instead of racing the donation cycle
             self._publish()
             return int(n)
+
+    def _note_drain_error(self, where: str, exc: Exception) -> None:
+        """Count a failed tick, keep its message, log the first traceback."""
+        with self._lock:
+            first = self._stats["drain_errors"] == 0
+            self._stats["drain_errors"] += 1
+            self._stats["last_drain_error"] = f"{type(exc).__name__}: {exc}"
+        if first:
+            _log.error("ingest gateway %s failed", where, exc_info=exc)
 
     def _publish(self) -> None:
         pub = getattr(self.window, "publish", None)
@@ -356,11 +371,10 @@ class IngestGateway:
         while now >= self._next_slice_t:
             try:
                 self.window.advance_slice()
-            except Exception:
+            except Exception as e:
                 # same contract as a failing drain tick: count it, resync
                 # the clock, keep the thread alive
-                with self._lock:
-                    self._stats["drain_errors"] += 1
+                self._note_drain_error("slice advance", e)
                 self._next_slice_t = now + self.slice_interval_s
                 break
             advanced += 1

@@ -8,15 +8,35 @@ jax initialization, and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_keys_mesh", "HW"]
+__all__ = [
+    "make_mesh",
+    "make_production_mesh",
+    "make_local_mesh",
+    "make_keys_mesh",
+]
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the one place meshes are built.
+
+    JAX 0.9's ``make_mesh`` defaults to ``Explicit`` axes, under which
+    sharding-in-types rejects the partitioner-driven code here (a plain
+    ``jnp.take`` of a sharded embedding raises ``ShardingTypeError``).  Every
+    mesh in the repo, tests included, comes from this helper instead.
+    """
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes), **kwargs
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_keys_mesh(num_shards: int | None = None, *, devices=None):
@@ -39,7 +59,7 @@ def make_keys_mesh(num_shards: int | None = None, *, devices=None):
     n = len(devs) if num_shards is None else int(num_shards)
     if not 1 <= n <= len(devs):
         raise ValueError(f"num_shards={n} outside [1, {len(devs)}] visible devices")
-    return jax.make_mesh((n,), ("keys",), devices=devs[:n])
+    return make_mesh((n,), ("keys",), devices=devs[:n])
 
 
 def make_local_mesh(model: int = 1):
@@ -51,12 +71,5 @@ def make_local_mesh(model: int = 1):
     n = len(jax.devices())
     if n % model:
         raise ValueError(f"{n} devices not divisible by model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
-
-class HW:
-    """TPU v5e-class hardware constants for the roofline model (§7)."""
-
-    PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-    HBM_BW = 819e9  # bytes/s per chip
-    ICI_BW = 50e9  # bytes/s per link (per-chip effective for ring terms)

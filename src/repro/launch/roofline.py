@@ -18,6 +18,10 @@ shape, with ring cost factors expressed against the result:
 
 The group size n comes from replica_groups (both the explicit {{0,1,...}}
 and the iota [g,n]<=[N] forms are parsed).
+
+Peaks come from ``PEAKS``, one table keyed by ``device_kind`` with its
+source; a device missing from it is an error, never a default, so a CPU
+run can produce no roofline share.
 """
 
 from __future__ import annotations
@@ -25,9 +29,10 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-from repro.launch.mesh import HW
-
 __all__ = [
+    "PEAKS",
+    "Peaks",
+    "peaks",
     "CollectiveStats",
     "parse_collectives",
     "roofline_terms",
@@ -35,6 +40,44 @@ __all__ = [
     "ingest_bytes_model",
     "attained_bandwidth",
 ]
+
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one device kind."""
+
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # interchip bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e" (system architecture table):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interchip
+# interconnect per chip over four links (50 GB/s per link).  JAX reports a
+# v5e chip's device_kind as "TPU v5 lite".
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str | None = None) -> Peaks:
+    """The ``PEAKS`` row of ``device_kind`` (default: ``jax.devices()[0]``).
+
+    Raises ``KeyError`` for a kind with no published row — a CPU run, or a
+    chip nobody has sourced yet — instead of guessing.
+    """
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; add a "
+            "sourced row to repro.launch.roofline.PEAKS"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -134,11 +177,11 @@ def roofline_terms(
     hbm_bytes: float,
     wire_bytes: float,
     *,
-    hw=HW,
+    hw: Peaks,
 ) -> dict:
-    compute_s = flops / hw.PEAK_FLOPS
-    memory_s = hbm_bytes / hw.HBM_BW
-    collective_s = wire_bytes / hw.ICI_BW
+    compute_s = flops / hw.peak_flops
+    memory_s = hbm_bytes / hw.hbm_bw
+    collective_s = wire_bytes / hw.ici_bw
     terms = {
         "compute_s": compute_s,
         "memory_s": memory_s,
@@ -226,19 +269,17 @@ def ingest_bytes_model(
     }
 
 
-def attained_bandwidth(model_bytes: float, seconds: float, *, hw=HW) -> dict:
-    """Measured bandwidth for a modeled byte count, vs the HW HBM roofline.
+def attained_bandwidth(model_bytes: float, seconds: float, *, hw: Peaks) -> dict:
+    """Measured bandwidth for a modeled byte count, vs the chip's HBM peak.
 
     ``attained_gbps`` is what the measured wall-clock implies the modeled
-    bytes moved at; ``hbm_frac`` positions that against ``hw.HBM_BW`` — on
-    TPU this is the attained-bandwidth fraction proper, on the CPU ref tier
-    it reads as "distance to the TPU roofline if the same bytes moved at
-    the measured rate" (the trajectory number the bench gate tracks).
+    bytes moved at; ``hbm_frac`` positions that against ``hw.hbm_bw``.
+    Only meaningful for a time measured on the chip ``hw`` describes.
     """
     if seconds <= 0:
         return {"attained_gbps": 0.0, "hbm_frac": 0.0}
     bps = model_bytes / seconds
-    return {"attained_gbps": bps / 1e9, "hbm_frac": bps / hw.HBM_BW}
+    return {"attained_gbps": bps / 1e9, "hbm_frac": bps / hw.hbm_bw}
 
 
 def collective_shape_histogram(hlo_text: str, top: int = 12) -> list[dict]:

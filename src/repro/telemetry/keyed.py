@@ -272,7 +272,6 @@ class KeyedWindow:
         spec: BucketSpec,
         capacity: int,
         *,
-        use_kernel: bool = False,
         collapse_threshold: float | None = 0.0,
         evict_after: int = 1,
         method: str | None = None,
@@ -291,7 +290,6 @@ class KeyedWindow:
         self.capacity = capacity
         # reentrant: KeyedAggregator.flush holds it while calling reset()
         self.lock = threading.RLock()
-        self.use_kernel = use_kernel
         self.collapse_threshold = collapse_threshold
         self.evict_after = evict_after
         self.method = method  # insert pipeline pin ("matmul"/"sort"/None auto)
@@ -301,7 +299,6 @@ class KeyedWindow:
             capacity + 1,
             num_shards=num_shards,
             counts_dtype=counts_dtype,
-            use_kernel=use_kernel,
             method=method,
         )
         self.bank = self.engine.new_bank()

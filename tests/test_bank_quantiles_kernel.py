@@ -94,11 +94,13 @@ def test_kernel_empty_rows_and_bank(rng):
     assert _fused(zero_bank, [0.5], spec, force="interpret").shape == (0, 1)
 
 
-def test_sketch_bank_quantiles_uses_fused_path(rng):
+def test_sketch_bank_quantiles_uses_fused_path(rng, request):
     spec = BucketSpec()
-    bank = _bank(spec, 7, 2000, rng)
+    bank = _bank(spec, 9, 2000, rng)  # >= row_tile rows: the kernel's menu
     a = sb.quantiles(bank, jnp.asarray(QS), spec=spec)
-    b = sb.quantiles(bank, jnp.asarray(QS), spec=spec, use_kernel=True)
+    request.getfixturevalue("tpu_interpret")  # default dispatch -> kernel
+    b = sb.quantiles(bank, jnp.asarray(QS), spec=spec)
+    assert ops.fallback_count() == 0
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     one = sb.quantile(bank, 0.5, spec=spec)
     np.testing.assert_array_equal(np.asarray(one), np.asarray(a[:, QS.index(0.5)]))
@@ -141,8 +143,9 @@ def _host_twin(spec, level, vals, weights):
     level=st.integers(min_value=0, max_value=MAX_COLLAPSE_LEVEL),
     data=st.lists(
         st.tuples(
-            st.floats(min_value=1e-3, max_value=1e6, allow_nan=False,
-                      width=32),
+            # width=32 bounds must be float32-representable
+            st.floats(min_value=float(np.float32(1e-3)), max_value=1e6,
+                      allow_nan=False, width=32),
             st.integers(min_value=1, max_value=4),
             st.booleans(),
         ),

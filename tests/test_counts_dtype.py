@@ -31,7 +31,7 @@ def test_int32_counts_exact_past_f32_ceiling():
     assert int(i32.count) == int(CEIL) + 1
 
 
-def test_int32_bank_add_merge_collapse_preserve_dtype(rng):
+def test_int32_bank_add_merge_collapse_preserve_dtype(rng, request):
     x = jnp.asarray((rng.pareto(1.0, 2000) + 1.0).astype(np.float32))
     s = jnp.asarray(rng.integers(0, 4, 2000).astype(np.int32))
     bank = sb.add(sb.empty(SPEC, 4, counts_dtype=jnp.int32), x, s, spec=SPEC)
@@ -45,11 +45,13 @@ def test_int32_bank_add_merge_collapse_preserve_dtype(rng):
     assert folded.pos.dtype == jnp.int32
     assert int(folded.counts.sum()) == int(bank.counts.sum())  # mass conserved
     # the kernel fold accumulates in f32, so integer banks stay on the ref
-    folded_k = sb.collapse(bank, spec=SPEC, use_kernel=True)
+    # even where the default dispatch picks kernels
+    request.getfixturevalue("tpu_interpret")
+    folded_k = sb.collapse(bank, spec=SPEC)
     np.testing.assert_array_equal(np.asarray(folded_k.pos), np.asarray(folded.pos))
 
 
-def test_int_bank_quantiles_match_float_bank(rng):
+def test_int_bank_quantiles_match_float_bank(rng, request):
     x = jnp.asarray((rng.pareto(1.0, 3000) + 1.0).astype(np.float32))
     s = jnp.asarray(rng.integers(0, 3, 3000).astype(np.int32))
     qs = jnp.asarray([0.1, 0.5, 0.99])
@@ -59,10 +61,9 @@ def test_int_bank_quantiles_match_float_bank(rng):
         np.asarray(sb.quantiles(f32, qs, spec=SPEC)),
         np.asarray(sb.quantiles(i32, qs, spec=SPEC)),
     )
-    np.testing.assert_array_equal(
-        np.asarray(sb.quantiles(i32, qs, spec=SPEC)),
-        np.asarray(sb.quantiles(i32, qs, spec=SPEC, use_kernel=True)),
-    )
+    ref = np.asarray(sb.quantiles(i32, qs, spec=SPEC))
+    request.getfixturevalue("tpu_interpret")  # default dispatch -> kernel
+    np.testing.assert_array_equal(ref, np.asarray(sb.quantiles(i32, qs, spec=SPEC)))
 
 
 def test_int32_host_roundtrip_exact():

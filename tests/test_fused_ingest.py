@@ -284,7 +284,7 @@ def test_picked_insert_method_dense_stats_downgrade():
     assert sb.picked_insert_method(1 << 18, 8, 2048) == "sort"
     assert sb.picked_insert_method(1 << 18, 128, 2048) == "fused"
     # the kernel tier has no dense-stats regime: fused stands
-    assert sb.picked_insert_method(1 << 18, 8, 2048, use_kernel=True) == "fused"
+    assert sb.picked_insert_method(1 << 18, 8, 2048, on_tpu=True) == "fused"
 
 
 def test_fused_auto_falls_back_for_tall_banks(monkeypatch, rng):
@@ -319,6 +319,7 @@ def test_fused_auto_falls_back_for_tall_banks(monkeypatch, rng):
         "tall_bank_fallbacks": {},
         "range_merge_calls": {},
         "query_fallbacks": {},
+        "subtile_fallbacks": {},
     }
 
 

@@ -148,6 +148,35 @@ def test_drain_error_sheds_tick_and_keeps_serving(rng):
     assert gw.stats()["ingested_values"] == 5
 
 
+def test_drain_error_keeps_message_and_logs_once(rng, caplog):
+    """A failing engine tick is never silent: the exception's type and
+    message stay in stats(), and only the first traceback is logged."""
+    window = make_window()
+    gw = IngestGateway(window, start=False)
+    assert gw.stats()["last_drain_error"] is None
+    charges = [2]
+
+    def failing_tick(path):
+        if charges[0]:
+            charges[0] -= 1
+            raise ValueError(f"Mosaic refused the {path} kernel")
+
+    window.engine.tick_hooks.append(failing_tick)
+    with caplog.at_level("ERROR", logger="repro.launch.ingest_gateway"):
+        for n in (10, 20):
+            gw.submit("/a", np.ones(n))
+            gw.flush()
+    st = gw.stats()
+    assert st["drain_errors"] == 2 and st["shed_mass"] == 30
+    assert st["last_drain_error"] == "ValueError: Mosaic refused the ingest kernel"
+    logged = [r for r in caplog.records if r.exc_info]
+    assert len(logged) == 1 and "drain tick" in logged[0].getMessage()
+    json.dumps(st)  # the message rides the JSON /stats payload
+    gw.submit("/a", np.ones(5))
+    gw.flush()  # the hook is spent: the next tick ingests
+    assert gw.stats()["ingested_values"] == 5
+
+
 def test_background_drain_thread(rng):
     gw = IngestGateway(make_window(), tick_interval_s=0.002)
     gw.submit("/a", rng.pareto(1.0, 100) + 1.0)

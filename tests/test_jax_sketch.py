@@ -195,7 +195,8 @@ from repro.core.ddsketch import DDSketch
 from repro.kernels.ref import BucketSpec
 
 SPEC = BucketSpec()
-mesh = jax.make_mesh((8,), ("d",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("d",))
 rng = np.random.default_rng(0)
 data = (rng.pareto(1.0, 8 * 500) + 1.0).astype(np.float32)
 

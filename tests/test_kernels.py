@@ -92,6 +92,28 @@ def test_bucket_index_matches_host_mapping(mapping, rng):
     assert (idx == host_idx).mean() > 0.99
 
 
+def test_log_mapping_log_is_within_one_ulp(rng):
+    """The "log" mapping's natural log (``ref._ln``) is built from adds and
+    multiplies so it holds ~1 ulp on every backend, bucket edges included
+    (a backend's own ``log`` may not: on a TPU v5e it is off by thousands)."""
+    from repro.kernels.ref import _ln
+
+    spec = BucketSpec()
+    edges = spec.gamma ** np.arange(-1000.0, 1000.0)
+    eps = np.array([-3e-5, -1e-6, 0.0, 1e-6, 3e-5])
+    x = np.concatenate([
+        (edges[:, None] * (1.0 + eps)).ravel(),
+        np.exp(rng.uniform(-87.0, 88.0, 50_000)),
+        [1.0, 2.0, 0.5, np.float32(0.17905134)],
+    ]).astype(np.float32)
+    got = np.asarray(_ln(jnp.asarray(x))).astype(np.float64)
+    want = np.log(x.astype(np.float64))
+    ulp = np.spacing(np.abs(want).astype(np.float32)).astype(np.float64)
+    nz = want != 0.0
+    assert np.max(np.abs(got - want)[nz] / ulp[nz]) <= 1.0
+    assert got[~nz].tolist() == [0.0] * int((~nz).sum())
+
+
 def test_ops_dispatch_ref_on_cpu(rng):
     spec = BucketSpec()
     x = jnp.asarray(_data((512,), rng))

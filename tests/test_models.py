@@ -63,7 +63,8 @@ def test_smoke_train_step(arch):
     """One full train step (grads + AdamW + telemetry) on the 1-device mesh."""
     from repro.launch.steps import StepConfig, build_train_step
     cfg = configs.smoke(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     scfg = StepConfig(remat=False, ssm_chunk=16, q_block=32, warmup_steps=2,
                       total_steps=10)
     fn, in_sh, out_sh, donate, state_shapes = build_train_step(cfg, mesh, scfg=scfg)

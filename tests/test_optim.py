@@ -91,7 +91,8 @@ def test_clip_by_global_norm(rng):
 
 
 def test_zero1_spec_adds_data_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")
@@ -115,7 +116,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.compat import shard_map
 from repro.optim.compression import compressed_psum, compress_state_init
-mesh = jax.make_mesh((8,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("pod",))
 
 def step(g_all, err):
     def inner(g, e):

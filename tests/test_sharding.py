@@ -114,7 +114,8 @@ from repro.models.common import init_params
 from repro.optim import adamw_init
 from repro.telemetry import TelemetryConfig, init_telemetry
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = configs.smoke("{arch}")
 scfg = StepConfig(remat=False, ssm_chunk=16, q_block=32, warmup_steps=2, total_steps=10)
 fn, in_sh, out_sh, donate, shapes = build_train_step(cfg, mesh, scfg=scfg)
@@ -156,7 +157,8 @@ from repro.models.common import init_params
 from repro.optim import adamw_init
 from repro.telemetry import TelemetryConfig, init_telemetry
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = configs.smoke("yi-6b")
 scfg = StepConfig(remat=False, ssm_chunk=16, q_block=32, grad_compress_axis="pod",
                   warmup_steps=2, total_steps=10)

@@ -312,14 +312,23 @@ def test_size_aware_dispatch_crossover(monkeypatch):
     kernel even for sub-tile batches where padding to value_tile dominates;
     auto now routes them to the XLA ref.  The crossover is value_tile."""
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    assert ops._impl(None, 2047, 2048) == "ref"
-    assert ops._impl(None, 2048, 2048) == "pallas"
-    assert ops._impl(None, 0, 2048) == "ref"
-    # pinned values always pass through untouched
-    assert ops._impl("ref", 1 << 20, 2048) == "ref"
-    assert ops._impl("interpret", 4, 2048) == "interpret"
+    ops.reset_dispatch_stats()
+    with pytest.warns(RuntimeWarning, match="below one tile"):
+        assert ops._impl(None, 2047, 2048, "site") == "ref"
+    assert ops._impl(None, 2048, 2048, "site") == "pallas"
+    assert ops._impl(None, 0, 2048, "site") == "ref"
+    # the sub-tile detour is counted, not silent
+    assert ops.dispatch_stats()["subtile_fallbacks"] == {"site": 2}
+    assert ops.fallback_count() == 2
+    # pinned values always pass through untouched (and uncounted)
+    assert ops._impl("ref", 1 << 20, 2048, "site") == "ref"
+    assert ops._impl("interpret", 4, 2048, "site") == "interpret"
+    # off TPU the reference is the plan, not a detour
     monkeypatch.setattr(ops, "_on_tpu", lambda: False)
-    assert ops._impl(None, 1 << 20, 2048) == "ref"
+    assert ops._impl(None, 1 << 20, 2048, "site") == "ref"
+    assert ops._impl(None, 4, 2048, "site") == "ref"
+    assert ops.fallback_count() == 2
+    ops.reset_dispatch_stats()
 
 
 def test_scatter_auto_falls_back_for_tall_banks(monkeypatch, rng):

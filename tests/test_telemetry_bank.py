@@ -92,7 +92,8 @@ def test_train_step_single_hist_dispatch(monkeypatch):
     # cache would absorb the add trace this test wants to observe
     counter = _HistCounter(monkeypatch)
     cfg = configs.smoke("smollm-135m")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     scfg = StepConfig(remat=False, ssm_chunk=16, q_block=32, warmup_steps=2,
                       total_steps=10)
     with mesh:
