@@ -60,6 +60,7 @@ from repro.core.sketch_bank import SketchBank
 from repro.engine.tables import next_pow2
 from repro.kernels import ops
 from repro.kernels.ref import MAX_COLLAPSE_LEVEL, BucketSpec, bank_quantiles_ref
+from repro.tracing import span
 
 __all__ = ["SketchEngine", "shared_engine", "window_merge_bank"]
 
@@ -279,7 +280,8 @@ class SketchEngine:
         exe = self._cache.get(key)
         if exe is None:
             self._misses += 1
-            exe = self._wrap(build, donate, in_kinds, out_kinds).lower(*args).compile()
+            with span("engine.compile", key=str(key)):
+                exe = self._wrap(build, donate, in_kinds, out_kinds).lower(*args).compile()
             self._cache[key] = exe
         else:
             self._hits += 1
@@ -367,14 +369,15 @@ class SketchEngine:
         def copy_impl(b: SketchBank) -> SketchBank:
             return jax.lax.optimization_barrier(b)
 
-        return self._compiled(
-            ("snapshot", kind),
-            copy_impl,
-            (),
-            (kind,),
-            (kind,),
-            state,
-        )
+        with span("engine.snapshot", kind=kind):
+            return self._compiled(
+                ("snapshot", kind),
+                copy_impl,
+                (),
+                (kind,),
+                (kind,),
+                state,
+            )
 
     def reset(self, bank: SketchBank, levels=None) -> SketchBank:
         """Zero the bank **in place** (donated), keeping or replacing levels.
@@ -464,10 +467,7 @@ class SketchEngine:
         if v.shape != s.shape:
             raise ValueError(f"values {v.shape} vs sketch_ids {s.shape}")
         w = None if weights is None else np.asarray(weights, np.float32).reshape(-1)
-        vv, ss, ww, geom = self._prep_batch(v, s, w, block=block)
-
         reactive = threshold is not None
-        key = ("ingest", geom, w is not None, reactive, auto_collapse)
 
         def ingest_impl(b, vv, ss, ww, thr):
             b = sbank.add_impl(
@@ -490,19 +490,22 @@ class SketchEngine:
             )
             return b, fire, clamped
 
-        thr = jnp.asarray(0.0 if threshold is None else threshold, jnp.float32)
-        out = self._compiled(
-            key,
-            ingest_impl,
-            (0,),
-            ("bank", "batch", "ids", "batch", "scalar"),
-            ("bank", "rows", "rows") if reactive else ("bank",),
-            bank,
-            vv,
-            ss,
-            ww,
-            thr,
-        )
+        with span("engine.ingest", lanes=v.size) as sp:
+            vv, ss, ww, geom = self._prep_batch(v, s, w, block=block)
+            sp.set(geom=str(geom))
+            thr = jnp.asarray(0.0 if threshold is None else threshold, jnp.float32)
+            out = self._compiled(
+                ("ingest", geom, w is not None, reactive, auto_collapse),
+                ingest_impl,
+                (0,),
+                ("bank", "batch", "ids", "batch", "scalar"),
+                ("bank", "rows", "rows") if reactive else ("bank",),
+                bank,
+                vv,
+                ss,
+                ww,
+                thr,
+            )
         if not reactive:
             return out, None, None
         return out

@@ -52,15 +52,27 @@ Write path (``gateway=`` an ``launch.ingest_gateway.IngestGateway``):
   POST /ingest   {"key": str, "values": [..], "weights"?: [..],
                   "deadline_ms"?: float}
                  -> 200 admission receipt {status, queued, shed,
-                    queue_depth}; 429 + Retry-After when the gateway queue
+                    queue_depth, seq}; 429 + Retry-After when the gateway queue
                     is full (reject policy); 400 on malformed payloads;
                     413 past ``max_body_bytes``
   GET  /stats    -> {"server": per-server counters (write_errors,
                     requests, faults fired), "engine": executable-cache
                     hit/miss counts + ring occupancy (when the telemetry
-                    source exposes ``engine_stats``), "gateway":
-                    queue/shed/latency counters} — the operator's
-                    overload dashboard
+                    source exposes ``engine_stats``), "query_planner":
+                    coalescer/cache counters, "gateway": queue/shed
+                    counters and ``latency_s``, per-batch quantiles of
+                    admission to published version (host side; device
+                    work may still be in flight)} — the operator's
+                    overload dashboard.  The planner's and the gateway's
+                    blocks each carry ``spans``: per span name ``count``,
+                    ``total_s`` and ``self_s`` (``repro.tracing``; the
+                    process's totals since start)
+
+Spans (``repro.tracing``): ``http.ingest`` is one ``POST /ingest`` (its
+status, values and the gateway's ``seq``), with children
+``http.ingest.parse`` (body read, ``json.loads``, validation),
+``gateway.submit`` and ``http.reply`` (every reply the server writes);
+``http.read`` is one versioned GET (its path).
 
 Robustness: a peer closing mid-response used to make ``wfile.write``
 raise ``BrokenPipeError``/``ConnectionResetError``, which
@@ -85,6 +97,7 @@ from urllib.parse import parse_qs, urlparse
 
 from repro.launch.ingest_gateway import GatewayOverloaded
 from repro.telemetry.keyed import OVERFLOW_KEY
+from repro.tracing import span
 
 __all__ = [
     "TelemetryFacade",
@@ -302,6 +315,11 @@ def _make_handler(
             return True
 
         def _reply(self, code: int, payload: dict, headers: dict | None = None) -> None:
+            self._status = code
+            with span("http.reply"):
+                self._write(code, payload, headers)
+
+        def _write(self, code: int, payload: dict, headers: dict | None) -> None:
             try:
                 body = json.dumps(payload).encode()
                 if faults is not None and faults.take("half_close") is not None:
@@ -381,6 +399,13 @@ def _make_handler(
             stats.incr("requests")
             if self._chaos_drop():
                 return
+            if url.path in versioned_paths:
+                with span("http.read", path=url.path):
+                    self._get(url, query)
+            else:
+                self._get(url, query)
+
+        def _get(self, url, query) -> None:
             try:
                 if url.path == "/healthz":  # liveness: no auth, no bucket
                     self._reply(200, {"ok": True})
@@ -538,6 +563,12 @@ def _make_handler(
             stats.incr("requests")
             if self._chaos_drop():
                 return
+            self._status = 0  # no reply (a truncated body)
+            with span("http.ingest") as sp:
+                self._post(url, sp)
+                sp.set(status=self._status)
+
+        def _post(self, url, sp: span) -> None:
             try:
                 if url.path != "/ingest":
                     self._reply(404, {"error": f"unknown path {url.path!r}"})
@@ -561,32 +592,34 @@ def _make_handler(
                         {"error": f"body {length} bytes > limit {max_body_bytes}"},
                     )
                     return
-                raw = self.rfile.read(length)
-                if len(raw) < length:  # peer died mid-upload: no reply path
-                    stats.incr("truncated_bodies")
-                    self.close_connection = True
-                    return
-                try:
-                    payload = json.loads(raw)
-                except json.JSONDecodeError as e:
-                    raise ValueError(f"invalid JSON body: {e}") from e
-                if not isinstance(payload, dict):
-                    raise ValueError("body must be a JSON object")
-                key = payload.get("key")
-                values = payload.get("values")
-                if not isinstance(key, str) or not key:
-                    raise ValueError("'key' must be a non-empty string")
-                if not isinstance(values, list):
-                    raise ValueError("'values' must be a list of numbers")
-                weights = payload.get("weights")
-                if weights is not None and not isinstance(weights, list):
-                    raise ValueError("'weights' must be a list of numbers")
-                deadline_ms = payload.get("deadline_ms")
-                if deadline_ms is not None and (
-                    isinstance(deadline_ms, bool)
-                    or not isinstance(deadline_ms, (int, float))
-                ):
-                    raise ValueError("'deadline_ms' must be a number")
+                with span("http.ingest.parse"):
+                    raw = self.rfile.read(length)
+                    if len(raw) < length:  # peer died mid-upload: no reply path
+                        stats.incr("truncated_bodies")
+                        self.close_connection = True
+                        return
+                    try:
+                        payload = json.loads(raw)
+                    except json.JSONDecodeError as e:
+                        raise ValueError(f"invalid JSON body: {e}") from e
+                    if not isinstance(payload, dict):
+                        raise ValueError("body must be a JSON object")
+                    key = payload.get("key")
+                    values = payload.get("values")
+                    if not isinstance(key, str) or not key:
+                        raise ValueError("'key' must be a non-empty string")
+                    if not isinstance(values, list):
+                        raise ValueError("'values' must be a list of numbers")
+                    weights = payload.get("weights")
+                    if weights is not None and not isinstance(weights, list):
+                        raise ValueError("'weights' must be a list of numbers")
+                    deadline_ms = payload.get("deadline_ms")
+                    if deadline_ms is not None and (
+                        isinstance(deadline_ms, bool)
+                        or not isinstance(deadline_ms, (int, float))
+                    ):
+                        raise ValueError("'deadline_ms' must be a number")
+                sp.set(values=len(values))
                 try:
                     receipt = gateway.submit(
                         key,
@@ -605,6 +638,7 @@ def _make_handler(
                     )
                     return
                 stats.incr("ingest_accepted")
+                sp.set(seq=receipt.get("seq", 0))
                 self._reply(200, receipt)
             except (ValueError, TypeError) as e:
                 # TypeError covers malformed payload *types* that survive

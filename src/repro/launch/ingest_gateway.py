@@ -29,9 +29,16 @@ the adapter, hardened for the day traffic exceeds what the engine absorbs:
   tick's ingest, so a slice never misses values admitted inside its
   interval); ``flush()`` never advances the clock;
 * **observability** — ``stats()`` snapshots the counters (accepted /
-  ingested / shed / rejected / expired / depth / ticks) and the gateway
-  dogfoods its own paper: ingest-to-queryable latency per batch goes into
-  a host ``DDSketch`` (``latency_quantiles``).
+  ingested / shed / rejected batches and values / expired / depth /
+  ticks; ``batches_taken`` and ``queue_wait_s``, the batches the drain
+  ticks took off the queue and the seconds they had waited there) with a
+  ``spans`` block: the totals of the write path's spans (``http.ingest*``,
+  ``gateway.*``, ``window.*``, ``engine.*``; ``repro.tracing``).  Each
+  admitted batch gets a sequence number (``seq`` in the receipt and on the
+  ``gateway.submit`` span), and the ``gateway.tick`` span names the first
+  and last it ingested.  The gateway dogfoods its own paper: per batch,
+  admission to published version (host side; device work may still be in
+  flight) goes into a host ``DDSketch`` (``latency_quantiles``).
 
 Fault injection (``launch.faults``) hooks two points deterministically:
 ``queue_stall`` sleeps the drain loop (backs the queue up so the 429/shed
@@ -49,19 +56,22 @@ import logging
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
 
 from repro.core.ddsketch import DDSketch
+from repro.tracing import span, totals
 
 __all__ = ["GatewayOverloaded", "IngestGateway"]
 
 _log = logging.getLogger(__name__)
 
 # relative-error guarantee for the gateway's self-instrumented
-# ingest-to-queryable latency sketch (paper alpha, host DDSketch)
+# admission-to-published latency sketch (paper alpha, host DDSketch)
 _LATENCY_ALPHA = 0.01
+# the spans whose totals ride stats()["spans"]
+_SPAN_PREFIXES = ("http.ingest", "http.reply", "gateway.", "window.", "engine.")
 
 
 class GatewayOverloaded(RuntimeError):
@@ -82,8 +92,8 @@ class _Batch:
     weights: np.ndarray | None
     t_enqueue: float
     deadline: float | None  # absolute monotonic time; None = no deadline
+    seq: int  # admission order, from 1
     shed: int = 0  # values stride-sampled away at admission
-    t_queryable: float = field(default=0.0)
 
 
 class IngestGateway:
@@ -158,8 +168,11 @@ class IngestGateway:
             "shed_mass": 0,  # sampled-away + expired + error-dropped values
             "sampled_batches": 0,
             "rejected_batches": 0,
+            "rejected_values": 0,
             "expired_batches": 0,
             "ticks": 0,
+            "batches_taken": 0,
+            "queue_wait_s": 0.0,  # sum over taken batches of take - enqueue
             "slice_advances": 0,
             "engine_calls": 0,
             "drain_errors": 0,
@@ -167,8 +180,9 @@ class IngestGateway:
             "stalls": 0,
             "max_queue_depth": 0,
         }
-        # ingest-to-queryable seconds, measured on ourselves with the very
-        # sketch this service exists to serve
+        self._seq = 0  # last admitted batch's sequence number
+        # admission-to-published seconds, measured on ourselves with the
+        # very sketch this service exists to serve
         self._latency = DDSketch(_LATENCY_ALPHA)
         # EWMA of drained values/s; seeds Retry-After before the first tick
         self._drain_rate = float(max_queue_values) / max(tick_interval_s, 1e-3)
@@ -194,8 +208,19 @@ class IngestGateway:
         batch to weighted stride samples (receipt ``shed`` > 0); a
         *completely* full queue drops the batch whole — still a defined
         response (receipt ``status: "shed"``), never an exception, because
-        degrade mode prefers availability.
+        degrade mode prefers availability.  A queued batch's receipt
+        carries its sequence number (``seq``).
         """
+        with span("gateway.submit") as sp:
+            try:
+                receipt = self._admit(key, values, weights, deadline_s)
+            except GatewayOverloaded:
+                sp.set(outcome="rejected")
+                raise
+            sp.set(outcome=receipt["status"], seq=receipt.get("seq", 0))
+            return receipt
+
+    def _admit(self, key, values, weights, deadline_s) -> dict:
         if not isinstance(key, str) or not key:
             raise ValueError("key must be a non-empty string")
         v = np.asarray(values, np.float32).reshape(-1)
@@ -222,6 +247,7 @@ class IngestGateway:
             if v.size > room:
                 if self.shed_policy == "reject":
                     self._stats["rejected_batches"] += 1
+                    self._stats["rejected_values"] += int(v.size)
                     raise GatewayOverloaded(self._retry_after_locked(), self._depth)
                 if room == 0:
                     self._stats["shed_mass"] += int(v.size)
@@ -246,13 +272,21 @@ class IngestGateway:
                 v = kept
                 self._stats["sampled_batches"] += 1
                 self._stats["shed_mass"] += shed
-            self._q.append(_Batch(key, v, w, time.monotonic(), deadline, shed))
+            self._seq += 1
+            seq = self._seq
+            self._q.append(_Batch(key, v, w, time.monotonic(), deadline, seq, shed))
             self._depth += v.size
             self._stats["accepted_values"] += int(v.size)
             self._stats["max_queue_depth"] = max(self._stats["max_queue_depth"], self._depth)
             depth = self._depth
             self._wake.notify()
-        return {"status": "accepted", "queued": int(v.size), "shed": shed, "queue_depth": depth}
+        return {
+            "status": "accepted",
+            "queued": int(v.size),
+            "shed": shed,
+            "queue_depth": depth,
+            "seq": seq,
+        }
 
     def _retry_after_locked(self) -> float:
         """Advisory backoff: time for the measured drain rate to clear the
@@ -295,7 +329,7 @@ class IngestGateway:
 
     def _drain_once(self) -> int:
         """One tick: grab everything queued, drop expired, ingest the rest
-        in ONE engine call.  Returns lanes ingested; never raises."""
+        in ONE engine call, publish.  Returns lanes ingested; never raises."""
         with self._drain_lock:
             with self._lock:
                 if not self._q:
@@ -303,44 +337,63 @@ class IngestGateway:
                 batches = list(self._q)
                 self._q.clear()
                 self._depth = 0
+                now = time.monotonic()
                 self._stats["ticks"] += 1
-            now = time.monotonic()
-            live: list[_Batch] = []
+                self._stats["batches_taken"] += len(batches)
+                self._stats["queue_wait_s"] += sum(now - b.t_enqueue for b in batches)
+                tick = self._stats["ticks"]
+            live, expired = [], []
             for b in batches:
-                if b.deadline is not None and now > b.deadline:
-                    with self._lock:
-                        self._stats["expired_batches"] += 1
-                        self._stats["shed_mass"] += int(b.values.size)
-                else:
-                    live.append(b)
-            if not live:
+                (expired if b.deadline is not None and now > b.deadline else live).append(b)
+            with span(
+                "gateway.tick",
+                tick=tick,
+                first_seq=batches[0].seq,
+                last_seq=batches[-1].seq,
+                batches=len(batches),
+                values=int(sum(b.values.size for b in live)),
+                expired=len(expired),
+            ) as sp:
+                n, ingest_s = self._ingest(live, expired)
+            if not n:
                 return 0
-            t0 = time.monotonic()
-            try:
-                n = self.window.record_batches(
-                    [(b.key, b.values, b.weights) for b in live]
-                )
-            except Exception as e:
-                # partial failure stays partial: count it, shed this tick's
-                # batches, keep the drain thread alive for the next one
-                self._note_drain_error("drain tick", e)
-                with self._lock:
-                    self._stats["shed_mass"] += int(sum(b.values.size for b in live))
-                return 0
-            done = time.monotonic()
+            # the span's end is the end of the tick's publish: the moment
+            # these batches' version is visible to readers
             for b in live:
-                self._latency.add(done - b.t_enqueue)
+                self._latency.add(sp.t1 - b.t_enqueue)
             with self._lock:
                 self._stats["engine_calls"] += 1
-                self._stats["ingested_values"] += int(n)
-                drained_s = max(done - t0, 1e-6)
-                rate = n / drained_s
+                self._stats["ingested_values"] += n
+                rate = n / max(ingest_s, 1e-6)
                 self._drain_rate = 0.8 * self._drain_rate + 0.2 * rate
-            # RCU publish: refresh the window's read snapshot once per tick
-            # (a no-op until the first reader exists), so poll storms hit
-            # the version cache instead of racing the donation cycle
-            self._publish()
-            return int(n)
+            return n
+
+    def _ingest(self, live: list[_Batch], expired: list[_Batch]) -> tuple[int, float]:
+        """The tick's work after the grab: account the expired batches,
+        ingest the live ones, publish.  Returns (lanes ingested, seconds
+        of the ingest call); (0, 0.0) when nothing was ingested."""
+        if expired:
+            with self._lock:
+                self._stats["expired_batches"] += len(expired)
+                self._stats["shed_mass"] += int(sum(b.values.size for b in expired))
+        if not live:
+            return 0, 0.0
+        t0 = time.monotonic()
+        try:
+            n = self.window.record_batches([(b.key, b.values, b.weights) for b in live])
+        except Exception as e:
+            # partial failure stays partial: count it, shed this tick's
+            # batches, keep the drain thread alive for the next one
+            self._note_drain_error("drain tick", e)
+            with self._lock:
+                self._stats["shed_mass"] += int(sum(b.values.size for b in live))
+            return 0, 0.0
+        ingest_s = time.monotonic() - t0
+        # RCU publish: refresh the window's read snapshot once per tick
+        # (a no-op until the first reader exists), so poll storms hit
+        # the version cache instead of racing the donation cycle
+        self._publish()
+        return int(n), ingest_s
 
     def _note_drain_error(self, where: str, exc: Exception) -> None:
         """Count a failed tick, keep its message, log the first traceback."""
@@ -422,16 +475,19 @@ class IngestGateway:
 
     # ------------------------------------------------------------------ #
     def stats(self) -> dict:
-        """Counter snapshot + live depth (thread-safe copy)."""
+        """Counter snapshot + live depth (thread-safe copy), with a fresh
+        ``spans`` block: the process's totals of the write path's spans."""
         with self._lock:
             out = dict(self._stats)
             out["queue_depth"] = self._depth
             out["drain_rate_values_per_s"] = round(self._drain_rate, 1)
+        out["spans"] = totals(*_SPAN_PREFIXES)
         return out
 
     def latency_quantiles(self, qs=(0.5, 0.95, 0.99)) -> list[float]:
-        """Ingest-to-queryable latency quantiles (seconds), sketched by the
-        gateway itself — NaN-free only once at least one tick completed."""
+        """Per-batch latency quantiles (seconds) from admission to published
+        version (host side; device work may still be in flight), sketched
+        by the gateway itself — NaN-free only once a tick completed."""
         if self._latency.count == 0:
             return [float("nan")] * len(qs)
         return self._latency.quantiles(list(qs))

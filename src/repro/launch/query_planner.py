@@ -33,6 +33,12 @@ image for reads, sitting between the HTTP handler pool and the
 The union-qs axis is padded (duplicating the last q) to a power of two so
 arbitrary poll mixes compile O(log Q) fused-query executables, not one per
 distinct union size.
+
+Spans (``repro.tracing``): ``planner.wait`` is one uncached read from its
+submit to its result (the leader's coalesce sleep and dispatches
+included), ``planner.dispatch`` one fused group (its shape, its window's
+slices, 0 for the live bank, and its padded q count).
+``stats()["spans"]`` carries their totals and ``http.read``'s.
 """
 
 from __future__ import annotations
@@ -46,6 +52,7 @@ from typing import Any, Callable
 import numpy as np
 
 from repro.engine.tables import next_pow2
+from repro.tracing import span, totals
 
 __all__ = ["QueryPlanner", "QueryResultCache"]
 
@@ -213,16 +220,17 @@ class QueryPlanner:
         if hit is not None:
             return hit
         req = _Pending(kind, wslices, qs)
-        with self._lock:
-            self._pending.append(req)
-            lead = not self._leading
+        with span("planner.wait"):
+            with self._lock:
+                self._pending.append(req)
+                lead = not self._leading
+                if lead:
+                    self._leading = True
             if lead:
-                self._leading = True
-        if lead:
-            self._lead()
-        else:
-            self._bump("coalesced")
-        req.event.wait()
+                self._lead()
+            else:
+                self._bump("coalesced")
+            req.event.wait()
         if req.error is not None:
             raise req.error
         return req.result
@@ -271,18 +279,19 @@ class QueryPlanner:
             # reuse O(log Q) compiled fused-query executables
             padded = union + [union[-1]] * (next_pow2(len(union), 1) - len(union))
             try:
-                if kind == "rows":
-                    table = (
-                        snap.row_quantiles(padded)
-                        if w is None
-                        else snap.windowed_row_quantiles(padded, slices=w)
-                    )
-                else:
-                    vals = (
-                        snap.rollup_quantiles(padded)
-                        if w is None
-                        else snap.windowed_rollup(padded, slices=w)
-                    )
+                with span("planner.dispatch", shape=kind, window=w or 0, nq=len(padded)):
+                    if kind == "rows":
+                        table = (
+                            snap.row_quantiles(padded)
+                            if w is None
+                            else snap.windowed_row_quantiles(padded, slices=w)
+                        )
+                    else:
+                        vals = (
+                            snap.rollup_quantiles(padded)
+                            if w is None
+                            else snap.windowed_rollup(padded, slices=w)
+                        )
             except BaseException as e:
                 for r in reqs:
                     r.error = e
@@ -306,4 +315,5 @@ class QueryPlanner:
             out = dict(self._stats)
         out["cache"] = self.cache.stats()
         out["version"] = self.window.version
+        out["spans"] = totals("planner.", "http.read")
         return out

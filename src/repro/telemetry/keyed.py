@@ -33,6 +33,11 @@ per-row levels *survive* window resets.  Every transition is recorded as a
 operators can see *when and why* a key's alpha degraded; ``levels()`` /
 ``alphas()`` report the live resolution, ``drain_events()`` hands the
 event log to the serving layer.
+
+Spans (``repro.tracing``): ``window.record_batches`` (its self time is the
+row-id and concatenate stage; ``engine.ingest`` is its child),
+``window.materialize_events``, ``window.publish`` (a snapshot build, with
+its ``engine.snapshot`` copies) and ``window.advance_slice`` (a seal).
 """
 
 from __future__ import annotations
@@ -50,6 +55,7 @@ from repro.core import sketch_bank as sbank
 from repro.core.ddsketch import DDSketch
 from repro.core.jax_sketch import BucketSpec, effective_alpha
 from repro.engine import ShardedEngine, WindowRing, make_engine
+from repro.tracing import span
 
 __all__ = [
     "OVERFLOW_KEY",
@@ -417,7 +423,7 @@ class KeyedWindow:
         ids: list[np.ndarray] = []
         ws: list[np.ndarray] = []
         any_weighted = any(w is not None for _, _, w in batches)
-        with self.lock:
+        with span("window.record_batches"), self.lock:
             for key, values, weights in batches:
                 v = np.asarray(values, np.float32).reshape(-1)
                 if v.size == 0:
@@ -465,24 +471,25 @@ class KeyedWindow:
         if not self._pending:
             return
         pending, self._pending = self._pending, []
-        row_key = {r: k for k, r in self.key_to_row.items()}
-        for fired, clamped, window in pending:
-            f = np.asarray(fired)
-            if not f.any():
-                continue
-            cm = np.asarray(clamped)
-            for r in np.flatnonzero(f):
-                old = int(self._levels[r])
-                self._levels[r] = old + 1
-                self._events.append(
-                    CollapseEvent(
-                        key=row_key.get(int(r), OVERFLOW_KEY),
-                        old_level=old,
-                        new_level=old + 1,
-                        window=window,
-                        clamped_mass=float(cm[r]),
+        with span("window.materialize_events", pending=len(pending)):
+            row_key = {r: k for k, r in self.key_to_row.items()}
+            for fired, clamped, window in pending:
+                f = np.asarray(fired)
+                if not f.any():
+                    continue
+                cm = np.asarray(clamped)
+                for r in np.flatnonzero(f):
+                    old = int(self._levels[r])
+                    self._levels[r] = old + 1
+                    self._events.append(
+                        CollapseEvent(
+                            key=row_key.get(int(r), OVERFLOW_KEY),
+                            old_level=old,
+                            new_level=old + 1,
+                            window=window,
+                            clamped_mass=float(cm[r]),
+                        )
                     )
-                )
 
     @property
     def events(self) -> "deque[CollapseEvent]":
@@ -505,6 +512,10 @@ class KeyedWindow:
         snap = self._snap
         if snap is not None and snap.version == self._version:
             return snap
+        with span("window.publish"):
+            return self._build_snapshot()
+
+    def _build_snapshot(self) -> BankSnapshot:
         slab = sealed = None
         if self.ring is not None:
             sealed = self.ring.sealed
@@ -635,7 +646,7 @@ class KeyedWindow:
         rebuilds the seal triggered.
         """
         ring = self._require_ring()
-        with self.lock:
+        with span("window.advance_slice"), self.lock:
             self._window += 1
             self._materialize_events()
             merges = ring.seal(self.bank)
