@@ -34,9 +34,9 @@ from repro.launch.roofline import attained_bandwidth, ingest_bytes_model, peaks
 
 # device programs one full bank ingest launches per pipeline: the fused
 # path is ONE dispatch (bucketize + bin + aux stats); sort pays key pass +
-# reducing scatter + the separate stats pass; matmul pays two sign-masked
-# histogram passes + the stats pass
-DISPATCHES_PER_INGEST = {"fused": 1, "sort": 3, "matmul": 3}
+# reducing scatter + the separate stats pass; matmul pays one histogram pass
+# over the stacked sign stores + the stats pass
+DISPATCHES_PER_INGEST = {"fused": 1, "sort": 3, "matmul": 2}
 
 
 def _time(fn, *args, iters=10) -> float:

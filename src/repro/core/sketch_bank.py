@@ -188,7 +188,7 @@ def add_impl(
     level at which all of its batch values are indexable, so nothing clamps.
 
     ``method`` pins the insert pipeline: ``"matmul"`` runs the segmented
-    one-hot histogram per sign, ``"sort"`` compacts a combined composite-key
+    one-hot histogram once over both sign stores, ``"sort"`` compacts a combined composite-key
     stream (sort–reduce) and scatters U <= min(N, 2·K·m) unique triples —
     the input-stationary path whose cost stops growing with the bank size —
     and ``"fused"`` produces the histograms *and* the six aux stats in one

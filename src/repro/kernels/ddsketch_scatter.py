@@ -1,8 +1,8 @@
 """Pallas TPU kernel for the scatter stage of the sort–reduce–scatter ingest.
 
-The matmul-histogram kernels stream the *full* value array once per output
-tile, so bank insert work grows as O(K·m·N) — multiplicative in the bank
-size.  The ingest pipeline (``ref.compact_triples``) first sorts composite
+The single-sketch matmul-histogram kernel streams the *full* value array
+once per bucket tile, and the segmented one once per (row tile, value tile)
+pair that holds a lane, so both pay for every lane.  The ingest pipeline (``ref.compact_triples``) first sorts composite
 ``sign_base + seg*m + bucket`` keys and reduces duplicate runs, so only
 U <= min(N, 2·K·m) unique ``(key, weight)`` triples reach the device — the
 post-collapse regime UDDSketch observes (streams concentrate into a few

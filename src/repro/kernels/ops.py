@@ -23,15 +23,16 @@ shapes, dtypes, mappings and tile configurations asserting exact agreement.
   once per site, so a served path that left the chip's kernels shows it.
 
 ``bank_histograms`` is the bank-insert front door: it routes a batch of
-``(value, segment)`` pairs to the matmul-histogram formulation (work
-O(K·m·N): every output tile streams the whole batch), to the
-sort–reduce–scatter pipeline (O(N log N) sort + compaction to
-U <= min(N, 2·K·m) triples), or to the fused single-dispatch ingest
-(``fused_ingest``: bucketize + bin + aux stats in one program) based on the
-``(N, K, m)`` arithmetic-intensity ratio; ``method=`` pins a pipeline the
-same way ``force=`` pins a backend, and the ``REPRO_INSERT_METHOD``
-environment variable overrides the auto heuristic process-wide (benchmark
-attribution / emergency pinning).
+``(value, segment)`` pairs to the matmul-histogram formulation (one
+row-sparse launch over both sign stores: a stable sort of the lanes by row,
+then one step per bucket tile and (row tile, value tile) pair that holds a
+lane, at most ``nr + nv - 1`` pairs), to the sort–reduce–scatter pipeline
+(O(N log N) sort + compaction to U <= min(N, 2·K·m) triples), or to the
+fused single-dispatch ingest (``fused_ingest``: bucketize + bin + aux stats
+in one program) based on the ``(N, K, m)`` arithmetic-intensity ratio;
+``method=`` pins a pipeline the same way ``force=`` pins a backend, and the
+``REPRO_INSERT_METHOD`` environment variable overrides the auto heuristic
+process-wide (benchmark attribution / emergency pinning).
 """
 
 from __future__ import annotations
@@ -48,7 +49,7 @@ from repro.kernels.bank_range_merge import bank_range_merge_pallas
 from repro.kernels.ddsketch_hist import histogram_pallas
 from repro.kernels.ddsketch_ingest import ddsketch_ingest_pallas
 from repro.kernels.ddsketch_scatter import MAX_RESIDENT_ROWS, ddsketch_scatter_pallas
-from repro.kernels.ddsketch_seg_hist import segment_histogram_pallas
+from repro.kernels.ddsketch_seg_hist import pair_bound, segment_histogram_pallas
 from repro.kernels.fold_pairs import fold_pairs_pallas
 from repro.kernels.ref import (
     MAX_COLLAPSE_LEVEL,
@@ -101,6 +102,10 @@ _DISPATCH_STATS: dict[str, dict[str, int]] = {
     "query_fallbacks": {},
     # ingest batch below one value / triple tile
     "subtile_fallbacks": {},
+    # per-*trace* row-sparse segment_histogram launches ("launches") and the
+    # largest static (row tile, value tile) pair bound one carries
+    # ("max_pair_bound"); the pairs a tick visits show as device time
+    "row_sparse_hist": {},
 }
 _FALLBACK_KINDS = ("tall_bank_fallbacks", "query_fallbacks", "subtile_fallbacks")
 _WARNED: set[tuple[str, str]] = set()
@@ -214,15 +219,20 @@ def insert_method(
     heuristic process-wide (any of ``matmul | sort | fused``) — the
     benchmark-attribution / emergency-pinning knob; invalid values raise.
 
-    On TPU the matmul-histogram kernel streams all N lanes through every
-    ``(row_tile, bucket_tile)`` output tile — work grows with
-    ``ceil(2K/TR) * ceil(m/TB)``; the sort pipeline pays N·log2(N) once and
-    then streams only U <= 2·K·m compacted triples; the fused kernel keeps
-    all 2K rows resident so its streamed work is ``ceil(m/TB) * N`` with no
-    sort stage and no second stats pass.  Hence for full ingests with the
-    rows resident, fused wins unless the bucket-tile count outgrows the
-    sort factor (huge m, small N); histogram-only keeps the PR-3 sort vs
-    matmul rule; banks taller than the resident-row ceiling stay on matmul.
+    On TPU the matmul-histogram kernel stable-sorts the lanes by row, then
+    runs one step per bucket tile and (row tile, value tile) pair that
+    holds a lane — at most ``ceil(2K/TR) + ceil(N/TV) - 1`` pairs, so it
+    streams ``ceil(m/TB) * (N + TV * touched_row_tiles)`` lanes at most,
+    plus the sort, whatever the bank height; the sort pipeline pays
+    N·log2(N) once and then streams only U <= 2·K·m compacted triples; the
+    fused kernel keeps all 2K rows resident so its streamed work is
+    ``ceil(m/TB) * N`` with no sort stage and no second stats pass.  For
+    full ingests with the rows resident, fused wins unless the bucket-tile
+    count outgrows the sort factor (huge m, small N); banks taller than the
+    resident-row ceiling take matmul, the one kernel that tiles the rows.
+    The histogram-only rule below (``out_tiles`` against ``sort_cost``)
+    prices a grid that visits every output tile and is not yet
+    recalibrated for the row-sparse one.
 
     On the XLA reference tier the sort pipeline folds into one key pass +
     one reducing scatter; the fused path adds the stacked stats reductions
@@ -304,13 +314,21 @@ def segment_histogram(
 ) -> jnp.ndarray:
     """Per-segment bucket counts ``(num_segments, m)`` — one dispatch for a
     whole bank of K sketches regardless of K.  ``levels`` holds *per-value*
-    int32 collapse levels (gather per-row levels outside); omitted = level 0."""
+    int32 collapse levels (gather per-row levels outside); omitted = level 0.
+    Each kernel trace counts in ``dispatch_stats()["row_sparse_hist"]``."""
     _check_force(force)
     impl = _impl(force, values.size, value_tile, "segment_histogram")
     if impl == "ref":
         return segment_histogram_ref(
             values, segment_ids, weights, levels, num_segments=num_segments, spec=spec
         )
+    hist = _DISPATCH_STATS["row_sparse_hist"]
+    hist["launches"] = hist.get("launches", 0) + 1
+    hist["max_pair_bound"] = max(
+        hist.get("max_pair_bound", 0),
+        pair_bound(num_segments, values.size, row_tile=row_tile,
+                   value_tile=value_tile),
+    )
     return segment_histogram_pallas(
         values,
         segment_ids,
@@ -407,15 +425,15 @@ def bank_histograms(
     The single entry point behind ``DeviceSketch.add`` / ``SketchBank.add``:
     sign routing (positives keyed on x, negatives on ``|x|``, everything
     else contributing nothing) happens here, and ``method`` picks the
-    pipeline — ``"matmul"`` masks each sign and runs the segmented
-    histogram twice; ``"sort"`` is the sort–reduce–scatter ingest pipeline
-    over one combined composite-key stream into the stacked ``(2K, m)``
-    layout.  On the Pallas tiers the pipeline is materialized literally —
-    ``ref.compact_triples`` (sort + segment-sum) feeds the
-    ``ddsketch_scatter`` kernel U <= min(N, 2·K·m) unique triples — while
-    the XLA twin folds the sort+reduce *into* the reducing scatter-add
-    (order-free exact accumulation needs no physical sort), so the ref tier
-    pays one key pass + one scatter where matmul pays two of each.
+    pipeline — ``"matmul"`` routes each sign into the stacked ``(2K, m)``
+    layout and runs the segmented histogram once over it; ``"sort"`` is the
+    sort–reduce–scatter ingest pipeline over one combined composite-key
+    stream into the same layout.  On the Pallas tiers the pipeline is
+    materialized literally — ``ref.compact_triples`` (sort + segment-sum)
+    feeds the ``ddsketch_scatter`` kernel U <= min(N, 2·K·m) unique
+    triples — while the XLA twin folds the sort+reduce *into* the reducing
+    scatter-add (order-free exact accumulation needs no physical sort), so
+    on the ref tier both pipelines pay one key pass + one scatter.
 
     ``method="fused"`` routes through ``fused_ingest`` (one program:
     bucketize + bin + aux stats) and drops the stats — correct anywhere,
@@ -460,19 +478,27 @@ def bank_histograms(
         return pos, neg
     if method == "matmul":
         x = values.reshape(-1).astype(jnp.float32)
-        pos_vals = jnp.where(x > spec.min_indexable, x, -1.0)
-        neg_vals = jnp.where(x < -spec.min_indexable, -x, -1.0)
         if segment_ids is None:
             kw = dict(spec=spec, value_tile=value_tile, bucket_tile=bucket_tile,
                       force=force)
+            pos_vals = jnp.where(x > spec.min_indexable, x, -1.0)
+            neg_vals = jnp.where(x < -spec.min_indexable, -x, -1.0)
             pos = ddsketch_histogram(pos_vals, weights, levels, **kw)[None]
             neg = ddsketch_histogram(neg_vals, weights, levels, **kw)[None]
-        else:
-            kw = dict(num_segments=num_segments, spec=spec, value_tile=value_tile,
-                      row_tile=row_tile, bucket_tile=bucket_tile, force=force)
-            pos = segment_histogram(pos_vals, segment_ids, weights, levels, **kw)
-            neg = segment_histogram(neg_vals, segment_ids, weights, levels, **kw)
-        return pos, neg
+            return pos, neg
+        # one launch over the stacked (2K, m) layout: positives key into rows
+        # [0, K), negatives (on |x|) into [K, 2K), every other lane into -1
+        s = segment_ids.reshape(-1).astype(jnp.int32)
+        ok = (s >= 0) & (s < num_segments)
+        rows = jnp.where(ok & (x > spec.min_indexable), s,
+                         jnp.where(ok & (x < -spec.min_indexable),
+                                   s + num_segments, -1))
+        both = segment_histogram(
+            jnp.abs(x), rows, weights, levels,
+            num_segments=2 * num_segments, spec=spec, value_tile=value_tile,
+            row_tile=row_tile, bucket_tile=bucket_tile, force=force,
+        )
+        return both[:num_segments], both[num_segments:]
     impl = _impl(force, n, triple_tile, "bank_histograms[sort]")
     if impl != "ref" and 2 * num_segments > MAX_RESIDENT_ROWS and force is None:
         # bank too tall for the resident-row scatter kernel (warn once)
@@ -481,8 +507,7 @@ def bank_histograms(
     if impl == "ref":
         # XLA twin of the pipeline: scatter-add already reduces by key, so
         # the sort + segment-sum stages are the identity here — one
-        # composite-key pass and one reducing scatter replace the matmul
-        # path's two masked key passes and two scatters.
+        # composite-key pass and one reducing scatter.
         keys = composite_keys(
             values, segment_ids, levels, num_segments=num_segments, spec=spec
         )

@@ -230,8 +230,8 @@ def ingest_bytes_model(
       separate stats pass re-reads the lanes and streams six segment
       reductions (4 sums + 2 extrema, each moving data + ids) — ~5x the
       fused path's lane traffic.
-    * ``matmul`` — two sign-masked histogram passes over the lanes plus the
-      same separate stats pass.
+    * ``matmul`` — one histogram pass over the lanes into the stacked
+      ``(2K, m)`` layout plus the same separate stats pass.
 
     Returns ``{"method", "hbm_bytes", "terms": {stage: bytes}}``; feed
     ``hbm_bytes`` and a measured wall-clock to ``attained_bandwidth`` for
@@ -257,7 +257,7 @@ def ingest_bytes_model(
         }
     elif method == "matmul":
         terms = {
-            "hist_passes": 2 * lane * n + 2 * cells,
+            "hist_pass": lane * n + 2 * cells,
             "stats_pass": stats_pass,
         }
     else:

@@ -320,6 +320,7 @@ def test_fused_auto_falls_back_for_tall_banks(monkeypatch, rng):
         "range_merge_calls": {},
         "query_fallbacks": {},
         "subtile_fallbacks": {},
+        "row_sparse_hist": {},
     }
 
 

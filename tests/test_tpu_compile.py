@@ -3,7 +3,9 @@
 No chip is attached: the TPU compiler that ships with JAX compiles for a
 topology it is only told about, and refuses what the chip would refuse
 (block alignment, VMEM budgets, primitives Mosaic cannot lower).  Widths:
-m=2048 buckets, 2K=1024 resident rows, N=65536 lanes, Q=4 qs, D=12 slices.
+m=2048 buckets, 2K=1024 resident rows, N=65536 lanes, Q=4 qs, D=12 slices;
+the segmented histogram also at a 4096-key bank's stacked 8192 rows, the
+row-sparse grid's scalar-prefetch tables and traced pair count.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process may load the TPU library, and every test worker imports
@@ -30,6 +32,7 @@ from repro.kernels.ref import MAX_COLLAPSE_LEVEL, BucketSpec
 
 SPEC = BucketSpec()  # alpha=0.01, m=2048
 K, M, N, Q, D = 512, 2048, 65536, 4, 12  # 2K = 1024 resident rows
+K_TALL = 4096  # keys4096: both sign stores stacked, 8192 rows
 F32, I32 = jnp.float32, jnp.int32
 
 
@@ -66,6 +69,11 @@ def _kernel(name):
                 x, s, w, lv, num_segments=K, spec=SPEC, interpret=False),
             [lanes(F32), lanes(I32), lanes(F32), lanes(I32)],
         ),
+        "ddsketch_seg_hist_tall": (
+            lambda x, s, w, lv: segment_histogram_pallas(
+                x, s, w, lv, num_segments=2 * K_TALL, spec=SPEC, interpret=False),
+            [lanes(F32), lanes(I32), lanes(F32), lanes(I32)],
+        ),
         "ddsketch_scatter": (
             lambda k, w: ddsketch_scatter_pallas(
                 k, w, num_rows=2 * K, num_buckets=M, interpret=False),
@@ -96,7 +104,7 @@ def _kernel(name):
 @pytest.mark.parametrize(
     "name",
     ["ddsketch_ingest", "ddsketch_seg_hist", "ddsketch_scatter", "ddsketch_hist",
-     "fold_pairs", "bank_quantiles", "bank_range_merge"],
+     "fold_pairs", "bank_quantiles", "bank_range_merge", "ddsketch_seg_hist_tall"],
 )
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = _kernel(name)
