@@ -35,8 +35,6 @@ pairs that hold a lane:
 4. The output aliases a zeroed buffer, so row tiles no pair visits read
    zero.
 
-A bank of one row tile skips the sort: its pairs are the value tiles.
-
 VMEM budget per step (defaults TV=2048, TR=8, TB=512, f32):
   rows+indices+weights 24 KiB + A (TR,TV) 64 KiB + M (TV,TB) 4 MiB
   + out tile (TR,TB) 16 KiB  << 16 MiB.
@@ -66,7 +64,7 @@ def pair_bound(
     """Most (row tile, value tile) pairs a launch over ``n`` lanes can visit."""
     nr = -(-num_segments // row_tile)
     nv = max(-(-n // value_tile), 1)
-    return nv if nr == 1 else nr + nv - 1
+    return nr + nv - 1
 
 
 def pair_table(
@@ -215,7 +213,6 @@ def segment_histogram_pallas(
         lev = jnp.pad(lev, (0, pad), constant_values=0)
     rows_padded = num_segments + ((-num_segments) % row_tile)
     buckets_padded = spec.num_buckets + ((-spec.num_buckets) % bucket_tile)
-    nv = x.shape[0] // value_tile
     nr = rows_padded // row_tile
     nb = buckets_padded // bucket_tile
 
@@ -228,17 +225,11 @@ def segment_histogram_pallas(
     row = jnp.where(mask, s, rows_padded)  # sentinel: past every row tile
     # XLA's log, as on the reference tier
     idx = jnp.clip(level_keys(x, lev, spec) - spec.offset, 0, spec.num_buckets - 1)
-    if nr == 1:
-        rt = jnp.zeros((nv,), jnp.int32)
-        vt = jnp.arange(nv, dtype=jnp.int32)
-        count = jnp.int32(nv)
-        steps = nv
-    else:
-        row, idx, w = jax.lax.sort((row, idx, w), num_keys=1, is_stable=True)
-        rt, vt, count = pair_table(
-            row, num_row_tiles=nr, row_tile=row_tile, value_tile=value_tile
-        )
-        steps = jnp.maximum(count, 1)  # one step zeroes a block when no lane is valid
+    row, idx, w = jax.lax.sort((row, idx, w), num_keys=1, is_stable=True)
+    rt, vt, count = pair_table(
+        row, num_row_tiles=nr, row_tile=row_tile, value_tile=value_tile
+    )
+    steps = jnp.maximum(count, 1)  # one step zeroes a block when no lane is valid
     # lanes ride as one (1, N) row so each (1, TV) block is tile-aligned
     row, idx, w = (a.reshape(1, -1) for a in (row, idx, w))
     lane_block = pl.BlockSpec(

@@ -49,7 +49,7 @@ from repro.kernels.bank_range_merge import bank_range_merge_pallas
 from repro.kernels.ddsketch_hist import histogram_pallas
 from repro.kernels.ddsketch_ingest import ddsketch_ingest_pallas
 from repro.kernels.ddsketch_scatter import MAX_RESIDENT_ROWS, ddsketch_scatter_pallas
-from repro.kernels.ddsketch_seg_hist import pair_bound, segment_histogram_pallas
+from repro.kernels.ddsketch_seg_hist import segment_histogram_pallas
 from repro.kernels.fold_pairs import fold_pairs_pallas
 from repro.kernels.ref import (
     MAX_COLLAPSE_LEVEL,
@@ -102,10 +102,6 @@ _DISPATCH_STATS: dict[str, dict[str, int]] = {
     "query_fallbacks": {},
     # ingest batch below one value / triple tile
     "subtile_fallbacks": {},
-    # per-*trace* row-sparse segment_histogram launches ("launches") and the
-    # largest static (row tile, value tile) pair bound one carries
-    # ("max_pair_bound"); the pairs a tick visits show as device time
-    "row_sparse_hist": {},
 }
 _FALLBACK_KINDS = ("tall_bank_fallbacks", "query_fallbacks", "subtile_fallbacks")
 _WARNED: set[tuple[str, str]] = set()
@@ -314,21 +310,13 @@ def segment_histogram(
 ) -> jnp.ndarray:
     """Per-segment bucket counts ``(num_segments, m)`` — one dispatch for a
     whole bank of K sketches regardless of K.  ``levels`` holds *per-value*
-    int32 collapse levels (gather per-row levels outside); omitted = level 0.
-    Each kernel trace counts in ``dispatch_stats()["row_sparse_hist"]``."""
+    int32 collapse levels (gather per-row levels outside); omitted = level 0."""
     _check_force(force)
     impl = _impl(force, values.size, value_tile, "segment_histogram")
     if impl == "ref":
         return segment_histogram_ref(
             values, segment_ids, weights, levels, num_segments=num_segments, spec=spec
         )
-    hist = _DISPATCH_STATS["row_sparse_hist"]
-    hist["launches"] = hist.get("launches", 0) + 1
-    hist["max_pair_bound"] = max(
-        hist.get("max_pair_bound", 0),
-        pair_bound(num_segments, values.size, row_tile=row_tile,
-                   value_tile=value_tile),
-    )
     return segment_histogram_pallas(
         values,
         segment_ids,

@@ -320,7 +320,6 @@ def test_fused_auto_falls_back_for_tall_banks(monkeypatch, rng):
         "range_merge_calls": {},
         "query_fallbacks": {},
         "subtile_fallbacks": {},
-        "row_sparse_hist": {},
     }
 
 
