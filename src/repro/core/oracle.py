@@ -17,8 +17,9 @@ def exact_quantile(sorted_values: np.ndarray, q: float) -> float:
     n = len(sorted_values)
     if n == 0:
         return math.nan
-    rank = int(math.floor(1 + q * (n - 1)))  # 1-based
-    return float(sorted_values[rank - 1])
+    # rank floor(q (n - 1)), 0-based; ``1 + q (n - 1)`` would round up to
+    # the next integer for q within an ulp of 1
+    return float(sorted_values[int(math.floor(q * (n - 1)))])
 
 
 def exact_quantiles(values, qs) -> list[float]:

@@ -12,13 +12,14 @@ from repro.engine.tables import (
     padded_row_count,
 )
 from repro.engine.engine import SketchEngine, shared_engine, window_merge_bank
-from repro.engine.ring import WindowRing
+from repro.engine.ring import StaleCover, WindowRing
 from repro.engine.sharded import ShardedBank, ShardedEngine, make_engine
 
 __all__ = [
     "SketchEngine",
     "ShardedEngine",
     "ShardedBank",
+    "StaleCover",
     "WindowRing",
     "make_engine",
     "shared_engine",

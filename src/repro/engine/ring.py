@@ -13,7 +13,9 @@ O(log S) cached node reads feeding ONE fused device dispatch:
   nodes ``S..2S-2`` hold the merge tree: level-j node slots store
   pre-merged blocks of ``2**j`` consecutive slices.  All mutation is
   donated (``seal_slice`` / ``merge_node``), so the ring's footprint is
-  exactly one slab — no per-slice allocations, ever.
+  exactly one slab — no per-slice allocations, ever, and no copies for
+  readers: a read taken at an earlier seal count runs against the live
+  slab for as long as ``cover_intact`` holds (below).
 
 * **Incremental cascade** — sealing absolute slice ``a`` writes leaf
   ``a mod S`` and then, for each level ``j`` with ``(a+1) % 2**j == 0``,
@@ -47,7 +49,12 @@ import numpy as np
 from repro.core.sketch_bank import SketchBank
 from repro.engine.engine import SketchEngine
 
-__all__ = ["WindowRing"]
+__all__ = ["StaleCover", "WindowRing"]
+
+
+class StaleCover(RuntimeError):
+    """A read's node cover was overwritten by seals since its seal count:
+    re-take the read at a newer count."""
 
 
 class WindowRing:
@@ -132,10 +139,10 @@ class WindowRing:
         """Canonical aligned-block node cover of ``[lo, hi)`` *as of* a
         past ``sealed`` count — pure slot arithmetic, no live bookkeeping.
 
-        The snapshot read path: a slab copied when ``self.sealed`` was
-        ``sealed`` holds exactly the blocks this decomposition names (the
-        freshness-by-construction invariant), so covers computed against
-        the captured count stay valid however far the live ring advances.
+        The snapshot read path: the slab as it stood when ``self.sealed``
+        was ``sealed`` holds exactly the blocks this decomposition names
+        (the freshness-by-construction invariant), and the live slab still
+        does while ``cover_intact(sealed, w)`` holds.
         """
         if not (max(0, sealed - self.num_slices) <= lo <= hi <= sealed):
             raise ValueError(
@@ -190,7 +197,7 @@ class WindowRing:
 
         Pure math over the ring's static layout — safe to call without
         holding the writer lock, against ring state that has since moved
-        on.  Pair with a slab snapshot taken at the same count.
+        on.  The live slab answers it while ``cover_intact`` holds.
         """
         w = int(window_slices)
         if w < 1:
@@ -208,6 +215,21 @@ class WindowRing:
         nodes[: len(cover)] = cover
         valid[: len(cover)] = 1.0
         return nodes, valid
+
+    def cover_intact(self, sealed: int, window_slices: int) -> bool:
+        """Whether the live slab still holds every node of the cover
+        ``query_args_at(sealed, window_slices)`` names.
+
+        That cover reads sealed slices ``[sealed - (w - 1), sealed)``.  The
+        seal at count ``s`` writes leaf ``s mod S``, which held slice
+        ``s - S``, and for each level ``j`` with ``(s + 1) % 2**j == 0`` the
+        slot that held block ``[s + 1 - 2**j - S, s + 1 - S)``.  So the seals
+        ``sealed, ..., sealed + d - 1`` leave the cover untouched whenever
+        ``d <= S - w + 1`` (exactly then, where the cover's oldest slice is
+        read as a leaf; a level-``j`` block there lasts ``2**j - 1`` seals
+        longer).  ``w <= S`` makes one seal always safe.
+        """
+        return self.sealed - int(sealed) <= self.num_slices - int(window_slices) + 1
 
     def query_args(self, window_slices: int) -> tuple[np.ndarray, np.ndarray]:
         """Padded ``(nodes, valid)`` arrays covering the last

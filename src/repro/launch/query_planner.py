@@ -30,6 +30,11 @@ image for reads, sitting between the HTTP handler pool and the
   tier answers ``If-None-Match`` re-polls with 304 and no body before any
   planner work at all (see ``launch.http_api``).
 
+* **stale covers** — a windowed read runs against the live ring slab, and
+  a snapshot the ring has sealed ``S - w + 1`` times past can no longer
+  answer it (``StaleCover``): the round re-takes the newest snapshot, and
+  each answer carries the version of the snapshot that gave it.
+
 The union-qs axis is padded (duplicating the last q) to a power of two so
 arbitrary poll mixes compile O(log Q) fused-query executables, not one per
 distinct union size.
@@ -51,6 +56,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.engine.ring import StaleCover
 from repro.engine.tables import next_pow2
 from repro.tracing import span, totals
 
@@ -279,19 +285,7 @@ class QueryPlanner:
             # reuse O(log Q) compiled fused-query executables
             padded = union + [union[-1]] * (next_pow2(len(union), 1) - len(union))
             try:
-                with span("planner.dispatch", shape=kind, window=w or 0, nq=len(padded)):
-                    if kind == "rows":
-                        table = (
-                            snap.row_quantiles(padded)
-                            if w is None
-                            else snap.windowed_row_quantiles(padded, slices=w)
-                        )
-                    else:
-                        vals = (
-                            snap.rollup_quantiles(padded)
-                            if w is None
-                            else snap.windowed_rollup(padded, slices=w)
-                        )
+                snap, out = self._dispatch(snap, kind, w, padded)
             except BaseException as e:
                 for r in reqs:
                     r.error = e
@@ -301,13 +295,36 @@ class QueryPlanner:
             for r in reqs:
                 idx = [col[q] for q in r.qs]
                 if kind == "rows":
-                    r.result = (snap.version, table[:, idx], snap.key_to_row)
+                    r.result = (snap.version, out[:, idx], snap.key_to_row)
                 else:
-                    r.result = (snap.version, [vals[i] for i in idx])
+                    r.result = (snap.version, [out[i] for i in idx])
                 # fill under the *executed* snapshot's version: if the
                 # writer bumped mid-round the entry is simply never hit
                 self.cache.put(((r.kind, w, r.qs), snap.version), r.result)
                 r.event.set()
+
+    def _dispatch(self, snap, kind: str, w: int | None, padded: list):
+        """One fused read -> ``(snapshot that answered, result)``; a
+        windowed read whose cover the ring has sealed past re-takes the
+        newest snapshot."""
+        while True:
+            try:
+                with span("planner.dispatch", shape=kind, window=w or 0, nq=len(padded)):
+                    if kind == "rows":
+                        out = (
+                            snap.row_quantiles(padded)
+                            if w is None
+                            else snap.windowed_row_quantiles(padded, slices=w)
+                        )
+                    else:
+                        out = (
+                            snap.rollup_quantiles(padded)
+                            if w is None
+                            else snap.windowed_rollup(padded, slices=w)
+                        )
+                return snap, out
+            except StaleCover:
+                snap = self.window.snapshot()
 
     # ------------------------------------------------------------------ #
     def stats(self) -> dict:

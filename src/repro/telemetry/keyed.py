@@ -37,7 +37,11 @@ event log to the serving layer.
 Spans (``repro.tracing``): ``window.record_batches`` (its self time is the
 row-id and concatenate stage; ``engine.ingest`` is its child),
 ``window.materialize_events``, ``window.publish`` (a snapshot build, with
-its ``engine.snapshot`` copies) and ``window.advance_slice`` (a seal).
+its ``engine.snapshot`` bank copy), ``window.advance_slice`` (a seal) ⊃
+``window.ring_seal`` (args ``sealed``, ``merges``: the seal and the bank
+reset under the ring lock), and ``window.ring_read`` (args ``sealed``,
+``ring_sealed``, ``window``, ``retried``: one windowed read's enqueue under
+the ring lock).
 """
 
 from __future__ import annotations
@@ -49,12 +53,13 @@ from typing import NamedTuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import sketch_bank as sbank
 from repro.core.ddsketch import DDSketch
 from repro.core.jax_sketch import BucketSpec, effective_alpha
-from repro.engine import ShardedEngine, WindowRing, make_engine
+from repro.engine import ShardedEngine, StaleCover, WindowRing, make_engine
 from repro.tracing import span
 
 __all__ = [
@@ -134,13 +139,16 @@ class CollapseEvent(NamedTuple):
 class BankSnapshot:
     """An immutable, version-stamped read view of a ``KeyedWindow``.
 
-    Holds device-side *copies* of the bank (and ring slab, when the window
-    has one) minted by ``SketchEngine.snapshot`` — fresh buffers the
-    writer's donated ingest/seal/reset paths can never consume — plus a
-    host copy of the key->row map taken at the same instant.  Every query
-    method here runs **lock-free**: the drain thread keeps donating into
+    Holds a device-side *copy* of the live bank minted by
+    ``SketchEngine.snapshot`` — a fresh buffer the writer's donated
+    ingest/reset paths can never consume — plus a host copy of the
+    key->row map and the ring's seal count taken at the same instant.
+    Live-bank reads run **lock-free**: the drain thread keeps donating into
     the live bank while any number of reader threads answer quantiles off
-    this view.
+    this view.  Windowed reads take no slab copy: they run against the
+    live ring slab (``KeyedWindow._ring_read``), which still holds this
+    view's node cover for ``S - w + 1`` seals; past that they raise
+    ``StaleCover`` and the caller re-takes the newest snapshot.
 
     ``version`` stamps the window state the view reflects (exactly one
     bump per ingest tick, slice seal, or window reset — the discrete
@@ -156,11 +164,10 @@ class BankSnapshot:
         "key_to_row",
         "ring",
         "sealed",
-        "slab",
         "window",
     )
 
-    def __init__(self, *, version, window, bank, key_to_row, sealed, slab):
+    def __init__(self, *, version, window, bank, key_to_row, sealed):
         self.version = version
         self.window = window
         self.spec = window.spec
@@ -169,7 +176,6 @@ class BankSnapshot:
         self.key_to_row = key_to_row
         self.ring = window.ring
         self.sealed = sealed  # ring seal count at capture (None: no ring)
-        self.slab = slab  # slab copy at ``sealed`` (shared between snaps)
 
     # fused device reads ------------------------------------------------- #
     def row_quantiles(self, qs) -> np.ndarray:
@@ -180,14 +186,15 @@ class BankSnapshot:
         """Raw per-row windowed quantiles ``(K, len(qs))``.
 
         The node cover comes from ``query_args_at`` evaluated at the
-        *captured* seal count — pure layout math, valid however far the
-        live ring has advanced since this snapshot was taken.
+        *captured* seal count and is read from the live slab; raises
+        ``StaleCover`` once the ring has sealed past it.
         """
+        return self._windowed(self.engine.window_query, qs, window, slices)
+
+    def _windowed(self, read, qs, window, slices) -> np.ndarray:
         w = self.window.resolve_window(window=window, slices=slices)
         nodes, valid = self.ring.query_args_at(self.sealed, w)
-        return np.asarray(
-            self.engine.window_query(self.slab, self.bank, nodes, valid, True, qs)
-        )
+        return np.asarray(self.window._ring_read(self, read, w, nodes, valid, qs))
 
     # keyed views (same contracts as the KeyedWindow methods) ------------ #
     def quantiles(self, key: str, qs) -> list[float]:
@@ -224,11 +231,7 @@ class BankSnapshot:
         }
 
     def windowed_rollup(self, qs, *, window=None, slices=None) -> list[float]:
-        w = self.window.resolve_window(window=window, slices=slices)
-        nodes, valid = self.ring.query_args_at(self.sealed, w)
-        out = np.asarray(
-            self.engine.window_rollup(self.slab, self.bank, nodes, valid, True, qs)
-        )
+        out = self._windowed(self.engine.window_rollup, qs, window, slices)
         return [float(v) for v in out]
 
     def total_mass(self) -> float:
@@ -265,12 +268,15 @@ class KeyedWindow:
     already-deleted buffer to the engine or lose one thread's update.
     Readers (``quantiles``/``total_mass``/...) do NOT contend on that
     lock: they run against the version-stamped ``BankSnapshot`` published
-    by ``snapshot()`` — device-side copies the donation cycle can never
-    touch — and only take the lock for the brief rebuild when the version
-    moved (RCU-style: the lock shrank from covering every query dispatch
-    to covering the snapshot pointer swap).  ``KeyedAggregator.flush``
-    holds the lock across its read-then-reset so the window swap is
-    atomic too.
+    by ``snapshot()`` — a device-side bank copy the donation cycle can
+    never touch — and only take the lock for the brief rebuild when the
+    version moved (RCU-style: the lock shrank from covering every query
+    dispatch to covering the snapshot pointer swap).  Windowed reads
+    enqueue against the live ring slab under a second, short ring lock,
+    which a seal's donated enqueue also takes, so the device runs a read
+    already enqueued before the seal that follows it.
+    ``KeyedAggregator.flush`` holds the lock across its read-then-reset so
+    the window swap is atomic too.
     """
 
     def __init__(
@@ -330,9 +336,12 @@ class KeyedWindow:
         # slice seal / reset) + the published snapshot readers run against
         self._version = 0
         self._snap: BankSnapshot | None = None
-        self._slab_snap: tuple[int, object] | None = None  # (sealed, copy)
         self._snap_builds = 0
-        self._slab_builds = 0
+        # held while a windowed read or a seal is enqueued (never while
+        # the device runs), so a read enqueued first runs on its slab
+        self._ring_lock = threading.Lock()
+        self._ring_reads = 0
+        self._ring_read_retries = 0
 
     def _initial_free_pool(self) -> list[int]:
         """Usable rows, ordered so ``pop()`` balances load.
@@ -473,11 +482,13 @@ class KeyedWindow:
         pending, self._pending = self._pending, []
         with span("window.materialize_events", pending=len(pending)):
             row_key = {r: k for k, r in self.key_to_row.items()}
-            for fired, clamped, window in pending:
-                f = np.asarray(fired)
-                if not f.any():
-                    continue
-                cm = np.asarray(clamped)
+            # one batched transfer per kind, not one blocking round trip
+            # per parked array (a seal reads back hundreds of ticks')
+            fired = jax.device_get([f for f, _, _ in pending])
+            hot = [i for i, f in enumerate(fired) if f.any()]
+            clamped = jax.device_get([pending[i][1] for i in hot])
+            for i, cm in zip(hot, clamped):
+                f, window = fired[i], pending[i][2]
                 for r in np.flatnonzero(f):
                     old = int(self._levels[r])
                     self._levels[r] = old + 1
@@ -516,24 +527,12 @@ class KeyedWindow:
             return self._build_snapshot()
 
     def _build_snapshot(self) -> BankSnapshot:
-        slab = sealed = None
-        if self.ring is not None:
-            sealed = self.ring.sealed
-            cached = self._slab_snap
-            if cached is None or cached[0] != sealed:
-                # the slab only mutates on seal, so one copy per seal
-                # count serves every bank snapshot taken in between
-                cached = (sealed, self.engine.snapshot(self.ring.slab))
-                self._slab_builds += 1
-                self._slab_snap = cached
-            slab = cached[1]
         snap = BankSnapshot(
             version=self._version,
             window=self,
             bank=self.engine.snapshot(self.bank),
             key_to_row=dict(self.key_to_row),
-            sealed=sealed,
-            slab=slab,
+            sealed=None if self.ring is None else self.ring.sealed,
         )
         self._snap_builds += 1
         self._snap = snap
@@ -626,6 +625,29 @@ class KeyedWindow:
     # ------------------------------------------------------------------ #
     # sliding-window ring (num_slices-enabled windows over time slices)
     # ------------------------------------------------------------------ #
+    def _ring_read(self, snap: BankSnapshot, read, w: int, nodes, valid, qs):
+        """Enqueue ``read`` (``engine.window_query`` / ``window_rollup``) of
+        ``snap``'s cover against the live slab; returns the device result.
+
+        Under the ring lock: take the slab, check the cover is intact at
+        ``snap.sealed`` (``WindowRing.cover_intact``), enqueue.  A seal
+        enqueued later donates the slab only after this read has run.
+        Raises ``StaleCover`` when the ring has sealed past the cover.
+        """
+        ring = self.ring
+        with span("window.ring_read", sealed=snap.sealed, window=w) as sp:
+            with self._ring_lock:
+                intact = ring.cover_intact(snap.sealed, w)
+                sp.set(ring_sealed=ring.sealed, retried=int(not intact))
+                if not intact:
+                    self._ring_read_retries += 1
+                    raise StaleCover(
+                        f"{w}-slice cover at seal {snap.sealed} overwritten "
+                        f"by the ring at seal {ring.sealed}"
+                    )
+                self._ring_reads += 1
+                return read(ring.slab, snap.bank, nodes, valid, True, qs)
+
     def _require_ring(self) -> WindowRing:
         if self.ring is None:
             raise ValueError(
@@ -649,8 +671,10 @@ class KeyedWindow:
         with span("window.advance_slice"), self.lock:
             self._window += 1
             self._materialize_events()
-            merges = ring.seal(self.bank)
-            self.bank = self.engine.reset(self.bank)
+            with span("window.ring_seal") as sp, self._ring_lock:
+                merges = ring.seal(self.bank)
+                self.bank = self.engine.reset(self.bank)
+                sp.set(sealed=ring.sealed, merges=merges)
             self._version += 1
         return merges
 
@@ -697,8 +721,8 @@ class KeyedWindow:
 
         One fused engine dispatch — gather the ring's O(log S) cached
         nodes, level-reconcile, reduce the slice axis, Algorithm 2 — vs
-        N-1 host-looped merges.  Runs against the published snapshot
-        (slab + bank copies), lock-free vs concurrent seals and ingest.
+        N-1 host-looped merges.  Runs against the published snapshot's
+        bank copy and the live slab, lock-free vs concurrent ingest.
         """
         self._require_ring()
         return self.snapshot().windowed_quantiles(
@@ -721,11 +745,17 @@ class KeyedWindow:
         return self.snapshot().windowed_rollup(qs, window=window, slices=slices)
 
     def ring_stats(self) -> dict | None:
-        """Ring occupancy / maintenance metadata (None when no ring)."""
+        """Ring occupancy / maintenance metadata, with the windowed reads
+        enqueued on the live slab (``ring_reads``) and those refused for a
+        stale cover (``ring_read_retries``); None when no ring."""
         if self.ring is None:
             return None
-        with self.lock:
-            return self.ring.stats()
+        with self._ring_lock:
+            return {
+                **self.ring.stats(),
+                "ring_reads": self._ring_reads,
+                "ring_read_retries": self._ring_read_retries,
+            }
 
     def engine_stats(self) -> dict:
         """Executable-cache + ring + read-path observability (/stats)."""
@@ -735,11 +765,10 @@ class KeyedWindow:
                 "read_path": {
                     "version": self._version,
                     "snapshot_builds": self._snap_builds,
-                    "slab_snapshot_builds": self._slab_builds,
                 },
             }
-            if self.ring is not None:
-                out["ring"] = self.ring.stats()
+        if self.ring is not None:
+            out["ring"] = self.ring_stats()
         return out
 
     def reset(self) -> None:

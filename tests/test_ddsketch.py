@@ -48,6 +48,21 @@ def test_alpha_accurate_with_negatives_and_zero(data, q):
     assert abs(est - actual) <= ALPHA * abs(actual) + 1e-12
 
 
+@pytest.mark.parametrize("data, q, want", [
+    ([0.0, 1.0], 0.9999999999999999, 0.0),
+    ([1.0, 2.0], 0.9999999999999999, 1.0),
+    ([1.0, 2.0, 3.0], 0.5, 2.0),
+    ([1.0, 2.0], 1.0, 2.0),
+])
+def test_exact_quantile_takes_rank_floor(data, q, want):
+    """The oracle's rank is floor(q (n - 1)), also where 1 + q (n - 1)
+    rounds up to the next integer (q within an ulp of 1)."""
+    assert exact_quantile(np.asarray(data), q) == want
+    sk = DDSketch(ALPHA, max_bins=None)
+    sk.extend(data)
+    assert abs(sk.quantile(q) - want) <= ALPHA * abs(want)
+
+
 @given(
     parts=st.lists(st.lists(floats_pos, min_size=1, max_size=100), min_size=2, max_size=5),
     q=qs_strategy,

@@ -171,6 +171,39 @@ def test_collapse_transition_events(rng):
         assert e.old_level >= events[-1].new_level
 
 
+def test_collapse_events_transfer_in_one_batch_per_kind(rng, monkeypatch):
+    """Parked (fired, clamped) outputs reach the host in one batched
+    ``device_get`` of every tick's ``fired`` and one of the ``clamped`` of
+    the ticks that fired, and log exactly what per-tick readbacks log."""
+    import jax
+
+    wide = (10.0 ** rng.uniform(-15.0, 9.0, 400)).astype(np.float32)
+    narrow = (rng.pareto(1.0, 100) + 1.0).astype(np.float32)
+    ticks = [("cold", narrow), ("hot", wide), ("cold", narrow), ("hot", wide * 1e3)]
+
+    step = KeyedWindow(TelemetryConfig().spec, capacity=4)
+    want, fired_ticks = [], 0
+    for key, v in ticks:
+        step.record(key, v)
+        new = list(step.events)[len(want):]
+        fired_ticks += bool(new)
+        want += new
+    assert 0 < fired_ticks < len(ticks)
+
+    window = KeyedWindow(TelemetryConfig().spec, capacity=4)
+    for key, v in ticks:
+        window.record(key, v)
+    real, calls = jax.device_get, []
+
+    def counted(x):
+        calls.append(len(x))
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    assert list(window.events) == want
+    assert calls == [len(ticks), fired_ticks]
+
+
 def test_collapse_events_disabled(rng):
     window = KeyedWindow(
         TelemetryConfig().spec, capacity=2, track_collapse_events=False

@@ -1,9 +1,12 @@
-"""Program spans (``repro.tracing``) on the write path.
+"""Program spans (``repro.tracing``) on the write path and the ring.
 
 A traced run must put the spans on the profiler's host plane, nested as
 the work nests, and tie each POST to the tick that ingested it by the
 gateway's batch ``seq``; with no profiler session the span totals must
-reconcile with the gateway's own counters.
+reconcile with the gateway's own counters.  The ring's spans
+(``window.ring_read`` inside a windowed ``planner.dispatch``,
+``window.ring_seal`` inside ``window.advance_slice``) count what the
+ring's counters count and reach ``/stats``.
 """
 
 import json
@@ -207,6 +210,67 @@ def test_stats_with_spans_is_strict_json(rng):
     assert read["http.read"]["count"] >= 1 and read["planner.wait"]["count"] >= 1
     assert read["planner.dispatch"]["count"] >= 1
     assert payload["gateway"]["latency_s"][0] > 0
+
+
+def _get_json(url):
+    with urlopen(url, timeout=30) as resp:
+        return _strict_json(resp.read())
+
+
+def test_ring_spans_nest_count_and_reach_stats(rng, tmp_path):
+    window = KeyedWindow(BucketSpec(), capacity=8, num_slices=4, slice_seconds=60.0)
+    gw = IngestGateway(window, start=False)
+    reads = ("/quantiles?endpoint=/a&slices=2&q=0.5", "/rollup?slices=3&q=0.5,0.9")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with QuantileHTTPServer(TelemetryFacade(window, KeyedAggregator(window.spec)),
+                            gateway=gw) as server:
+        def step():
+            _post(server.url, "/a", (rng.pareto(1.0, 8) + 1.0).tolist())
+            gw.flush()
+            window.advance_slice()
+            for path in reads:
+                _get_json(server.url + path)
+
+        step()  # compile every shape untraced
+        before, ring0 = gw.stats()["spans"], window.ring_stats()
+        reads0 = _count("http.read")
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            step()
+            step()
+            _await_count("http.read", reads0 + 4)
+        finally:
+            jax.profiler.stop_trace()
+        payload = _get_json(server.url + "/stats")
+    after, ring1 = payload["gateway"]["spans"], window.ring_stats()
+
+    def d(name):
+        return after[name]["count"] - before[name]["count"]
+
+    assert d("window.ring_seal") == d("window.advance_slice") == 2
+    assert d("window.ring_read") == ring1["ring_reads"] - ring0["ring_reads"] == 4
+    assert ring1["ring_read_retries"] == ring0["ring_read_retries"] == 0
+    assert payload["engine"]["ring"]["ring_reads"] == ring1["ring_reads"]
+    assert payload["engine"]["ring"]["ring_read_retries"] == 0
+
+    lines = _host_lines(tmp_path)
+    seals = [(line, e) for line in lines for e in line if e[0] == "window.advance_slice"]
+    assert len(seals) == 2
+    for line, adv in seals:
+        (seal,) = _children(line, adv, "window.ring_seal")
+        assert seal[3]["merges"] in (0, 1, 2)
+    assert sorted(_children(line, adv, "window.ring_seal")[0][3]["sealed"]
+                  for line, adv in seals) == [ring1["sealed"] - 1, ring1["sealed"]]
+    dispatches = [(line, e) for line in lines for e in line
+                  if e[0] == "planner.dispatch" and e[3]["window"] > 0]
+    assert len(dispatches) == 4
+    for line, disp in dispatches:
+        (read,) = _children(line, disp, "window.ring_read")
+        args = read[3]
+        assert args["window"] == disp[3]["window"] and args["retried"] == 0
+        assert args["sealed"] == args["ring_sealed"]
 
 
 def test_totals_lose_no_update_under_thread_churn():

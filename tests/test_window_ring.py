@@ -22,7 +22,12 @@ The tentpole contract, test by test:
   drain tick and ``flush()`` never advances it;
 * sharded parity: the same ring over a row-sharded engine answers windowed
   queries bit-exactly vs the single-device engine (subprocess-covered on
-  single-device hosts).
+  single-device hosts);
+* reads on the live slab: a cover taken at seal count ``s`` stays
+  bit-identical for ``S - w + 1`` more seals (``WindowRing.cover_intact``),
+  a snapshot read inside that answers as the host ``DDSketch`` merge of its
+  slices, and a read enqueued before a donated seal returns the pre-seal
+  answer.
 """
 
 import json
@@ -41,7 +46,8 @@ import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
 from repro.core import sketch_bank as sb
-from repro.engine import SketchEngine, WindowRing
+from repro.core.ddsketch import DDSketch
+from repro.engine import SketchEngine, StaleCover, WindowRing
 from repro.kernels import ops
 from repro.kernels.ref import (
     MAX_COLLAPSE_LEVEL,
@@ -645,3 +651,137 @@ def test_sharded_window_subprocess():
         timeout=600,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
+
+
+# --------------------------------------------------------------------- #
+# reads on the live slab: the cover's lifetime, snapshots, donated seals
+# --------------------------------------------------------------------- #
+def _distinct_slice(spec, k, t):
+    """Slice ``t``'s bank: values no other slice holds, so every node that
+    a seal rewrites changes bits."""
+    vals = np.asarray([1.5 * (t + 1), 2.5 * (t + 1) + 0.25], np.float32)
+    return sb.add(sb.empty(spec, k), jnp.asarray(vals),
+                  jnp.zeros(vals.size, jnp.int32), spec=spec)
+
+
+@pytest.mark.parametrize("s_ring", [4, 8])
+def test_cover_intact_rule_exhaustive(s_ring):
+    """Every seal count ``s``, delay ``d`` and window ``w``: the nodes that
+    ``query_args_at(s, w)`` names are bit-identical after ``d`` more seals
+    exactly while ``s + d < e + S`` (``e`` the end of the cover's oldest
+    block), and ``cover_intact`` never passes a changed cover.  Where the
+    oldest slice is read as a leaf of a full cover that bound is
+    ``d <= S - w + 1``, and the rule is exact there."""
+    spec, k = SMALL, 2
+    eng = SketchEngine(spec, k)
+    ring = WindowRing(eng, s_ring)
+    top = 3 * s_ring
+    history = [jax.tree.map(np.asarray, ring.slab)]
+    rule = {}  # (s, w, d) -> cover_intact as the ring said at seal s + d
+    for t in range(top + s_ring + 2):
+        for s in range(max(0, t - s_ring - 1), min(t, top) + 1):
+            for w in range(1, s_ring + 1):
+                rule[(s, w, t - s)] = ring.cover_intact(s, w)
+        ring.seal(_distinct_slice(spec, k, t))
+        history.append(jax.tree.map(np.asarray, ring.slab))
+    exact_cases = {w: 0 for w in range(2, s_ring + 1)}
+    for (s, w, d), said in rule.items():
+        nodes, valid = ring.query_args_at(s, w)
+        cover = nodes[valid > 0].tolist()
+        same = all(
+            np.array_equal(a[cover], b[cover])
+            for a, b in zip(jax.tree.leaves(history[s]),
+                            jax.tree.leaves(history[s + d]))
+        )
+        if cover:
+            lo = s - min(w - 1, s)
+            first = cover[0]
+            level = max(j for j in range(ring.tree_levels + 1)
+                        if first >= ring._base[j])
+            end = lo + (1 << level)
+            assert same == (s + d < end + s_ring), (s, w, d, cover)
+        else:
+            assert same
+        if said:
+            assert same, ("rule passed a changed cover", s, w, d)
+        if w >= 2 and s >= w - 1 and cover[0] < s_ring:
+            assert said == same, (s, w, d)
+            exact_cases[w] += 1
+    # the exact case occurs for every window, so the bound cannot widen
+    assert all(exact_cases.values()), exact_cases
+
+
+def _host_merge(slices, spec):
+    """Host ``DDSketch`` merge (Algorithm 4) of per-slice host sketches."""
+    out = DDSketch(spec.relative_accuracy, max_bins=None, mapping=spec.mapping)
+    for vals in slices:
+        sk = DDSketch(spec.relative_accuracy, max_bins=None, mapping=spec.mapping)
+        sk.extend(float(v) for v in vals)
+        out.merge(sk)
+    return out
+
+
+def test_snapshot_read_after_seals_answers_as_host_merge():
+    """A snapshot taken at seal count ``s`` answers a ``w``-slice window
+    after up to ``S - w + 1`` further seals as the host ``DDSketch`` merge
+    of the slices it covers plus its live slice, read from the live slab;
+    one seal more and it refuses (``StaleCover``)."""
+    spec, s_ring = BucketSpec(), 4
+    keys = ("a", "b")
+    rng = np.random.default_rng(5)
+    for w in range(1, s_ring + 1):
+        win = KeyedWindow(spec, capacity=4, num_slices=s_ring)
+        sealed: list[dict] = []
+
+        def fill():
+            vals = {k: (rng.pareto(1.5, 40) + 1.0).astype(np.float32) for k in keys}
+            for k in keys:
+                win.record(k, vals[k])
+            return vals
+
+        for _ in range(s_ring + 2):  # wrapped past S
+            sealed.append(fill())
+            win.advance_slice()
+        live = fill()
+        snap = win.snapshot()
+        s = snap.sealed
+        covered = sealed[s - (w - 1):s] if w > 1 else []
+        for d in range(s_ring - w + 3):
+            if d <= s_ring - w + 1:
+                got = snap.windowed_all_quantiles(QS, slices=w)
+                for k in keys:
+                    want = _host_merge([c[k] for c in covered] + [live[k]], spec)
+                    assert got[k] == pytest.approx(want.quantiles(QS), rel=1e-5), (w, d, k)
+                fleet = _host_merge([c[k] for c in covered + [live] for k in keys], spec)
+                assert snap.windowed_rollup(QS, slices=w) == pytest.approx(
+                    fleet.quantiles(QS), rel=1e-5), (w, d)
+            else:
+                with pytest.raises(StaleCover):
+                    snap.windowed_row_quantiles(QS, slices=w)
+            fill()
+            win.advance_slice()
+
+
+def test_read_enqueued_before_donated_seal_returns_pre_seal_answer():
+    """A window read enqueued on the slab, then a seal that donates the
+    slab and rewrites the very node the read names: the read still answers
+    from the slab as it was (the runtime orders the donation after it)."""
+    spec, k, s_ring = BucketSpec(), 3, 4
+    eng = SketchEngine(spec, k)
+    ring = WindowRing(eng, s_ring)
+    banks = [_slice_bank(spec, k, seed=600 + t) for t in range(s_ring + 1)]
+    for b in banks[:s_ring]:
+        ring.seal(b)
+    live = _slice_bank(spec, k, seed=699)
+    nodes = np.zeros(ring.max_range_nodes, np.int32)  # node 0: leaf of slice 0
+    valid = np.zeros(ring.max_range_nodes, np.float32)
+    valid[0] = 1.0
+    old = ring.slab
+    pending = eng.window_query(ring.slab, live, nodes, valid, True, QS)
+    ring.seal(banks[s_ring])  # slice 4 -> leaf 0, slab donated
+    assert old.pos.is_deleted()  # the seal really consumed the read's slab
+    want = np.asarray(sb.quantiles(_merge_all([banks[0], live], spec),
+                                   jnp.asarray(QS, jnp.float32), spec=spec))
+    np.testing.assert_array_equal(np.asarray(pending), want)
+    after = np.asarray(eng.window_query(ring.slab, live, nodes, valid, True, QS))
+    assert not np.array_equal(after, want, equal_nan=True)
