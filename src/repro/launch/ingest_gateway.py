@@ -31,7 +31,8 @@ the adapter, hardened for the day traffic exceeds what the engine absorbs:
 * **observability** — ``stats()`` snapshots the counters (accepted /
   ingested / shed / rejected batches and values / expired / depth /
   ticks; ``batches_taken`` and ``queue_wait_s``, the batches the drain
-  ticks took off the queue and the seconds they had waited there) with a
+  ticks took off the queue and the seconds they had waited there;
+  ``drain_wait_s``, the seconds the drain loop waited between ticks) with a
   ``spans`` block: the totals of the write path's spans (``http.ingest*``,
   ``gateway.*``, ``window.*``, ``engine.*``; ``repro.tracing``).  Each
   admitted batch gets a sequence number (``seq`` in the receipt and on the
@@ -102,7 +103,11 @@ class IngestGateway:
     ``window`` is any sink with ``record_batches``/``total_mass``
     (``telemetry.KeyedWindow``).  ``max_queue_values`` bounds queued value
     lanes (the memory bound under overload); ``tick_interval_s`` is the
-    drain cadence; ``shed_policy`` is ``"reject"`` or ``"sample"`` (stride
+    drain cadence, the least period between the starts of two ticks (a
+    tick that outlasts it is followed at once by the next, while the
+    queue holds work; a quick one waits out the rest of its interval, so
+    light traffic still coalesces); ``shed_policy`` is ``"reject"`` or
+    ``"sample"`` (stride
     ``sample_stride`` at admission once the queue is past
     ``sample_watermark`` of the bound); ``deadline_s`` is the default
     ingest deadline.  ``start=False`` leaves the drain thread off — tests
@@ -173,6 +178,7 @@ class IngestGateway:
             "ticks": 0,
             "batches_taken": 0,
             "queue_wait_s": 0.0,  # sum over taken batches of take - enqueue
+            "drain_wait_s": 0.0,  # drain loop's waits between ticks
             "slice_advances": 0,
             "engine_calls": 0,
             "drain_errors": 0,
@@ -311,7 +317,9 @@ class IngestGateway:
                 if self._stopped and not self._q:
                     return
                 if not self._q:
+                    t0 = time.monotonic()
                     self._wake.wait(timeout=self.tick_interval_s)
+                    self._stats["drain_wait_s"] += time.monotonic() - t0
                     if self._stopped and not self._q:
                         return
             if self.faults is not None:
@@ -320,12 +328,20 @@ class IngestGateway:
                     with self._lock:
                         self._stats["stalls"] += 1
                     time.sleep(stall)
+            t_tick = time.monotonic()
             self._drain_once()
             # slice clock rides the drain tick: drained values land in the
             # live bank *before* it can be sealed into the ring, so a slice
             # never misses ingest that was admitted inside its interval
             self._maybe_advance_slice()
-            time.sleep(self.tick_interval_s)
+            # tick_interval_s is the least period between tick starts: a
+            # tick that overran it is followed at once while work is queued
+            t0 = time.monotonic()
+            rest = t_tick + self.tick_interval_s - t0
+            if rest > 0:
+                time.sleep(rest)
+                with self._lock:
+                    self._stats["drain_wait_s"] += time.monotonic() - t0
 
     def _drain_once(self) -> int:
         """One tick: grab everything queued, drop expired, ingest the rest

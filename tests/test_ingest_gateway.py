@@ -191,6 +191,79 @@ def test_background_drain_thread(rng):
         gw.submit("/a", [1.0])
 
 
+class _TimedWindow:
+    """A window whose ``record_batches`` takes ``tick_s`` and notes the
+    gateway's counters as each tick begins its ingest; ``refill`` queues
+    one more batch from inside the tick, so the queue never runs dry."""
+
+    def __init__(self, tick_s=0.0, refill=0):
+        self.tick_s, self.refill = tick_s, refill
+        self.gw = None
+        self.seen = []  # gateway stats at each record_batches call
+
+    def record_batches(self, batches):
+        self.seen.append(self.gw.stats())
+        if self.refill:
+            self.refill -= 1
+            self.gw.submit("/a", np.ones(4))
+        if self.tick_s:
+            time.sleep(self.tick_s)
+        return sum(v.size for _, v, _ in batches)
+
+
+def test_overrunning_ticks_follow_each_other_without_a_wait():
+    """A tick three intervals long, with work always queued: the next tick
+    begins at once, and the loop adds no wait between them."""
+    interval, n = 0.01, 8
+    window = _TimedWindow(tick_s=3 * interval, refill=n - 1)
+    gw = IngestGateway(window, tick_interval_s=interval)
+    window.gw = gw
+    gw.submit("/a", np.ones(4))
+    deadline = time.monotonic() + 10.0
+    while len(window.seen) < n:
+        assert time.monotonic() < deadline, "drain thread stopped ticking"
+        time.sleep(0.005)
+    gw.stop()
+    first, last = window.seen[0], window.seen[n - 1]
+    assert last["ticks"] - first["ticks"] == n - 1
+    assert last["drain_wait_s"] - first["drain_wait_s"] < interval
+
+
+def test_quick_ticks_are_still_spaced_by_the_interval():
+    """An instant tick under a trickle of batches waits out the rest of
+    its interval: no more ticks than intervals elapsed, plus one."""
+    interval = 0.02
+    window = _TimedWindow()
+    t_start = time.monotonic()
+    gw = IngestGateway(window, tick_interval_s=interval)
+    window.gw = gw
+    while time.monotonic() - t_start < 0.3:
+        gw.submit("/a", np.ones(4))
+        time.sleep(0.002)
+    st = gw.stats()
+    elapsed = time.monotonic() - t_start
+    gw.stop()
+    assert 1 <= st["ticks"] <= elapsed / interval + 1
+    assert st["drain_wait_s"] > 0
+
+
+def test_drain_wait_counters_in_stats_and_http():
+    window = _TimedWindow()
+    gw = IngestGateway(window, tick_interval_s=0.002)
+    window.gw = gw
+    gw.submit("/a", np.ones(4))
+    deadline = time.monotonic() + 10.0
+    while gw.stats()["ingested_values"] < 4:
+        assert time.monotonic() < deadline, "drain thread never ingested"
+        time.sleep(0.005)
+    st = gw.stats()
+    assert isinstance(st["drain_wait_s"], float) and st["drain_wait_s"] > 0
+    with QuantileHTTPServer(TelemetryFacade(make_window(), None), gateway=gw) as server:
+        got = _get(f"{server.url}/stats")["gateway"]
+        assert got["drain_wait_s"] >= st["drain_wait_s"]
+    gw.stop()
+
+
 def test_gateway_validation():
     gw = IngestGateway(make_window(), start=False)
     with pytest.raises(ValueError):
