@@ -28,15 +28,7 @@ import jax.numpy as jnp
 
 from repro.core import jax_sketch as js
 from repro.core import sketch_bank as sb
-from repro.kernels import ops
 from repro.kernels.ref import BucketSpec
-from repro.launch.roofline import attained_bandwidth, ingest_bytes_model, peaks
-
-# device programs one full bank ingest launches per pipeline: the fused
-# path is ONE dispatch (bucketize + bin + aux stats); sort pays key pass +
-# reducing scatter + the separate stats pass; matmul pays one histogram pass
-# over the stacked sign stores + the stats pass
-DISPATCHES_PER_INGEST = {"fused": 1, "sort": 3, "matmul": 2}
 
 
 def _time(fn, *args, iters=10) -> float:
@@ -68,7 +60,6 @@ def bench_bank_insert(
             lambda v, s, k=k: sb.add(sb.empty(spec, k), v, s, spec=spec)
         )
         bank_secs = _time(bank_fn, values, ids, iters=iters)
-        picked = sb.picked_insert_method(n, k, spec.num_buckets)
 
         # naive path: one jax_sketch.add per sketch over its own slice
         k_loop = min(k, loop_cap)
@@ -94,138 +85,9 @@ def bench_bank_insert(
                 "loop_ms": round(loop_est * 1e3, 3),
                 "loop_measured_K": k_loop,
                 "speedup": round(loop_est / bank_secs, 1),
-                "picked_method": picked,
                 "impl": "xla_ref",
             }
         )
-    return rows
-
-
-def bench_insert_methods(
-    configs=((1_000_000, 128, 4096), (200_000, 64, 2048)), iters: int = 3
-) -> list[dict]:
-    """Three-way histogram pipelines — matmul vs sort–scatter vs fused —
-    over (N, K, m).
-
-    The tentpole claim: the matmul formulation pays for every (row, bucket)
-    output tile per value — O(K·m·N) — while the ingest pipeline pays one
-    O(N log N) sort plus a scatter of U <= min(N, 2·K·m) compacted triples,
-    and the fused pipeline folds the key pass into the binning dispatch
-    itself (its aux-stats half, the bigger win, is timed by
-    ``bench_fused_ingest`` — this sweep isolates the histogram cost).
-    CPU wall-clock of the jit'd ref paths (``force="ref"``), which is what
-    the auto heuristic dispatches between off-TPU; the ``dup`` axis sweeps
-    the duplicate ratio — "high" concentrates the stream into a few hundred
-    live buckets per row (the post-collapse regime of UDDSketch streams),
-    "low" spreads it across the full bucket range.  ``live_buckets`` counts
-    distinct (row, bucket, sign) cells actually hit, so ``n / live_buckets``
-    is the measured duplicate ratio; ``picked_method`` records what the
-    hist-only heuristic would auto-select at this (N, K, m).
-    """
-    rows = []
-    for n, k, m in configs:
-        spec = BucketSpec(num_buckets=m, offset=-m // 2)
-        rng = np.random.default_rng(0)
-        ids = jnp.asarray(rng.integers(0, k, n).astype(np.int32))
-        picked = ops.insert_method(n, k, m)
-        for dup, decades in (("high", 1.3), ("low", 14.0)):
-            sgn = np.where(rng.random(n) < 0.3, -1.0, 1.0)
-            vals = jnp.asarray(
-                (10.0 ** rng.uniform(0.0, decades, n) * sgn).astype(np.float32)
-            )
-            pos, neg = ops.bank_histograms(
-                vals, ids, num_segments=k, spec=spec, method="matmul", force="ref"
-            )
-            live = int((np.asarray(pos) > 0).sum() + (np.asarray(neg) > 0).sum())
-            for method in ("matmul", "sort", "fused"):
-                fn = jax.jit(
-                    lambda v, s, method=method: ops.bank_histograms(
-                        v, s, num_segments=k, spec=spec, method=method, force="ref"
-                    )
-                )
-                secs = _time(fn, vals, ids, iters=iters)
-                rows.append(
-                    {
-                        "bench": "insert_methods",
-                        "n": n,
-                        "K": k,
-                        "m": m,
-                        "dup": dup,
-                        "live_buckets": live,
-                        "method": method,
-                        "picked_method": picked,
-                        "ms": round(secs * 1e3, 3),
-                        "mvals_per_s": round(n / secs / 1e6, 1),
-                        "impl": "xla_ref",
-                    }
-                )
-    return rows
-
-
-def bench_fused_ingest(
-    configs=((1_000_000, 128, 4096), (200_000, 64, 2048)), iters: int = 3
-) -> list[dict]:
-    """Full ``add_impl`` ingest — histograms AND aux stats — per pipeline.
-
-    This is the fusion tentpole's acceptance row: unlike
-    ``bench_insert_methods`` (histograms only), every timing here includes
-    the six per-row aux stats (zero/overflow/underflow/sum/min/max).  The
-    sort and matmul pipelines pay a separate stats pass over the lanes —
-    six more segment reductions — while the fused pipeline produces bank
-    deltas in ONE dispatch, so ``dispatches_per_ingest`` drops 3 -> 1 and
-    the lane traffic drops ~5x (see ``launch.roofline.ingest_bytes_model``).
-
-    Each row carries ``model_mb``, the modeled bytes moved.  On a TPU it
-    adds ``attained_gbps`` (what the measured wall-clock implies those
-    bytes moved at) and ``hbm_frac`` (that rate against the chip's HBM
-    peak from ``roofline.PEAKS``); a CPU run writes neither.  ``speedup`` is vs the sort pipeline of the same
-    (config, dup) — the committed acceptance bar is fused >= 1.3x on the
-    high-duplication N=1M / K=128 row.  ``picked_method`` is what
-    ``method=None`` auto-resolves to for the config.
-    """
-    rows = []
-    hw = peaks() if jax.default_backend() == "tpu" else None
-    for n, k, m in configs:
-        spec = BucketSpec(num_buckets=m, offset=-m // 2)
-        rng = np.random.default_rng(0)
-        ids = jnp.asarray(rng.integers(0, k, n).astype(np.int32))
-        base = sb.empty(spec, k)
-        picked = sb.picked_insert_method(n, k, m)
-        for dup, decades in (("high", 1.3), ("low", 14.0)):
-            sgn = np.where(rng.random(n) < 0.3, -1.0, 1.0)
-            vals = jnp.asarray(
-                (10.0 ** rng.uniform(0.0, decades, n) * sgn).astype(np.float32)
-            )
-            secs_by: dict[str, float] = {}
-            for method in ("matmul", "sort", "fused"):
-                fn = jax.jit(
-                    lambda b, v, s, method=method: sb.add_impl(
-                        b, v, s, spec=spec, method=method
-                    )
-                )
-                secs_by[method] = _time(fn, base, vals, ids, iters=iters)
-            for method, secs in secs_by.items():
-                model = ingest_bytes_model(method, n, k, m)
-                row = {
-                    "bench": "fused_ingest",
-                    "n": n,
-                    "K": k,
-                    "m": m,
-                    "dup": dup,
-                    "method": method,
-                    "picked_method": picked,
-                    "dispatches_per_ingest": DISPATCHES_PER_INGEST[method],
-                    "ms": round(secs * 1e3, 3),
-                    "mvals_per_s": round(n / secs / 1e6, 1),
-                    "model_mb": round(model["hbm_bytes"] / 1e6, 1),
-                    "speedup": round(secs_by["sort"] / secs, 2),
-                    "impl": "xla_ref" if hw is None else "pallas",
-                }
-                if hw is not None:
-                    bw = attained_bandwidth(model["hbm_bytes"], secs, hw=hw)
-                    row["attained_gbps"] = round(bw["attained_gbps"], 2)
-                    row["hbm_frac"] = round(bw["hbm_frac"], 4)
-                rows.append(row)
     return rows
 
 
@@ -329,7 +191,6 @@ def bench_engine_ingest(
             bank = eng.add(bank, vals_np, ids_np)
         return bank
 
-    picked = sb.picked_insert_method(n, k, spec.num_buckets)
     rows = []
     for name, fn in (("jit_per_call", jit_path), ("engine", engine_path)):
         secs = _time(fn, iters=iters) / records
@@ -340,7 +201,6 @@ def bench_engine_ingest(
                 "n_per_record": n,
                 "records": records,
                 "path": name,
-                "picked_method": picked,
                 "ms_per_record": round(secs * 1e3, 4),
                 "records_per_s": round(1.0 / secs, 1),
                 "impl": "xla_ref",

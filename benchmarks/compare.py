@@ -5,7 +5,7 @@ Usage:
       --factor 2.0
 
 Rows are matched within each bench by their identity fields (every
-non-timing field: sizes, method, dup ratio, impl tag, ...); timing fields
+non-timing field: sizes, dup ratio, impl tag, ...); timing fields
 are any key carrying a unit token (``ms`` / ``us`` / ``ns``), normalized to
 milliseconds.  A row regresses when a timing grows by more than ``factor``
 vs the committed baseline.
@@ -43,12 +43,6 @@ _MEASURED_FIELDS = {
     "speedup",
     "loop_measured_K",
     "hist_calls_per_trace",
-    # the auto heuristic's pick and the pipeline's dispatch count are
-    # outputs of the code under test (they move when the heuristic or the
-    # fusion does), so rows must keep matching across such changes while
-    # the gate still compares their timings
-    "picked_method",
-    "dispatches_per_ingest",
     # ingest_http robustness counters: outputs under test (throttles, shed
     # mass, and the conservation flag move with load behaviour, not config)
     "http_429",

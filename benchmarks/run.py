@@ -100,19 +100,6 @@ def main() -> None:
             "bank_quantiles": lambda: bank_bench.bench_bank_quantiles(
                 k=256, n=50_000, iters=3
             ),
-            # the flagship compaction row (N=1M into K=128, m=4096) stays in
-            # the smoke tier: it is the CPU-measurable evidence for the
-            # sort–scatter crossover tracked in BENCH_baseline.json
-            "insert_methods": lambda: bank_bench.bench_insert_methods(
-                configs=((1_000_000, 128, 4096), (100_000, 64, 2048)), iters=3
-            ),
-            # full-ingest fusion acceptance row (histograms + aux stats in
-            # one dispatch): the flagship N=1M / K=128 config stays in the
-            # smoke tier so CI tracks the fused-vs-sort speedup and the
-            # modeled bytes-moved roofline position per PR
-            "fused_ingest": lambda: bank_bench.bench_fused_ingest(
-                configs=((1_000_000, 128, 4096), (100_000, 64, 2048)), iters=3
-            ),
             "fold_pairs": lambda: bank_bench.bench_fold_pairs(
                 ks=(1, 64, 256), iters=3
             ),
@@ -179,12 +166,6 @@ def main() -> None:
             "bank_quantiles": lambda: bank_bench.bench_bank_quantiles(
                 k=1024, n=200_000, iters=5
             ),
-            "insert_methods": lambda: bank_bench.bench_insert_methods(
-                configs=((1_000_000, 128, 4096), (200_000, 64, 2048)), iters=5
-            ),
-            "fused_ingest": lambda: bank_bench.bench_fused_ingest(
-                configs=((1_000_000, 128, 4096), (200_000, 64, 2048)), iters=5
-            ),
             "fold_pairs": lambda: bank_bench.bench_fold_pairs(iters=5),
             "collapse_insert": lambda: bank_bench.bench_collapse_insert(
                 n=100_000, iters=5
@@ -229,24 +210,6 @@ def main() -> None:
             "kernel_quantile": kernels_bench.bench_quantile_query,
             "bank_insert": bank_bench.bench_bank_insert,
             "bank_quantiles": bank_bench.bench_bank_quantiles,
-            "insert_methods": lambda: bank_bench.bench_insert_methods(
-                configs=(
-                    (1_000_000, 128, 4096),
-                    (1_000_000, 512, 2048),
-                    (500_000, 64, 2048),
-                    (100_000, 8, 2048),
-                ),
-                iters=5,
-            ),
-            "fused_ingest": lambda: bank_bench.bench_fused_ingest(
-                configs=(
-                    (1_000_000, 128, 4096),
-                    (1_000_000, 512, 2048),
-                    (500_000, 64, 2048),
-                    (100_000, 8, 2048),
-                ),
-                iters=5,
-            ),
             "fold_pairs": bank_bench.bench_fold_pairs,
             "collapse_insert": bank_bench.bench_collapse_insert,
             "engine_ingest": lambda: bank_bench.bench_engine_ingest(

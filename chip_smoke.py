@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Bring-up smoke of the sketch service on a TPU, through its entry points.
 
-    python chip_smoke.py                # phases "fused" and "tall", one chip
+    python chip_smoke.py                # phases "small" and "tall", one chip
     python chip_smoke.py --four-chips   # the row-sharded bank over 4 chips,
                                         # vs the same stream on one chip
 
@@ -67,8 +67,8 @@ TRIGGER = 1e-10  # below the level-0 range: folds a wide key on first sight
 # within ~1e-6 (relative) of a bucket edge may land in the neighbouring
 # bucket, which can add that much to the alpha_L bound
 KEY_ROUNDING = 1e-5
-# ingest kernel each phase must run ("fused" resident rows vs "tall")
-INGEST_KERNEL = {"fused": "ddsketch_ingest", "tall": "segment_histogram"}
+# the ingest kernel every phase must run (per shard in the sharded phase)
+INGEST_KERNEL = "segment_histogram"
 # engine executables of the main path; each must hold a Mosaic kernel
 # (snapshot / reset / slab_seal only move data)
 KERNEL_PATHS = ("ingest", "quantiles", "rollup", "window_query",
@@ -441,8 +441,8 @@ def _device_checks(result: dict, name: str) -> None:
             _check(bool(ks), f"{name}: engine executable {path!r} has no "
                    "tpu_custom_call (no compiled Pallas kernel)")
     used = {k for ks in kernels.values() for k in ks}
-    want = INGEST_KERNEL.get(name, "ddsketch_ingest")  # sharded: fused per shard
-    _check(want in used, f"{name}: {want} kernel not compiled ({sorted(used)})")
+    _check(INGEST_KERNEL in used,
+           f"{name}: {INGEST_KERNEL} kernel not compiled ({sorted(used)})")
 
 
 def _peak_bytes() -> int | None:
@@ -455,16 +455,15 @@ def _peak_bytes() -> int | None:
 # --------------------------------------------------------------------- #
 # phases as the chip runs them (sizes overridable for off-chip rehearsal)
 # --------------------------------------------------------------------- #
-def phase_fused(seed: int, **sizes) -> dict:
-    """K=512 (2K at the resident-row ceiling -> ddsketch_ingest), 64-slice
-    ring (a (127, 512, 2048) x 2 slab, ~1.06 GB, plus its snapshot)."""
+def phase_small(seed: int, **sizes) -> dict:
+    """K=512 (1024 stacked rows -> segment_histogram), 64-slice ring (a
+    (127, 512, 2048) x 2 slab, ~1.06 GB, plus its snapshot)."""
     cfg = dict(capacity=511, num_slices=64, ticks=6, per_tick=65536) | sizes
-    return run_phase("fused", seed=seed, **cfg)
+    return run_phase("small", seed=seed, **cfg)
 
 
 def phase_tall(seed: int, **sizes) -> dict:
-    """K=4096: past the resident-row ceiling, the deployments' path
-    (ddsketch_seg_hist ingest)."""
+    """K=4096, the deployments' bank (8192 stacked rows -> segment_histogram)."""
     cfg = dict(capacity=4095, ticks=4, per_tick=16384) | sizes
     return run_phase("tall", seed=seed + 100, **cfg)
 
@@ -479,9 +478,9 @@ def _same(a, b) -> bool:
 
 def phase_sharded(seed: int, *, num_shards: int = 4, **sizes) -> dict:
     """Row-sharded bank over ``num_shards`` chips (``keys`` mesh, 512 rows
-    and a fused ingest per shard, psum rollup), compared with the same
-    stream in a one-chip ``SketchEngine(K=capacity+1)``: per-key and rollup
-    quantiles must be equal."""
+    and a segment_histogram ingest per shard, psum rollup), compared with
+    the same stream in a one-chip ``SketchEngine(K=capacity+1)``: per-key
+    and rollup quantiles must be equal."""
     import jax
 
     from repro.core.jax_sketch import BucketSpec
@@ -559,7 +558,7 @@ def main(argv=None) -> int:
     clock = CompileClock()
     phases = (
         [("sharded", phase_sharded)] if args.four_chips
-        else [("fused", phase_fused), ("tall", phase_tall)]
+        else [("small", phase_small), ("tall", phase_tall)]
     )
     failed = []
     for name, phase in phases:

@@ -157,22 +157,6 @@ def effective_alpha(spec: BucketSpec, level: int) -> float:
     return (g - 1.0) / (g + 1.0)
 
 
-def _bank_histograms(values, weights, levels, spec, method):
-    """Both sign stores via the ops front door (matmul vs sort–scatter)."""
-    from repro.kernels import ops
-
-    pos, neg = ops.bank_histograms(
-        values,
-        None,
-        weights,
-        levels,
-        num_segments=1,
-        spec=spec,
-        method=method,
-    )
-    return pos[0], neg[0]
-
-
 def _raw_keys(x: jnp.ndarray, valid: jnp.ndarray, spec: BucketSpec) -> jnp.ndarray:
     """Level-0 integer keys of |x| for valid pos/neg lanes (1 elsewhere)."""
     mag = jnp.where(valid, jnp.abs(x), 1.0)
@@ -196,7 +180,7 @@ def _needed_levels(k0: jnp.ndarray, spec: BucketSpec) -> jnp.ndarray:
     return jnp.where(fits.any(axis=1), first, 0)
 
 
-@partial(jax.jit, static_argnames=("spec", "auto_collapse", "method"))
+@partial(jax.jit, static_argnames=("spec", "auto_collapse"))
 def add(
     sketch: DeviceSketch,
     values: jnp.ndarray,
@@ -204,7 +188,6 @@ def add(
     *,
     spec: BucketSpec,
     auto_collapse: bool = False,
-    method: str | None = None,
 ) -> DeviceSketch:
     """Vectorized Algorithm 1 over a batch of values (any shape).
 
@@ -214,10 +197,8 @@ def add(
     value is indexable (capped at ``MAX_COLLAPSE_LEVEL``), so nothing is
     clamped and the level-adjusted alpha guarantee holds for the whole
     stream; without it, out-of-range keys clamp into the edge buckets and
-    are tallied in ``overflow`` / ``underflow``.  ``method`` pins the insert
-    pipeline (``"matmul"`` / ``"sort"``; None auto-selects from the batch
-    and geometry sizes — see ``kernels.ops.bank_histograms``); both produce
-    identical bucket counts.
+    are tallied in ``overflow`` / ``underflow``.  Both sign stores come
+    from one ``kernels.ops.bank_histograms`` call (a bank of one sketch).
     """
     x = values.reshape(-1).astype(jnp.float32)
     raw_w = None if weights is None else weights.reshape(-1).astype(jnp.float32)
@@ -237,7 +218,11 @@ def add(
     lev = sketch.level
     shifts = jnp.broadcast_to(lev, x.shape)
 
-    pos_hist, neg_hist = _bank_histograms(x, raw_w, shifts, spec, method)
+    from repro.kernels import ops
+
+    pos_hist, neg_hist = ops.bank_histograms(
+        x, None, raw_w, shifts, num_segments=1, spec=spec
+    )
 
     # clamp accounting: shifted keys that escape [offset, offset + m - 1]
     top_key = spec.offset + spec.num_buckets - 1
@@ -253,8 +238,8 @@ def add(
 
     cd = sketch.pos.dtype
     return DeviceSketch(
-        pos=sketch.pos + pos_hist.astype(cd),
-        neg=sketch.neg + neg_hist.astype(cd),
+        pos=sketch.pos + pos_hist[0].astype(cd),
+        neg=sketch.neg + neg_hist[0].astype(cd),
         zero=sketch.zero + (w * is_zero).sum().astype(cd),
         overflow=sketch.overflow + (w * over).sum().astype(cd),
         underflow=sketch.underflow + (w * under).sum().astype(cd),

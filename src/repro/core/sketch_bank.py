@@ -5,11 +5,10 @@ endpoint, per customer, per host).  Because DDSketch bucket boundaries are
 data-independent, a bank of K fixed-geometry sketches is just a dense
 ``(K, m)`` array, and inserting a stream of ``(value, sketch_id)`` pairs is a
 *segmented* histogram — one kernel/ref dispatch regardless of K, instead of
-K launches of ``jax_sketch.add``.  Large batches take the sort–reduce–
-scatter ingest pipeline instead (compact duplicate ``(row, bucket)`` keys
-on device, then scatter U <= min(N, 2·K·m) unique triples), so insert cost
-stops growing multiplicatively with the bank size; ``add(..., method=...)``
-pins a pipeline.  Everything else the single sketch enjoys lifts row-wise:
+K launches of ``jax_sketch.add``.  On TPU that dispatch is the row-sparse
+``segment_histogram`` kernel, which visits only the (row tile, value tile)
+pairs that hold a lane.  Everything else the single sketch enjoys lifts
+row-wise:
 
 * ``merge`` / ``allreduce`` stay per-bucket '+' (Algorithm 4) after the
   rows align their collapse levels, now over ``(K, m)`` — the bank is
@@ -52,7 +51,6 @@ __all__ = [
     "empty",
     "add",
     "add_impl",
-    "picked_insert_method",
     "quantiles_impl",
     "merge",
     "allreduce",
@@ -134,36 +132,6 @@ def _dense_stats_applies(n: int, k: int) -> bool:
     return 0 < k <= _DENSE_STATS_MAX_ROWS and k * n <= _DENSE_STATS_MAX_ELEMENTS
 
 
-def picked_insert_method(
-    n: int,
-    k: int,
-    num_buckets: int,
-    *,
-    unit_weights: bool = True,
-    on_tpu: bool | None = None,
-) -> str:
-    """The pipeline ``add_impl(..., method=None)`` resolves to.
-
-    ``kernels.ops.insert_method`` plus this module's one adjustment: on the
-    ref tier (``on_tpu`` false; None asks the default backend) a small bank
-    (the dense (K, N) stats regime) keeps the two-pass sort path, since the
-    dense masked reductions beat the fused segment stats there.  Benches
-    record this so every timing row names the pipeline the auto heuristic
-    actually ran.
-    """
-    from repro.kernels import ops
-
-    if on_tpu is None:
-        on_tpu = ops._on_tpu()
-    method = ops.insert_method(
-        n, k, num_buckets, unit_weights=unit_weights, on_tpu=on_tpu,
-        full_ingest=True,
-    )
-    if method == "fused" and _dense_stats_applies(n, k) and not on_tpu:
-        method = "sort"
-    return method
-
-
 def add_impl(
     bank: SketchBank,
     values: jnp.ndarray,
@@ -172,7 +140,6 @@ def add_impl(
     *,
     spec: BucketSpec,
     auto_collapse: bool = False,
-    method: str | None = None,
 ) -> SketchBank:
     """Vectorized Algorithm 1 over ``(value, sketch_id)`` pairs (any shape).
 
@@ -187,20 +154,13 @@ def add_impl(
     ``auto_collapse=True`` every touched row first collapses to the smallest
     level at which all of its batch values are indexable, so nothing clamps.
 
-    ``method`` pins the insert pipeline: ``"matmul"`` runs the segmented
-    one-hot histogram once over both sign stores, ``"sort"`` compacts a combined composite-key
-    stream (sort–reduce) and scatters U <= min(N, 2·K·m) unique triples —
-    the input-stationary path whose cost stops growing with the bank size —
-    and ``"fused"`` produces the histograms *and* the six aux stats in one
-    dispatch (``kernels.ops.fused_ingest``), skipping this function's
-    second pass over the lanes entirely.  ``None`` auto-selects from
-    (N, K, m) with the fused path on the menu (``picked_insert_method``);
-    all pipelines produce identical counts — bit-for-bit except fractional
-    float weights on the Pallas sort path (duplicate-key accumulation order
-    differs) and the float ``summ``, whose lane-accumulation order varies
-    across stats formulations (dense small-K masked matmul vs segment sum
-    vs the fused kernel's tile-order pass) at ulp level; see
-    ``kernels.ops.bank_histograms`` / ``fused_ingest``.
+    Two passes over the lanes: ``kernels.ops.bank_histograms`` fills both
+    sign stores in one ``segment_histogram`` launch, and this function
+    folds the six per-row aux stats (zero / overflow / underflow / summ /
+    vmin / vmax) in its own pass — dense masked reductions over a
+    ``(K, N)`` one-hot for small banks, ``jax.ops.segment_*`` otherwise.
+    Counters and extrema are exact in both forms; the float ``summ`` may
+    differ between them at ulp level (the dense form reassociates).
     """
     k = bank.num_sketches
     x = values.reshape(-1).astype(jnp.float32)
@@ -237,35 +197,6 @@ def add_impl(
 
     from repro.kernels import ops
 
-    if method is None:
-        method = picked_insert_method(
-            x.size, k, spec.num_buckets,
-            unit_weights=raw_w is None,
-        )
-
-    if method == "fused":
-        # one dispatch: histograms + aux stats; no second pass below
-        pos_hist, neg_hist, st = ops.fused_ingest(
-            x,
-            s,
-            raw_w,
-            shifts,
-            num_segments=k,
-            spec=spec,
-        )
-        cd = bank.pos.dtype
-        return SketchBank(
-            pos=bank.pos + pos_hist.astype(cd),
-            neg=bank.neg + neg_hist.astype(cd),
-            zero=bank.zero + st.zero.astype(cd),
-            overflow=bank.overflow + st.overflow.astype(cd),
-            underflow=bank.underflow + st.underflow.astype(cd),
-            summ=bank.summ + st.summ,
-            vmin=jnp.minimum(bank.vmin, st.vmin),
-            vmax=jnp.maximum(bank.vmax, st.vmax),
-            level=bank.level,
-        )
-
     pos_hist, neg_hist = ops.bank_histograms(
         x,
         s,
@@ -273,7 +204,6 @@ def add_impl(
         shifts,
         num_segments=k,
         spec=spec,
-        method=method,
     )
 
     # clamp accounting: shifted keys that escape [offset, offset + m - 1]
@@ -320,9 +250,7 @@ def add_impl(
     )
 
 
-add = partial(
-    jax.jit, static_argnames=("spec", "auto_collapse", "method")
-)(add_impl)
+add = partial(jax.jit, static_argnames=("spec", "auto_collapse"))(add_impl)
 
 
 # --------------------------------------------------------------------- #

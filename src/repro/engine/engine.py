@@ -78,7 +78,6 @@ def shared_engine(
     num_sketches: int,
     *,
     counts_dtype=jnp.float32,
-    method: str | None = None,
 ) -> "SketchEngine":
     """Process-wide engine registry, one per bank geometry.
 
@@ -88,12 +87,7 @@ def shared_engine(
     executables instead of re-lowering per caller.  Pair with
     ``tables.padded_row_count`` to round row counts onto the shared grid.
     """
-    return SketchEngine(
-        spec,
-        num_sketches,
-        counts_dtype=counts_dtype,
-        method=method,
-    )
+    return SketchEngine(spec, num_sketches, counts_dtype=counts_dtype)
 
 
 def _zero_where(mask: jnp.ndarray, arr: jnp.ndarray) -> jnp.ndarray:
@@ -210,16 +204,15 @@ def window_merge_bank(
 
 
 class SketchEngine:
-    """Compiled call paths for one bank geometry (spec, K, dtype, method).
+    """Compiled call paths for one bank geometry (spec, K, dtype).
 
     Stateless with respect to the bank: banks are passed in and returned
     (donated) like any jax state, so one engine can drive many banks of the
     same geometry.  ``new_bank()`` mints a fresh one.
 
-    ``method`` pins the insert pipeline exactly as ``sketch_bank.add``
-    does (the backend is ``kernels.ops``' automatic choice: the compiled
-    Pallas kernels on TPU, the XLA reference elsewhere); ``collapse_threshold`` semantics
-    live at the call site (``ingest(..., threshold=)``), not here, so one
+    The backend is ``kernels.ops``' automatic choice: the compiled Pallas
+    kernels on TPU, the XLA reference elsewhere.  ``collapse_threshold``
+    semantics live at the call site (``ingest(..., threshold=)``), not here, so one
     executable serves every threshold value.
     """
 
@@ -229,12 +222,10 @@ class SketchEngine:
         num_sketches: int,
         *,
         counts_dtype=jnp.float32,
-        method: str | None = None,
     ):
         self.spec = spec
         self.num_sketches = int(num_sketches)
         self.counts_dtype = jax_sketch._counts_dtype(counts_dtype)
-        self.method = method
         self._cache: dict[tuple, Any] = {}
         self._hits = 0
         self._misses = 0
@@ -477,7 +468,6 @@ class SketchEngine:
                 ww,
                 spec=self.spec,
                 auto_collapse=auto_collapse,
-                method=self.method,
             )
             if not reactive:
                 return b

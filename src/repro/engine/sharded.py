@@ -480,14 +480,12 @@ class ShardedBank:
         *,
         num_shards: int | None = None,
         counts_dtype=jnp.float32,
-        method: str | None = None,
     ):
         self.engine = ShardedEngine(
             spec,
             num_sketches,
             num_shards=num_shards,
             counts_dtype=counts_dtype,
-            method=method,
         )
         self.state = self.engine.new_bank()
 

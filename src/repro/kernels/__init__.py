@@ -3,33 +3,24 @@
 Hot spots the paper optimizes (Algorithm 1's insert loop) plus the
 UDDSketch uniform-collapse fold, TPU-native:
 
-* ``ddsketch_hist``     — single-sketch histogram insert,
-* ``ddsketch_seg_hist`` — segmented insert for a bank of K sketches,
-* ``ddsketch_scatter``  — input-stationary scatter over compacted triples
-  (the back end of the sort–reduce–scatter ingest pipeline),
-* ``ddsketch_ingest``   — fused single-dispatch full ingest: bucketize +
-  bin + the six per-row aux stats in one program,
+* ``ddsketch_seg_hist`` — the one insert kernel: a row-sparse segmented
+  histogram over a bank's stacked sign stores (a single sketch is a bank
+  of one),
 * ``bank_quantiles``    — fused cumsum + searchsorted bank query,
 * ``bank_range_merge``  — fused slice-range merge for windowed quantiles
   (fold each slice row to the range's max collapse level, reduce slices),
 * ``fold_pairs``        — uniform-collapse resolution fold (gamma -> gamma^2),
 * ``ref``               — pure-jnp semantic oracles / XLA fallback,
-* ``ops``               — backend dispatch (``force=`` pins a path,
-  ``method=`` pins an insert pipeline).
+* ``ops``               — backend dispatch (``force=`` pins a path).
 """
 
 from repro.kernels.ops import (  # noqa: F401
     BucketSpec,
-    IngestStats,
     bank_histograms,
     bank_quantiles,
     bank_range_merge,
-    ddsketch_histogram,
-    ddsketch_scatter,
     dispatch_stats,
     fold_pairs,
-    fused_ingest,
-    insert_method,
     reset_dispatch_stats,
     segment_histogram,
 )
@@ -37,9 +28,7 @@ from repro.kernels.ref import (  # noqa: F401
     MAX_COLLAPSE_LEVEL,
     bank_quantiles_ref,
     bank_range_merge_ref,
-    compact_triples,
     fold_pairs_ref,
     histogram_ref,
-    scatter_histogram_ref,
     segment_histogram_ref,
 )

@@ -28,10 +28,7 @@ __all__ = [
     "approx_log2",
     "level_keys",
     "shift_key",
-    "composite_keys",
-    "compact_triples",
-    "scatter_histogram_ref",
-    "fused_ingest_ref",
+    "bank_add_ref",
     "bank_quantiles_ref",
     "bank_range_merge_ref",
     "multi_fold_destinations",
@@ -271,143 +268,7 @@ def segment_histogram_ref(
 
 
 # --------------------------------------------------------------------- #
-# sort–reduce front end of the input-stationary ingest pipeline
-# --------------------------------------------------------------------- #
-def composite_keys(
-    values: jnp.ndarray,
-    segment_ids: jnp.ndarray | None,
-    levels: jnp.ndarray | None,
-    *,
-    num_segments: int,
-    spec: BucketSpec,
-) -> jnp.ndarray:
-    """Flat ``sign_base + seg * m + bucket`` keys covering both sign stores.
-
-    Positive values key into rows ``[0, K)`` of the combined ``(2K, m)``
-    layout, negatives (keyed on ``|x|``) into rows ``[K, 2K)``, so one sort
-    and one scatter cover both stores.  Lanes that contribute nothing in
-    ``segment_histogram_ref`` (non-finite, ``|x| <= min_indexable``,
-    out-of-range segment id) get the sentinel key ``2*K*m``, which every
-    consumer drops.  The bucket index reuses the exact ``bucket_index``
-    float32 math, so the pipeline agrees with the matmul-histogram path
-    bit-for-bit.
-    """
-    m = spec.num_buckets
-    sentinel = 2 * num_segments * m
-    if sentinel + 1 > jnp.iinfo(jnp.int32).max:
-        raise ValueError(
-            f"2 * num_segments * num_buckets + 1 = {sentinel + 1} overflows "
-            "int32 composite keys; shard the bank or shrink the geometry"
-        )
-    x = values.reshape(-1).astype(jnp.float32)
-    if segment_ids is None:
-        s = jnp.zeros(x.shape, jnp.int32)
-    else:
-        s = segment_ids.reshape(-1).astype(jnp.int32)
-    lev = None if levels is None else levels.reshape(-1).astype(jnp.int32)
-    finite = jnp.isfinite(x)
-    is_pos = finite & (x > spec.min_indexable)
-    is_neg = finite & (x < -spec.min_indexable)
-    valid = (is_pos | is_neg) & (s >= 0) & (s < num_segments)
-    idx = bucket_index(jnp.where(valid, jnp.abs(x), 1.0), spec, lev)
-    key = (
-        jnp.clip(s, 0, num_segments - 1) * m
-        + idx
-        + jnp.where(is_neg, num_segments * m, 0)
-    )
-    return jnp.where(valid, key, sentinel)
-
-
-@partial(jax.jit, static_argnames=("num_segments", "spec", "payload_sort"))
-def compact_triples(
-    values: jnp.ndarray,
-    segment_ids: jnp.ndarray | None = None,
-    weights: jnp.ndarray | None = None,
-    levels: jnp.ndarray | None = None,
-    *,
-    num_segments: int,
-    spec: BucketSpec,
-    payload_sort: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Sort + reduce: N raw values -> U <= min(N, 2*K*m + 1) unique triples.
-
-    Returns ``(keys, weights)`` of length N with the runs *packed to the
-    front*: lanes ``0..U-1`` hold each distinct composite key of the
-    combined pos/neg layout (see ``composite_keys``) once, in ascending
-    order, carrying the run's total weight — invalid input lanes collapse
-    into one sentinel run whose key (``2*K*m``) every consumer drops.
-    Trailing lanes report int32-max keys with zero weight (also dropped).
-    Because the packing is front-aligned, callers may statically slice the
-    result to ``min(N, 2*K*m + 1)`` lanes — that slice is what makes the
-    scatter kernel's streamed axis the *compacted* axis.
-
-    ``weights=None`` is the fast path: only the keys are sorted (no
-    payload) and run totals count lanes — exact integer math.  Explicit
-    weights take the two-pass *weighted fast path*: the sort moves only
-    (key, lane-index) int32 pairs — never the float weights — and the
-    weights gather through the resulting permutation afterwards, so the
-    heavy sort stage stays all-integer and keys-shaped for weighted
-    streams too.  ``payload_sort=True`` pins the original formulation
-    (the (key, weight) pairs sort together) for parity testing.  Either
-    way runs reduce with an in-order ``segment_sum``; the sorts are
-    unstable, so equal-key payload order is arbitrary — exact whenever
-    the weights are integer-valued (the same 2^24 float32 ceiling the
-    dense stores have), final-ulp differences possible between the two
-    formulations for fractional weights.
-    """
-    m = spec.num_buckets
-    key = composite_keys(
-        values, segment_ids, levels, num_segments=num_segments, spec=spec
-    )
-    n = key.shape[0]
-    if n == 0:
-        return key, jnp.zeros(0, jnp.float32)
-    if weights is None:
-        sk = jax.lax.sort([key], num_keys=1, is_stable=False)[0]
-        sw = jnp.ones_like(sk, jnp.float32)
-    elif payload_sort:
-        w = weights.reshape(-1).astype(jnp.float32)
-        sk, sw = jax.lax.sort([key, w], num_keys=1, is_stable=False)
-    else:
-        w = weights.reshape(-1).astype(jnp.float32)
-        perm = jax.lax.iota(jnp.int32, n)
-        sk, sperm = jax.lax.sort([key, perm], num_keys=1, is_stable=False)
-        sw = w[sperm]
-    starts = jnp.concatenate([jnp.ones(1, bool), sk[1:] != sk[:-1]])
-    rid = jnp.cumsum(starts.astype(jnp.int32)) - 1  # run index, packed 0..U-1
-    run_w = jax.ops.segment_sum(sw, rid, num_segments=n, indices_are_sorted=True)
-    run_k = jax.ops.segment_min(sk, rid, num_segments=n, indices_are_sorted=True)
-    # empty trailing segments report int32-max keys (dropped by consumers)
-    return run_k, run_w
-
-
-@partial(jax.jit, static_argnames=("num_rows", "num_buckets"))
-def scatter_histogram_ref(
-    keys: jnp.ndarray,
-    weights: jnp.ndarray,
-    *,
-    num_rows: int,
-    num_buckets: int,
-) -> jnp.ndarray:
-    """Oracle for the scatter stage: ``out[k // m, k % m] += w`` per triple.
-
-    Keys outside ``[0, num_rows * num_buckets)`` contribute nothing (the
-    compaction sentinels land here).  With unique keys — what
-    ``compact_triples`` guarantees for the live lanes — every output bucket
-    receives at most one add, so any correct implementation matches this
-    bit-for-bit regardless of traversal order.
-    """
-    total = num_rows * num_buckets
-    k = keys.reshape(-1)
-    w = weights.reshape(-1).astype(jnp.float32)
-    valid = (k >= 0) & (k < total)
-    flat = jnp.where(valid, k, total)
-    out = jnp.zeros(total + 1, jnp.float32).at[flat].add(jnp.where(valid, w, 0.0))
-    return out[:total].reshape(num_rows, num_buckets)
-
-
-# --------------------------------------------------------------------- #
-# fused single-pass ingest: histogram + aux stats in one dispatch
+# whole bank insert: histograms + aux stats
 # --------------------------------------------------------------------- #
 class IngestStats(NamedTuple):
     """Per-row auxiliary statistics of one ingest batch, each ``(K,)``.
@@ -428,7 +289,7 @@ class IngestStats(NamedTuple):
 
 
 @partial(jax.jit, static_argnames=("num_segments", "spec"))
-def fused_ingest_ref(
+def bank_add_ref(
     values: jnp.ndarray,
     segment_ids: jnp.ndarray | None = None,
     weights: jnp.ndarray | None = None,
@@ -437,19 +298,16 @@ def fused_ingest_ref(
     num_segments: int,
     spec: BucketSpec,
 ) -> tuple[jnp.ndarray, IngestStats]:
-    """Oracle for the fused ingest: ``(hist (2K, m), IngestStats)`` in one pass.
+    """Oracle for a whole bank insert: ``(hist (2K, m), IngestStats)``.
 
-    The histogram half is bit-identical to the sort pipeline's XLA twin
-    (``composite_keys`` + ``scatter_histogram_ref``): positives land in rows
-    ``[0, K)``, negatives (keyed on ``|x|``) in rows ``[K, 2K)``.  The stats
-    half reuses the *same* elementwise key pass for the clamp accounting —
+    What ``sketch_bank.add_impl`` adds to a bank, from one independent
+    formulation: positives land in rows ``[0, K)`` of the histogram,
+    negatives (keyed on ``|x|``) in rows ``[K, 2K)``, through one reducing
+    scatter.  The same elementwise key pass feeds the clamp accounting —
     overflow / underflow are lanes whose shifted key escapes
-    ``[offset, offset + m - 1]`` — instead of a second bucketization, and
-    batches the six per-row reductions into one stacked ``segment_sum``
-    (zero / overflow / underflow / summ) plus one stacked ``segment_min``
-    (``vmin`` and ``vmax = -min(-x)``), so the whole ingest is one read of
-    the lanes where the sort path plus ``add_impl``'s stats pass reads them
-    ~5x (see ``launch.roofline.ingest_bytes_model``).
+    ``[offset, offset + m - 1]`` — and the six per-row stats come from one
+    stacked ``segment_sum`` (zero / overflow / underflow / summ) plus one
+    stacked ``segment_min`` (``vmin`` and ``vmax = -min(-x)``).
 
     Counters are exact (sums of ``w * {0, 1}``); ``summ`` accumulates in
     lane order like ``jax.ops.segment_sum``, matching ``add_impl``'s
@@ -480,8 +338,8 @@ def fused_ingest_ref(
     is_zero = valid & ~is_pos & ~is_neg
 
     # one elementwise key pass feeds the histogram AND the clamp accounting
-    # (the keys the fused kernel is fed; lanes off is_pos | is_neg carry no
-    # weight and no clamp flag, whatever their key)
+    # (lanes off is_pos | is_neg carry no weight and no clamp flag, whatever
+    # their key)
     k_lev = level_keys(x, lev, spec)
     idx = jnp.clip(k_lev - spec.offset, 0, m - 1)
     top_key = spec.offset + m - 1

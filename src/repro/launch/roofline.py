@@ -37,8 +37,6 @@ __all__ = [
     "parse_collectives",
     "roofline_terms",
     "fmt_row",
-    "ingest_bytes_model",
-    "attained_bandwidth",
 ]
 
 
@@ -200,86 +198,6 @@ def roofline_terms(
         }
     )
     return terms
-
-
-def ingest_bytes_model(
-    method: str,
-    n: int,
-    num_segments: int,
-    num_buckets: int,
-    *,
-    unit_weights: bool = True,
-    counter_bytes: int = 4,
-) -> dict:
-    """Bytes-moved model for one full bank ingest (histograms + aux stats).
-
-    First-order HBM-traffic accounting for the three ``ops.insert_method``
-    pipelines as ``sketch_bank.add_impl`` executes them on the XLA
-    reference tier (the CPU-measurable configuration tracked in
-    ``BENCH_baseline.json``; on the Pallas tiers the sort path's scatter
-    stage streams the *compacted* bound ``U <= min(N, 2Km + 1)`` instead of
-    N — strictly less traffic, same structure).  Lanes are
-    values + ids + levels (+ weights) at 4 bytes each; the bank update
-    reads and writes both ``(K, m)`` stores and the six ``(K,)`` stat rows.
-
-    * ``fused`` — ONE pass over the lanes: the single dispatch bucketizes,
-      bins and reduces the stats in-register, so lane traffic is
-      ``lane_bytes * N`` total.
-    * ``sort`` — the key pass re-reads the lanes and writes N int32 keys,
-      the reducing scatter re-reads keys + weights, and ``add_impl``'s
-      separate stats pass re-reads the lanes and streams six segment
-      reductions (4 sums + 2 extrema, each moving data + ids) — ~5x the
-      fused path's lane traffic.
-    * ``matmul`` — one histogram pass over the lanes into the stacked
-      ``(2K, m)`` layout plus the same separate stats pass.
-
-    Returns ``{"method", "hbm_bytes", "terms": {stage: bytes}}``; feed
-    ``hbm_bytes`` and a measured wall-clock to ``attained_bandwidth`` for
-    the roofline position.
-    """
-    lane = 12 + (0 if unit_weights else 4)  # values + ids + levels (+ w)
-    cells = 2 * num_segments * num_buckets * counter_bytes
-    stats = 6 * num_segments * 4
-    # the separate add_impl stats pass: re-read lanes, then 4 segment-sums
-    # + 2 segment-extrema each streaming (data + ids) = 6 * 8 bytes/lane
-    stats_pass = lane * n + 48 * n + 2 * stats
-    if method == "fused":
-        terms = {
-            "lane_pass": lane * n,
-            "hist_update": 2 * cells,
-            "stats_update": 2 * stats,
-        }
-    elif method == "sort":
-        terms = {
-            "key_pass": lane * n + 4 * n,
-            "scatter": 8 * n + 2 * cells,
-            "stats_pass": stats_pass,
-        }
-    elif method == "matmul":
-        terms = {
-            "hist_pass": lane * n + 2 * cells,
-            "stats_pass": stats_pass,
-        }
-    else:
-        raise ValueError(f"unknown ingest method {method!r}")
-    return {
-        "method": method,
-        "hbm_bytes": float(sum(terms.values())),
-        "terms": terms,
-    }
-
-
-def attained_bandwidth(model_bytes: float, seconds: float, *, hw: Peaks) -> dict:
-    """Measured bandwidth for a modeled byte count, vs the chip's HBM peak.
-
-    ``attained_gbps`` is what the measured wall-clock implies the modeled
-    bytes moved at; ``hbm_frac`` positions that against ``hw.hbm_bw``.
-    Only meaningful for a time measured on the chip ``hw`` describes.
-    """
-    if seconds <= 0:
-        return {"attained_gbps": 0.0, "hbm_frac": 0.0}
-    bps = model_bytes / seconds
-    return {"attained_gbps": bps / 1e9, "hbm_frac": bps / hw.hbm_bw}
 
 
 def collective_shape_histogram(hlo_text: str, top: int = 12) -> list[dict]:

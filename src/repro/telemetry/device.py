@@ -171,9 +171,9 @@ def record(
     entries are ignored, which also makes masked-out token losses — set to
     NaN by loss_fn — drop out naturally).  All streams concatenate into one
     ``(values, sketch_ids)`` batch and update the bank with a **single**
-    ``ops.bank_histograms`` call (segmented/scatter kernel picked by the
-    (N, K, m) heuristic), so the traced step carries one histogram no
-    matter how many streams are live.
+    ``ops.bank_histograms`` call (one ``segment_histogram`` launch), so
+    the traced step carries one histogram no matter how many streams are
+    live.
 
     Unknown stream names raise at trace time (``ValueError``) unless
     ``strict=False`` (argument or ``tcfg.strict``) asks for the legacy

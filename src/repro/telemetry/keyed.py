@@ -286,7 +286,6 @@ class KeyedWindow:
         *,
         collapse_threshold: float | None = 0.0,
         evict_after: int = 1,
-        method: str | None = None,
         counts_dtype=jnp.float32,
         num_shards: int | None = None,
         track_collapse_events: bool = True,
@@ -304,14 +303,12 @@ class KeyedWindow:
         self.lock = threading.RLock()
         self.collapse_threshold = collapse_threshold
         self.evict_after = evict_after
-        self.method = method  # insert pipeline pin ("matmul"/"sort"/None auto)
         self.counts_dtype = counts_dtype
         self.engine = make_engine(
             spec,
             capacity + 1,
             num_shards=num_shards,
             counts_dtype=counts_dtype,
-            method=method,
         )
         self.bank = self.engine.new_bank()
         self.key_to_row: dict[str, int] = {OVERFLOW_KEY: 0}

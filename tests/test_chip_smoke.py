@@ -35,9 +35,10 @@ def _assert_clean(rec, ticks):
     assert rec["max_err_over_alpha_L"] <= 1.0 + 1e-3
 
 
-def test_fused_phase_tiny_interpret(tpu_interpret):
-    res = _smoke().phase_fused(0, capacity=15, num_slices=4, ticks=3,
-                               per_tick=1024, windows=("1m", "3m", "4m"),
+def test_small_phase_tiny_interpret(tpu_interpret):
+    # per_tick: one value tile (2048 lanes), the least the kernel takes
+    res = _smoke().phase_small(0, capacity=15, num_slices=4, ticks=3,
+                               per_tick=2048, windows=("1m", "3m", "4m"),
                                sample_keys=8)
     _assert_clean(res["record"], 3)
     # every path the chip checks for a Mosaic kernel was built
@@ -45,14 +46,7 @@ def test_fused_phase_tiny_interpret(tpu_interpret):
             "slab_merge_node"} <= set(res["record"]["kernels"])
 
 
-def test_tall_phase_tiny_interpret(tpu_interpret, monkeypatch):
-    from repro.core import sketch_bank as sb
-    from repro.kernels import ops
-
-    # a 16-row bank stands in for the >512-key path: past the (lowered)
-    # resident-row ceiling, ingest takes the segment-histogram kernel
-    monkeypatch.setattr(ops, "MAX_RESIDENT_ROWS", 16)
-    assert sb.picked_insert_method(2048, 16, 2048) == "matmul"
+def test_tall_phase_tiny_interpret(tpu_interpret):
     res = _smoke().phase_tall(0, capacity=15, ticks=2, per_tick=2048,
                               sample_keys=8)
     _assert_clean(res["record"], 2)
@@ -69,7 +63,7 @@ smoke = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(smoke)
 ops._on_tpu = lambda: True  # the chip's dispatch, kernels interpreted
 pltpu.set_tpu_interpret_mode()
-res = smoke.phase_sharded(0, capacity=31, ticks=3, per_tick=2048,
+res = smoke.phase_sharded(0, capacity=31, ticks=3, per_tick=8192,
                           num_slices=4, windows=("1m", "3m", "4m"),
                           sample_keys=8)
 print(json.dumps(res["record"]))

@@ -41,6 +41,37 @@ def test_engine_add_matches_sketch_bank(rng, weights):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("k", [1, 16, 511])
+def test_engine_ingest_matches_sketch_bank_kernel_tier(k, rng, tpu_interpret):
+    """The engine's AOT ingest executable (inert pow-2 padding included) on
+    the kernel tier equals ``sb.add`` on the same tier, and so do the
+    quantiles read from it."""
+    from repro.kernels import ops
+
+    x, s, w = _stream(rng, 3000, k, weights=True)  # pads to 4096 lanes
+    eng = SketchEngine(SPEC, k)
+    got, fired, clamped = eng.ingest(eng.new_bank(), x, s, w)
+    assert fired is None and clamped is None
+    want = sb.add(sb.empty(SPEC, k), jnp.asarray(x), jnp.asarray(s),
+                  jnp.asarray(w), spec=SPEC)
+    assert ops.fallback_count() == 0
+    for name, g, wv in zip(got._fields, got, want):
+        if name == "summ":
+            # the padding lanes change the length the small-bank (dense)
+            # stats reduce over, so the float sum may reassociate: bound
+            # the drift by the row's |w x| mass
+            mass = np.bincount(s, np.abs(w * x).astype(np.float64), minlength=k)
+            err = np.abs(np.asarray(g, np.float64) - np.asarray(wv, np.float64))
+            assert (err <= 1e-6 * mass).all(), (err, mass)
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(wv),
+                                          err_msg=f"field {name!r}")
+    np.testing.assert_array_equal(
+        np.asarray(eng.quantiles(got, QS)),
+        np.asarray(sb.quantiles(want, jnp.asarray(QS, jnp.float32), spec=SPEC)),
+    )
+
+
 def test_engine_quantiles_match_sketch_bank(rng):
     k = 7
     x, s, _ = _stream(rng, 3000, k)

@@ -183,6 +183,66 @@ def test_add_is_jittable_and_donatable(rng):
 
 
 # --------------------------------------------------------------------- #
+# the kernel tier: a single sketch is a one-row bank through the row-sparse
+# segment_histogram kernel (TPU kernels in interpret mode)
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("variant", ["unit", "float_weights", "auto_collapse"])
+@pytest.mark.parametrize("mapping", ["log", "linear", "cubic"])
+def test_kernel_tier_add_matches_ref_and_host(mapping, variant, rng, tpu_interpret):
+    """``js.add`` on the kernel tier: both stores equal the reference
+    histograms bit-for-bit, and the quantiles equal a host ``DDSketch``'s
+    fed the same stream (at the device's collapse level)."""
+    from repro.kernels import ops
+    from repro.kernels.ref import histogram_ref
+
+    spec = BucketSpec(mapping=mapping)
+    n = 4096
+    if variant == "auto_collapse":  # 24 decades: folds before inserting
+        mag = 10.0 ** rng.uniform(-15.0, 9.0, n)
+    else:
+        mag = rng.pareto(1.0, n) + 1.0
+    x = (mag * np.where(rng.random(n) < 0.4, -1.0, 1.0)).astype(np.float32)
+    x[:5] = [np.nan, np.inf, -np.inf, 0.0, -0.0]
+    w = None
+    if variant == "float_weights":
+        w = rng.integers(0, 5, n).astype(np.float32)
+    sk = js.add(js.empty(spec), jnp.asarray(x),
+                None if w is None else jnp.asarray(w), spec=spec,
+                auto_collapse=variant == "auto_collapse")
+    assert ops.fallback_count() == 0
+    level = int(sk.level)
+    assert (level > 0) == (variant == "auto_collapse")
+    assert float(sk.overflow) == float(sk.underflow) == 0.0
+
+    lev = jnp.full(n, level, jnp.int32)
+    ww = None if w is None else jnp.asarray(w)
+    for got, vals in ((sk.pos, np.where(x > 0, x, -1.0)),
+                      (sk.neg, np.where(x < 0, -x, -1.0))):
+        want = histogram_ref(jnp.asarray(vals, jnp.float32), ww, lev, spec=spec)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    host = DDSketch(spec.relative_accuracy, max_bins=None, mapping=mapping,
+                    collapse_level=level)
+    ok = np.isfinite(x)
+    for v, c in zip(x[ok], np.ones(n, int)[ok] if w is None else w[ok].astype(int)):
+        if c:
+            host.add(float(v), int(c))
+    assert float(sk.count) == host.count
+    for q in QS:
+        dev = float(js.quantile(sk, q, spec=spec))
+        assert dev == pytest.approx(host.quantile(q), rel=1e-5, abs=1e-7), q
+
+
+def test_kernel_tier_add_is_one_launch(rng, tpu_interpret):
+    """Both stores of a single-sketch insert come from one
+    ``segment_histogram`` launch over the two stacked rows."""
+    x = jnp.asarray((rng.pareto(1.0, 4096) + 1.0).astype(np.float32) - 2.0)
+    jaxpr = str(jax.make_jaxpr(lambda v: js.add(js.empty(SPEC), v, spec=SPEC))(x))
+    assert jaxpr.count("pallas_call[") == 1
+    assert "name=segment_histogram_pallas" in jaxpr
+
+
+# --------------------------------------------------------------------- #
 # cross-device mergeability: the paper's headline property == psum
 # --------------------------------------------------------------------- #
 def test_psum_merge_across_devices():

@@ -1,13 +1,15 @@
-"""Pallas kernel vs pure-jnp oracle: exact agreement across shapes, dtypes,
-mappings, weights, and tile configurations (interpret mode on CPU)."""
+"""Single-sketch insert through the one insert kernel: the row-sparse
+``segment_histogram`` with one segment vs the ``histogram_ref`` oracle, exact
+agreement across shapes, dtypes, mappings, weights, and tile configurations
+(interpret mode on CPU)."""
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.ddsketch_hist import histogram_pallas
-from repro.kernels.ops import ddsketch_histogram
+from repro.kernels import ops
+from repro.kernels.ddsketch_seg_hist import segment_histogram_pallas
 from repro.kernels.ref import BucketSpec, bucket_index, histogram_ref
 from repro.core.mapping import make_mapping
 
@@ -28,13 +30,20 @@ def _data(shape, rng, kind="pareto"):
     return x.reshape(shape).astype(np.float32)
 
 
+def one_sketch_hist(x, w=None, levels=None, **kw):
+    """One sketch's bucket counts ``(m,)``: every lane in segment 0."""
+    ids = jnp.zeros(x.shape, jnp.int32)
+    return segment_histogram_pallas(x, ids, w, levels, num_segments=1,
+                                    interpret=True, **kw)[0]
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("mapping", MAPPINGS)
 def test_kernel_matches_ref(shape, mapping, rng):
     spec = BucketSpec(mapping=mapping)
     x = jnp.asarray(_data(shape, rng))
     ref = histogram_ref(x, spec=spec)
-    ker = histogram_pallas(x, spec=spec, interpret=True)
+    ker = one_sketch_hist(x, spec=spec)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
     assert float(ref.sum()) > 0
 
@@ -44,7 +53,7 @@ def test_kernel_dtypes(dtype, rng):
     spec = BucketSpec()
     x = jnp.asarray(rng.pareto(1.0, 513) + 1.0).astype(dtype)
     ref = histogram_ref(x, spec=spec)
-    ker = histogram_pallas(x, spec=spec, interpret=True)
+    ker = one_sketch_hist(x, spec=spec)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
 
 
@@ -53,7 +62,7 @@ def test_kernel_weights(rng):
     x = jnp.asarray(_data((777,), rng))
     w = jnp.asarray(rng.integers(0, 5, 777).astype(np.float32))
     ref = histogram_ref(x, w, spec=spec)
-    ker = histogram_pallas(x, w, spec=spec, interpret=True)
+    ker = one_sketch_hist(x, w, spec=spec)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
 
 
@@ -62,18 +71,22 @@ def test_kernel_tilings(value_tile, bucket_tile, rng):
     spec = BucketSpec()
     x = jnp.asarray(_data((3000,), rng))
     ref = histogram_ref(x, spec=spec)
-    ker = histogram_pallas(
-        x, spec=spec, value_tile=value_tile, bucket_tile=bucket_tile, interpret=True
+    ker = one_sketch_hist(
+        x, spec=spec, value_tile=value_tile, bucket_tile=bucket_tile
     )
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
 
 
-def test_kernel_rejects_bad_tiling():
-    with pytest.raises(ValueError):
-        histogram_pallas(
-            jnp.ones(8), spec=BucketSpec(num_buckets=2048), bucket_tile=1000,
+def test_single_sketch_rejects_bad_shapes():
+    spec = BucketSpec(num_buckets=2048)
+    with pytest.raises(ValueError, match="same size"):
+        segment_histogram_pallas(
+            jnp.ones(8), jnp.zeros(7, jnp.int32), num_segments=1, spec=spec,
             interpret=True,
         )
+    # segment ids may be left out only when the bank is one sketch
+    with pytest.raises(ValueError, match="single-row"):
+        ops.bank_histograms(jnp.ones(8), None, num_segments=3, spec=spec)
 
 
 @pytest.mark.parametrize("mapping", MAPPINGS)
@@ -117,16 +130,18 @@ def test_log_mapping_log_is_within_one_ulp(rng):
 def test_ops_dispatch_ref_on_cpu(rng):
     spec = BucketSpec()
     x = jnp.asarray(_data((512,), rng))
-    out = ddsketch_histogram(x, spec=spec)  # auto -> ref on CPU
+    ids = jnp.zeros(x.shape, jnp.int32)
+    out = ops.segment_histogram(x, ids, num_segments=1, spec=spec)  # auto -> ref
     np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(histogram_ref(x, spec=spec))
+        np.asarray(out[0]), np.asarray(histogram_ref(x, spec=spec))
     )
-    out2 = ddsketch_histogram(x, spec=spec, force="interpret")
+    out2 = ops.segment_histogram(x, ids, num_segments=1, spec=spec,
+                                 force="interpret")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 
 def test_kernel_empty_and_all_masked():
     spec = BucketSpec()
     x = jnp.asarray([-1.0, 0.0, jnp.nan], jnp.float32)
-    ker = histogram_pallas(x, spec=spec, interpret=True)
+    ker = one_sketch_hist(x, spec=spec)
     assert float(ker.sum()) == 0.0

@@ -1,7 +1,8 @@
 """Segmented Pallas kernel vs pure-jnp oracle: exact agreement across
 mappings, tile configurations, segment counts, row layouts, weights, and
 hostile inputs (interpret mode on CPU); the row-sparse pair table; plus the
-ops-dispatch contract."""
+ops-dispatch contracts (``bank_histograms``' sign routing, the size-aware
+``force=None``, ``force`` validation)."""
 
 import numpy as np
 import pytest
@@ -15,7 +16,7 @@ from repro.kernels.ddsketch_seg_hist import (
     pair_table,
     segment_histogram_pallas,
 )
-from repro.kernels.ops import ddsketch_histogram, segment_histogram
+from repro.kernels.ops import segment_histogram
 from repro.kernels.ref import BucketSpec, histogram_ref, segment_histogram_ref
 
 MAPPINGS = ["log", "linear", "cubic"]
@@ -126,8 +127,6 @@ def test_seg_kernel_empty_and_all_masked():
 def test_kernels_zero_length_input_returns_zeros():
     """Regression: an empty batch used to build a zero-length value grid
     (nv=0), crashing pallas_call and skipping the output-tile init."""
-    from repro.kernels.ddsketch_hist import histogram_pallas
-
     spec = BucketSpec()
     empty_vals = jnp.zeros((0,), jnp.float32)
     seg = segment_histogram_pallas(
@@ -135,8 +134,10 @@ def test_kernels_zero_length_input_returns_zeros():
         interpret=True,
     )
     assert seg.shape == (5, spec.num_buckets) and float(seg.sum()) == 0.0
-    single = histogram_pallas(empty_vals, spec=spec, interpret=True)
-    assert single.shape == (spec.num_buckets,) and float(single.sum()) == 0.0
+    pos, neg = ops.bank_histograms(empty_vals, None, num_segments=1, spec=spec,
+                                   force="interpret")
+    assert pos.shape == neg.shape == (1, spec.num_buckets)
+    assert float(pos.sum()) == float(neg.sum()) == 0.0
 
 
 def test_ops_seg_dispatch_ref_on_cpu(rng):
@@ -160,7 +161,7 @@ def test_force_pallas_raises_off_tpu(rng):
     spec = BucketSpec()
     x = jnp.asarray(rng.pareto(1.0, 64).astype(np.float32) + 1.0)
     with pytest.raises(RuntimeError, match="pallas"):
-        ddsketch_histogram(x, spec=spec, force="pallas")
+        ops.bank_histograms(x, None, num_segments=1, spec=spec, force="pallas")
     with pytest.raises(RuntimeError, match="pallas"):
         segment_histogram(
             x, jnp.zeros(64, jnp.int32), num_segments=2, spec=spec, force="pallas"
@@ -170,7 +171,8 @@ def test_force_pallas_raises_off_tpu(rng):
 def test_force_rejects_unknown_value(rng):
     x = jnp.ones(8, jnp.float32)
     with pytest.raises(ValueError, match="force"):
-        ddsketch_histogram(x, spec=BucketSpec(), force="jit")
+        ops.bank_histograms(x, None, num_segments=1, spec=BucketSpec(),
+                            force="jit")
 
 
 # --------------------------------------------------------------------- #
@@ -239,7 +241,7 @@ def test_bank_histograms_matmul_one_stream_hostile(rng):
     x, s, w = jnp.asarray(x), jnp.asarray(s), jnp.asarray(w)
     for weights in (None, w):
         pos, neg = ops.bank_histograms(x, s, weights, num_segments=k, spec=spec,
-                                       method="matmul", force="interpret")
+                                       force="interpret")
         want_pos = segment_histogram_ref(
             jnp.where(x > spec.min_indexable, x, -1.0), s, weights,
             num_segments=k, spec=spec)
@@ -261,7 +263,7 @@ def test_bank_histograms_row_runs_tpu_interpret(tpu_interpret, rng):
     x = (rng.pareto(1.0, s.size) + 1.0).astype(np.float32)
     x *= np.where(s % 3 == 0, -1.0, 1.0).astype(np.float32)
     x, s = jnp.asarray(x), jnp.asarray(s)
-    pos, neg = ops.bank_histograms(x, s, num_segments=k, spec=spec, method="matmul")
+    pos, neg = ops.bank_histograms(x, s, num_segments=k, spec=spec)
     for got, vals in ((pos, jnp.where(x > 0, x, -1.0)), (neg, jnp.where(x < 0, -x, -1.0))):
         want = segment_histogram_ref(vals, s, num_segments=k, spec=spec)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -314,12 +316,98 @@ def test_pair_table_lists_exact_pairs(layout, value_tile, rng):
 
 
 def test_bank_histograms_matmul_is_one_launch(rng):
-    """Both sign stores of a matmul ingest come from one kernel launch."""
+    """Both sign stores of a bank insert come from one kernel launch."""
     spec = BucketSpec()
     x = jnp.asarray(_data(4096, rng))
     s = jnp.asarray(rng.integers(0, 100, 4096).astype(np.int32))
     jaxpr = str(jax.make_jaxpr(
         lambda x, s: ops.bank_histograms(x, s, num_segments=100, spec=spec,
-                                         method="matmul", force="interpret")
+                                         force="interpret")
     )(x, s))
     assert jaxpr.count("pallas_call[") == 1
+
+
+# --------------------------------------------------------------------- #
+# ops dispatch: bank_histograms + size-aware force=None
+# --------------------------------------------------------------------- #
+def _signed_data(n, rng):
+    x = (rng.pareto(1.0, n) + 1.0).astype(np.float32)
+    x *= np.where(rng.random(n) < 0.4, -1.0, 1.0).astype(np.float32)
+    specials = np.array([np.nan, np.inf, -np.inf, -1.0, 0.0, 1e-38, 1e38])
+    idx = rng.choice(n, size=min(7, n), replace=False)
+    x[idx] = specials[: len(idx)].astype(np.float32)
+    return x
+
+
+def test_pipeline_weighted_and_levelled(rng):
+    """Weighted, per-lane-levelled lanes of both signs through the kernel:
+    each sign store equals the sign-masked reference histogram."""
+    spec = BucketSpec()
+    n, k = 3000, 11
+    x = jnp.asarray(_signed_data(n, rng))
+    s = jnp.asarray(rng.integers(0, k, n).astype(np.int32))
+    w = jnp.asarray(rng.integers(0, 5, n).astype(np.float32))
+    lev = jnp.asarray(rng.integers(0, 4, n).astype(np.int32))
+    pos, neg = ops.bank_histograms(x, s, w, lev, num_segments=k, spec=spec,
+                                   force="interpret")
+    want_pos = segment_histogram_ref(
+        jnp.where(x > spec.min_indexable, x, -1.0), s, w, lev,
+        num_segments=k, spec=spec)
+    want_neg = segment_histogram_ref(
+        jnp.where(x < -spec.min_indexable, -x, -1.0), s, w, lev,
+        num_segments=k, spec=spec)
+    np.testing.assert_array_equal(np.asarray(pos), np.asarray(want_pos))
+    np.testing.assert_array_equal(np.asarray(neg), np.asarray(want_neg))
+
+
+def test_size_aware_dispatch_crossover(monkeypatch):
+    """Regression (satellite): force=None on TPU used to launch the Pallas
+    kernel even for sub-tile batches where padding to value_tile dominates;
+    auto now routes them to the XLA ref.  The crossover is value_tile."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    ops.reset_dispatch_stats()
+    with pytest.warns(RuntimeWarning, match="below one tile"):
+        assert ops._impl(None, 2047, 2048, "site") == "ref"
+    assert ops._impl(None, 2048, 2048, "site") == "pallas"
+    assert ops._impl(None, 0, 2048, "site") == "ref"
+    # the sub-tile detour is counted, not silent
+    assert ops.dispatch_stats()["subtile_fallbacks"] == {"site": 2}
+    assert ops.fallback_count() == 2
+    # pinned values always pass through untouched (and uncounted)
+    assert ops._impl("ref", 1 << 20, 2048, "site") == "ref"
+    assert ops._impl("interpret", 4, 2048, "site") == "interpret"
+    # off TPU the reference is the plan, not a detour
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    assert ops._impl(None, 1 << 20, 2048, "site") == "ref"
+    assert ops._impl(None, 4, 2048, "site") == "ref"
+    assert ops.fallback_count() == 2
+    ops.reset_dispatch_stats()
+    assert ops.dispatch_stats() == {
+        "range_merge_calls": {},
+        "query_fallbacks": {},
+        "subtile_fallbacks": {},
+    }
+
+
+def test_force_validation_still_enforced(rng):
+    if jax.default_backend() == "tpu":
+        pytest.skip("off-TPU guard")
+    spec = BucketSpec()
+    x = jnp.ones(64)
+    with pytest.raises(RuntimeError, match="pallas"):
+        ops.bank_histograms(x, jnp.zeros(64, jnp.int32), num_segments=2,
+                            spec=spec, force="pallas")
+    with pytest.raises(ValueError, match="force"):
+        ops.bank_histograms(x, jnp.zeros(64, jnp.int32), num_segments=2,
+                            spec=spec, force="jit")
+
+
+def test_bank_histograms_interpret_matches_ref(rng):
+    spec = BucketSpec()
+    k, n = 6, 2500
+    x = jnp.asarray(_signed_data(n, rng))
+    s = jnp.asarray(rng.integers(0, k, n).astype(np.int32))
+    a = ops.bank_histograms(x, s, num_segments=k, spec=spec, force="ref")
+    b = ops.bank_histograms(x, s, num_segments=k, spec=spec, force="interpret")
+    for ga, gb in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(ga), np.asarray(gb))

@@ -117,27 +117,52 @@ def test_bank_merge_is_elementwise_sum(rng):
     np.testing.assert_array_equal(got, want)
 
 
-def test_bank_kernel_path_matches_ref_path(rng, request):
-    k, n = 33, 5000
+@pytest.mark.parametrize(
+    "k,n",
+    [(1, 5000), (2, 5000), (5, 5000), (16, 5000), (17, 5000), (33, 5000),
+     (511, 2048), (512, 2048), (4095, 2048)],
+)
+def test_bank_kernel_path_matches_ref_path(k, n, rng, request):
+    """The kernel tier's bank equals the reference tier's in every field,
+    from a single row through the dense/segment stats switch (16 rows) to
+    the cells' 4095-key bank."""
     x, s = _mixed_stream(rng, n, k)
+    if k > 512:  # a served tick touches few rows of a tall bank
+        hot = rng.choice(k, 128, replace=False).astype(np.int32)
+        s = np.where((s >= 0) & (s < k), hot[s % 128], s)
     ref_bank = sb.add(sb.empty(SPEC, k), jnp.asarray(x), jnp.asarray(s), spec=SPEC)
     request.getfixturevalue("tpu_interpret")  # default dispatch -> kernel
     ker_bank = sb.add(sb.empty(SPEC, k), jnp.asarray(x), jnp.asarray(s), spec=SPEC)
     from repro.kernels import ops
 
-    assert sb.picked_insert_method(n, k, SPEC.num_buckets) == "fused"
     assert ops.fallback_count() == 0
     for name, f_ref, f_ker in zip(ref_bank._fields, ref_bank, ker_bank):
-        if name == "summ":
-            # the fused kernel sums in tile order, not lane order (its
-            # documented contract): reassociation error is bounded by the
-            # row's sum of |x|; every other field is bit-exact
-            ok = np.isfinite(x) & (s >= 0) & (s < k)
-            mass = np.bincount(s[ok], np.abs(x[ok]).astype(np.float64), minlength=k)
-            err = np.abs(np.asarray(f_ker, np.float64) - np.asarray(f_ref, np.float64))
-            assert (err <= 1e-6 * mass).all(), (err, mass)
-        else:
-            np.testing.assert_array_equal(np.asarray(f_ref), np.asarray(f_ker))
+        np.testing.assert_array_equal(
+            np.asarray(f_ref), np.asarray(f_ker), err_msg=f"field {name!r}"
+        )
+
+
+@pytest.mark.parametrize("k", [1, 5, 33, 512])
+def test_bank_kernel_path_matches_ref_path_auto_collapse(k, rng, request):
+    """With ``auto_collapse`` the rows fold before the insert, so the kernel
+    keys every lane at its row's new level: both tiers agree in every
+    field, levels included."""
+    n = 3000
+    x, s = _mixed_stream(rng, n, k)
+    x[: 2 * k] = 1e-30  # below the level-0 range: these rows must fold
+    s[: 2 * k] = np.arange(2 * k) % k
+    args = (jnp.asarray(x), jnp.asarray(s))
+    ref_bank = sb.add(sb.empty(SPEC, k), *args, spec=SPEC, auto_collapse=True)
+    request.getfixturevalue("tpu_interpret")
+    ker_bank = sb.add(sb.empty(SPEC, k), *args, spec=SPEC, auto_collapse=True)
+    from repro.kernels import ops
+
+    assert ops.fallback_count() == 0
+    assert int(np.asarray(ref_bank.level).min()) > 0
+    for name, f_ref, f_ker in zip(ref_bank._fields, ref_bank, ker_bank):
+        np.testing.assert_array_equal(
+            np.asarray(f_ref), np.asarray(f_ker), err_msg=f"field {name!r}"
+        )
 
 
 def test_bank_row_and_set_row_roundtrip(rng):

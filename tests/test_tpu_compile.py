@@ -3,9 +3,11 @@
 No chip is attached: the TPU compiler that ships with JAX compiles for a
 topology it is only told about, and refuses what the chip would refuse
 (block alignment, VMEM budgets, primitives Mosaic cannot lower).  Widths:
-m=2048 buckets, 2K=1024 resident rows, N=65536 lanes, Q=4 qs, D=12 slices;
-the segmented histogram also at a 4096-key bank's stacked 8192 rows, the
-row-sparse grid's scalar-prefetch tables and traced pair count.
+m=2048 buckets, 2K=1024 rows, N=65536 lanes, Q=4 qs, D=12 slices.  The
+segmented histogram, the one insert kernel, compiles at each stacked row
+count the deployments give it: one sketch's 2 rows, a 511-key bank's 1022,
+512 rows, and a 4096-key bank's 8192, each with the row-sparse grid's
+scalar-prefetch tables and traced pair count.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process may load the TPU library, and every test worker imports
@@ -23,15 +25,12 @@ import jax.numpy as jnp
 
 from repro.kernels.bank_quantiles import bank_quantiles_pallas
 from repro.kernels.bank_range_merge import bank_range_merge_pallas
-from repro.kernels.ddsketch_hist import histogram_pallas
-from repro.kernels.ddsketch_ingest import ddsketch_ingest_pallas
-from repro.kernels.ddsketch_scatter import ddsketch_scatter_pallas
 from repro.kernels.ddsketch_seg_hist import segment_histogram_pallas
 from repro.kernels.fold_pairs import fold_pairs_pallas
 from repro.kernels.ref import MAX_COLLAPSE_LEVEL, BucketSpec
 
 SPEC = BucketSpec()  # alpha=0.01, m=2048
-K, M, N, Q, D = 512, 2048, 65536, 4, 12  # 2K = 1024 resident rows
+K, M, N, Q, D = 512, 2048, 65536, 4, 12  # 2K = 1024 rows
 K_TALL = 4096  # keys4096: both sign stores stacked, 8192 rows
 F32, I32 = jnp.float32, jnp.int32
 
@@ -58,31 +57,19 @@ def one_chip():
 def _kernel(name):
     """(fn, argument shapes) of one kernel at the widths above."""
     lanes = lambda dt: ((N,), dt)  # noqa: E731
+
+    def seg_hist(rows):
+        return (
+            lambda x, s, w, lv: segment_histogram_pallas(
+                x, s, w, lv, num_segments=rows, spec=SPEC, interpret=False),
+            [lanes(F32), lanes(I32), lanes(F32), lanes(I32)],
+        )
+
     return {
-        "ddsketch_ingest": (
-            lambda x, s, w, lv: ddsketch_ingest_pallas(
-                x, s, w, lv, num_segments=K, spec=SPEC, interpret=False),
-            [lanes(F32), lanes(I32), lanes(F32), lanes(I32)],
-        ),
-        "ddsketch_seg_hist": (
-            lambda x, s, w, lv: segment_histogram_pallas(
-                x, s, w, lv, num_segments=K, spec=SPEC, interpret=False),
-            [lanes(F32), lanes(I32), lanes(F32), lanes(I32)],
-        ),
-        "ddsketch_seg_hist_tall": (
-            lambda x, s, w, lv: segment_histogram_pallas(
-                x, s, w, lv, num_segments=2 * K_TALL, spec=SPEC, interpret=False),
-            [lanes(F32), lanes(I32), lanes(F32), lanes(I32)],
-        ),
-        "ddsketch_scatter": (
-            lambda k, w: ddsketch_scatter_pallas(
-                k, w, num_rows=2 * K, num_buckets=M, interpret=False),
-            [lanes(I32), lanes(F32)],
-        ),
-        "ddsketch_hist": (
-            lambda x, w, lv: histogram_pallas(x, w, lv, spec=SPEC, interpret=False),
-            [lanes(F32), lanes(F32), lanes(I32)],
-        ),
+        "ddsketch_seg_hist": seg_hist(K),
+        "ddsketch_seg_hist_single": seg_hist(2),
+        "ddsketch_seg_hist_511": seg_hist(2 * (K - 1)),
+        "ddsketch_seg_hist_tall": seg_hist(2 * K_TALL),
         "fold_pairs": (
             lambda c: fold_pairs_pallas(c, spec=SPEC, interpret=False),
             [((2 * K, M), F32)],
@@ -103,8 +90,8 @@ def _kernel(name):
 
 @pytest.mark.parametrize(
     "name",
-    ["ddsketch_ingest", "ddsketch_seg_hist", "ddsketch_scatter", "ddsketch_hist",
-     "fold_pairs", "bank_quantiles", "bank_range_merge", "ddsketch_seg_hist_tall"],
+    ["ddsketch_seg_hist", "fold_pairs", "bank_quantiles", "bank_range_merge",
+     "ddsketch_seg_hist_tall", "ddsketch_seg_hist_single", "ddsketch_seg_hist_511"],
 )
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = _kernel(name)
